@@ -1567,7 +1567,9 @@ def check_against(baseline_path: str, bench_json: Dict,
 def main(argv=None) -> None:
     import json
     import sys
+    from repro.launch.compile_cache import enable_compile_cache
     args = list(argv if argv is not None else sys.argv[1:])
+    enable_compile_cache()
 
     def _path_flag(flag):
         if flag not in args:

@@ -31,27 +31,21 @@ from jax import lax
 Axis = Union[str, Sequence[str]]
 
 
-def _one_axis_size(a: str) -> int:
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(a)
-    return lax.psum(1, a)  # older jax: count members instead
-
-
 def _index(axis: Axis) -> jax.Array:
     if isinstance(axis, str):
         return lax.axis_index(axis)
     idx = jnp.int32(0)
     for a in axis:
-        idx = idx * _one_axis_size(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
 def axis_size(axis: Axis) -> int:
     if isinstance(axis, str):
-        return _one_axis_size(axis)
+        return lax.axis_size(axis)
     n = 1
     for a in axis:
-        n *= _one_axis_size(a)
+        n *= lax.axis_size(a)
     return n
 
 
@@ -166,6 +160,6 @@ def cluster_send(x: jax.Array, inter_axis: str, dst_offset: int = 1
                  ) -> jax.Array:
     """Send x to the next cluster along the ring (one-byte-header GMI
     inter-cluster message -> collective_permute on the pod axis)."""
-    n = _one_axis_size(inter_axis)
+    n = lax.axis_size(inter_axis)
     perm = [(i, (i + dst_offset) % n) for i in range(n)]
     return lax.ppermute(x, inter_axis, perm)

@@ -13,26 +13,12 @@ forward.  Total steps = n_micro + n_stages - 1, i.e. Eq. 1 with X = T_stage.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 top-level API with check_vma
-    shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except AttributeError:  # older jax: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions."""
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **_SHARD_MAP_KW)
 
 
 def pipeline_steps(n_micro: int, n_stages: int) -> int:
@@ -94,9 +80,9 @@ def pipelined_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         out = lax.psum(jnp.where(sidx == n_stages - 1, out, 0.0), axis)
         return out
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(stage_params, x_micro)

@@ -6,12 +6,15 @@ per slot against the whole KV cache.  The dense jnp path materializes the
 kernel streams the cache through VMEM once — HBM traffic = K + V + Q + O,
 with Q and O negligible (one row per head group).
 
-Grid: (batch, kv_heads, Sk/bs) with the KV-split axis innermost, so each
-(batch, kv head) pair walks its splits sequentially while the per-split
-(m, l, acc) partials stay resident in VMEM scratch; the final split runs the
-reduction epilogue (normalize by l, cast, write O).  All `q_per_kv` query
-heads of a KV group ride in one block — the group dim is the sublane axis,
-so GQA costs no extra cache reads.
+Grid: (batch, Sk/bs) with the KV-split axis innermost, so each lane walks
+its splits sequentially while every kv head's (m, l, acc) partials stay
+resident in VMEM scratch; the final split runs the reduction epilogue
+(normalize by l, cast, write O).  A block spans all kv heads of its rows:
+its last two dims are the cache's own (KVH, hd), which Mosaic tiles for any
+head count, and each row is read once.  All `q_per_kv` query heads of a KV
+group ride in one block — the group dim is the sublane axis, so GQA costs
+no extra cache reads.  Per-lane scalars (qpos, active, the page table) are
+scalar-prefetched into SMEM.
 
 Masking is slot-metadata driven, matching the serving cache contract
 (models/attention.py):
@@ -45,51 +48,65 @@ NEG_INF = -1e30
 KPOS_SENTINEL = 2 ** 30  # never-written cache slot (models/attention.py)
 
 
-def _kernel(qpos_ref, active_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, n_s: int, bs: int, window: int):
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0]  # (G, hd) — the kv group's query heads
-    k = k_ref[0, :, 0, :]  # (bs, hd)
-    v = v_ref[0, :, 0, :]
+def _online_softmax_step(q, k, v, msk, m_ref, l_ref, acc_ref, h: int):
+    """One key block of kv head `h` folded into that head's running
+    (m, l, acc) partials — the online-softmax body every decode kernel
+    here shares.  q: (G, hd); k/v: (n, hd); msk: broadcastable to (G, n)."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)  # (G, bs)
-
-    qpos = qpos_ref[0, 0]
-    kpos = kpos_ref[0]  # (bs,) absolute positions; 2^30 = never written
-    msk = kpos[None, :] <= qpos  # causal; also rejects the sentinel
-    if window:
-        msk &= qpos - kpos[None, :] < window
-    msk &= active_ref[0, 0] != 0
-
+        preferred_element_type=jnp.float32)  # (G, n)
     s = jnp.where(msk, s, NEG_INF)
-    m_prev = m_ref[...]
+    m_prev = m_ref[h]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    # p from the mask, not from s > threshold: a fully-masked split leaves
+    # p from the mask, not from s > threshold: a fully-masked block leaves
     # m_new at NEG_INF and exp(s - m_new) would be exp(0) = 1 garbage
     p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    l_new = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-    acc_ref[...] = acc_ref[...] * alpha + pv
+    m_ref[h] = m_new
+    l_ref[h] = l_new
+    acc_ref[h] = acc_ref[h] * alpha + pv
+
+
+def _init_partials(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _write_out(o_ref, l_ref, acc_ref, kvh: int):
+    # combine the split partials: normalize by the running l.  l == 0
+    # (inactive slot / fresh cache, every key masked) yields exact 0.
+    for h in range(kvh):
+        o_ref[0, h] = (acc_ref[h]
+                       / jnp.maximum(l_ref[h], 1e-30)).astype(o_ref.dtype)
+
+
+def _kernel(qpos_ref, active_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
+            m_ref, l_ref, acc_ref, *, n_s: int, window: int):
+    b, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        _init_partials(m_ref, l_ref, acc_ref)
+
+    qpos = qpos_ref[b]
+    kpos = kpos_ref[0]  # (1, bs) absolute positions; 2^30 = never written
+    msk = kpos <= qpos  # causal; also rejects the sentinel
+    if window:
+        msk &= qpos - kpos < window
+    msk &= active_ref[b] != 0
+    for h in range(q_ref.shape[1]):  # every kv head of the block
+        _online_softmax_step(q_ref[0, h], k_ref[0, :, h, :],
+                             v_ref[0, :, h, :], msk, m_ref, l_ref, acc_ref,
+                             h)
 
     @pl.when(j == n_s - 1)
     def _epilogue():
-        # combine the split partials: normalize by the running l.  l == 0
-        # (inactive slot / fresh cache, every key masked) yields exact 0.
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref, q_ref.shape[1])
 
 
 def _paged_kernel(pt_ref, qpos_ref, active_ref, q_ref, k_ref, v_ref,
@@ -98,45 +115,25 @@ def _paged_kernel(pt_ref, qpos_ref, active_ref, q_ref, k_ref, v_ref,
     grid step j is whichever *page* the lane's page table names — the
     gather happens in the BlockSpec index map (scalar-prefetched page
     table), so the kernel body never sees page indirection at all."""
-    j = pl.program_id(2)
+    b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_partials(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, 0]  # (G, hd)
-    k = k_ref[0, :, 0, :]  # (ps, hd) — the page named by pt[b, j]
-    v = v_ref[0, :, 0, :]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)  # (G, ps)
-
-    qpos = qpos_ref[0, 0]
-    kpos = kpos_ref[0]  # (ps,) absolute positions; 2^30 = never written
+    qpos = qpos_ref[b]
+    kpos = kpos_ref[0]  # (1, ps) absolute positions; 2^30 = never written
     # causal test rejects the sentinel, so trash/unwritten page slots and
     # out-of-range page-table entries are unreachable by construction
-    msk = kpos[None, :] <= qpos
-    msk &= active_ref[0, 0] != 0
-
-    s = jnp.where(msk, s, NEG_INF)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-    acc_ref[...] = acc_ref[...] * alpha + pv
+    msk = (kpos <= qpos) & (active_ref[b] != 0)
+    for h in range(q_ref.shape[1]):  # the page's kv heads, one block
+        _online_softmax_step(q_ref[0, h], k_ref[0, :, h, :],
+                             v_ref[0, :, h, :], msk, m_ref, l_ref, acc_ref,
+                             h)
 
     @pl.when(j == n_p - 1)
     def _epilogue():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref, q_ref.shape[1])
 
 
 def _paged_kernel_q(pt_ref, qpos_ref, active_ref, q_ref, k_ref, v_ref,
@@ -147,46 +144,35 @@ def _paged_kernel_q(pt_ref, qpos_ref, active_ref, q_ref, k_ref, v_ref,
     are dequantized in VMEM right before the dots — HBM moves ~half the
     bytes of the bf16 arena (int8 values + one f32 scale per row per kv
     head) while the online-softmax recurrence is unchanged and stays f32."""
-    j = pl.program_id(2)
+    b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_partials(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # (G, hd)
-    # (ps, hd) int8 * (ps, 1) f32 scale -> dequantized page tile in VMEM;
-    # the scales ride the same page-table indirection as kpos, so a
-    # radix-shared page dequantizes identically for every lane reading it
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0]  # (ps, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)  # (G, ps)
-
-    qpos = qpos_ref[0, 0]
-    kpos = kpos_ref[0]  # (ps,) absolute positions; 2^30 = never written
-    msk = kpos[None, :] <= qpos  # causal; also rejects the sentinel
-    msk &= active_ref[0, 0] != 0
-
-    s = jnp.where(msk, s, NEG_INF)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-    acc_ref[...] = acc_ref[...] * alpha + pv
+    qpos = qpos_ref[b]
+    kpos = kpos_ref[0]  # (1, ps) absolute positions; 2^30 = never written
+    msk = (kpos <= qpos) & (active_ref[b] != 0)
+    for h in range(q_ref.shape[1]):
+        # (ps, hd) int8 * (ps, 1) f32 scale -> dequantized page tile in
+        # VMEM; the scales ride the same page-table indirection as kpos, so
+        # a radix-shared page dequantizes identically for every lane
+        k = k_ref[0, :, h, :].astype(jnp.float32) * ks_ref[0, :, h:h + 1]
+        v = v_ref[0, :, h, :].astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+        _online_softmax_step(q_ref[0, h].astype(jnp.float32), k, v, msk,
+                             m_ref, l_ref, acc_ref, h)
 
     @pl.when(j == n_p - 1)
     def _epilogue():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref, q_ref.shape[1])
+
+
+def _scratch(kvh: int, g: int, hd: int):
+    return [
+        pltpu.VMEM((kvh, g, 1), jnp.float32),   # running max m
+        pltpu.VMEM((kvh, g, 1), jnp.float32),   # running sum l
+        pltpu.VMEM((kvh, g, hd), jnp.float32),  # output accumulator
+    ]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -207,43 +193,37 @@ def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,
     unquantized kernel.
     """
     b, kvh, g, hd = q.shape
-    ps = k.shape[1]
+    n_pages, ps = kpos.shape
     maxp = page_table.shape[1]
-    grid = (b, kvh, maxp)
     kern = functools.partial(_paged_kernel_q, n_p=maxp)
+    page = lambda b, j, pt, qp, act: (pt[b, j], 0, 0, 0)  # noqa: E731
+    scale_spec = pl.BlockSpec((1, ps, kvh), lambda b, j, pt, qp, act:
+                              (pt[b, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
+        num_scalar_prefetch=3,
+        grid=(b, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, j, pt: (b, 0),
-                         memory_space=pltpu.SMEM),  # qpos
-            pl.BlockSpec((1, 1), lambda b, h, j, pt: (b, 0),
-                         memory_space=pltpu.SMEM),  # active
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, j, pt: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, j, pt: (pt[b, j], 0, h, 0)),  # k int8
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, j, pt: (pt[b, j], 0, h, 0)),  # v int8
-            pl.BlockSpec((1, ps, 1),
-                         lambda b, h, j, pt: (pt[b, j], 0, h)),  # k_scale
-            pl.BlockSpec((1, ps, 1),
-                         lambda b, h, j, pt: (pt[b, j], 0, h)),  # v_scale
-            pl.BlockSpec((1, ps), lambda b, h, j, pt: (pt[b, j], 0)),
+            pl.BlockSpec((1, kvh, g, hd), lambda b, j, pt, qp, act:
+                         (b, 0, 0, 0)),
+            pl.BlockSpec((1, ps, kvh, hd), page),  # k int8
+            pl.BlockSpec((1, ps, kvh, hd), page),  # v int8
+            scale_spec,  # k_scale
+            scale_spec,  # v_scale
+            pl.BlockSpec((1, 1, ps), lambda b, j, pt, qp, act:
+                         (pt[b, j], 0, 0)),  # kpos
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda b, h, j, pt: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),   # running max m
-            pltpu.VMEM((g, 1), jnp.float32),   # running sum l
-            pltpu.VMEM((g, hd), jnp.float32),  # output accumulator
-        ],
+        out_specs=pl.BlockSpec((1, kvh, g, hd), lambda b, j, pt, qp, act:
+                               (b, 0, 0, 0)),
+        scratch_shapes=_scratch(kvh, g, hd),
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
         interpret=interpret,
-    )(page_table, qpos, active, q, k, v, k_scale, v_scale, kpos)
+    )(page_table, qpos, active, q, k, v,
+      k_scale, v_scale,
+      kpos.reshape(n_pages, 1, ps))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -259,48 +239,42 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     (B, MAXP) int32 — lane b's logical KV positions [j*ps, (j+1)*ps) live
     in arena page page_table[b, j].  Entries may repeat across lanes
     (radix-shared prefixes) and unused entries may point anywhere whose
-    kpos are all sentinel (the allocator's trash page 0).  qpos: (B, 1)
-    int32; active: (B, 1) int32 row gate.  Returns (B, KVH, G, hd).
+    kpos are all sentinel (the allocator's trash page 0).  qpos: (B,)
+    int32; active: (B,) int32 row gate.  Returns (B, KVH, G, hd).
 
-    Grid: (batch, kv_heads, MAXP) with pages innermost; the page table is
-    scalar-prefetched and indexed in the k/v/kpos BlockSpec index maps, so
-    the per-page DMA *is* the gather — the kernel body is identical to the
-    dense split-KV kernel's online softmax.
+    Grid: (batch, MAXP) with pages innermost; the page table, qpos and
+    active are scalar-prefetched, and the page table is indexed in the
+    k/v/kpos BlockSpec index maps, so the per-page DMA *is* the gather.
+    One block holds a whole page — every kv head of it — so each page is
+    read once per lane and the block's last two dims are the arena's own
+    (KVH, hd), the tiling Mosaic accepts for any head count.
     """
     b, kvh, g, hd = q.shape
-    ps = k.shape[1]
+    n_pages, ps = kpos.shape
     maxp = page_table.shape[1]
-    grid = (b, kvh, maxp)
     kern = functools.partial(_paged_kernel, n_p=maxp)
+    page = lambda b, j, pt, qp, act: (pt[b, j], 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
+        num_scalar_prefetch=3,
+        grid=(b, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, j, pt: (b, 0),
-                         memory_space=pltpu.SMEM),  # qpos
-            pl.BlockSpec((1, 1), lambda b, h, j, pt: (b, 0),
-                         memory_space=pltpu.SMEM),  # active
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, j, pt: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, j, pt: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, j, pt: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps), lambda b, h, j, pt: (pt[b, j], 0)),
+            pl.BlockSpec((1, kvh, g, hd), lambda b, j, pt, qp, act:
+                         (b, 0, 0, 0)),
+            pl.BlockSpec((1, ps, kvh, hd), page),
+            pl.BlockSpec((1, ps, kvh, hd), page),
+            pl.BlockSpec((1, 1, ps), lambda b, j, pt, qp, act:
+                         (pt[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda b, h, j, pt: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),   # running max m
-            pltpu.VMEM((g, 1), jnp.float32),   # running sum l
-            pltpu.VMEM((g, hd), jnp.float32),  # output accumulator
-        ],
+        out_specs=pl.BlockSpec((1, kvh, g, hd), lambda b, j, pt, qp, act:
+                               (b, 0, 0, 0)),
+        scratch_shapes=_scratch(kvh, g, hd),
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
         interpret=interpret,
-    )(page_table, qpos, active, q, k, v, kpos)
+    )(page_table, qpos, active, q, k, v, kpos.reshape(n_pages, 1, ps))
 
 
 @functools.partial(
@@ -312,7 +286,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     """q: (B, KVH, G, hd) pre-scaled grouped queries; k/v: (B, Sk, KVH, hd).
 
     kpos: (B, Sk) int32 absolute key positions (2^30 = never written);
-    qpos: (B, 1) int32 query position; active: (B, 1) int32 row gate.
+    qpos: (B,) int32 query position; active: (B,) int32 row gate.
     Sk % bs == 0 (ops.py pads with the kpos sentinel).  Returns
     (B, KVH, G, hd) in q.dtype.  ops.py handles layout, padding and GQA
     head-group reshapes.
@@ -321,27 +295,26 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     sk = k.shape[1]
     assert sk % bs == 0, (sk, bs)
     n_s = sk // bs
-    grid = (b, kvh, n_s)
-    kern = functools.partial(_kernel, n_s=n_s, bs=bs, window=window)
+    kern = functools.partial(_kernel, n_s=n_s, window=window)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, n_s),
+        in_specs=[
+            pl.BlockSpec((1, kvh, g, hd), lambda b, j, qp, act:
+                         (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, kvh, hd), lambda b, j, qp, act:
+                         (b, j, 0, 0)),
+            pl.BlockSpec((1, bs, kvh, hd), lambda b, j, qp, act:
+                         (b, j, 0, 0)),
+            pl.BlockSpec((1, 1, bs), lambda b, j, qp, act: (b, 0, j)),
+        ],
+        out_specs=pl.BlockSpec((1, kvh, g, hd), lambda b, j, qp, act:
+                               (b, 0, 0, 0)),
+        scratch_shapes=_scratch(kvh, g, hd),
+    )
     return pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, j: (b, 0),
-                         memory_space=pltpu.SMEM),  # qpos
-            pl.BlockSpec((1, 1), lambda b, h, j: (b, 0),
-                         memory_space=pltpu.SMEM),  # active
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bs), lambda b, h, j: (b, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),   # running max m
-            pltpu.VMEM((g, 1), jnp.float32),   # running sum l
-            pltpu.VMEM((g, hd), jnp.float32),  # output accumulator
-        ],
         interpret=interpret,
-    )(qpos, active, q, k, v, kpos)
+    )(qpos, active, q, k, v, kpos.reshape(b, 1, sk))

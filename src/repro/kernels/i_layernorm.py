@@ -23,14 +23,16 @@ def _i_sqrt_block(n: jax.Array) -> jax.Array:
     bits = jnp.ceil(jnp.log2(jnp.maximum(n, 1).astype(jnp.float32) + 1.0))
     x0 = jnp.maximum(jnp.exp2(jnp.ceil(bits / 2.0)).astype(jnp.int32), 1)
 
+    # the converged flag rides as int32: Mosaic cannot carry a vector of
+    # bools through a loop (scf.for with an i1 vector fails to legalize)
     def body(_, carry):
         x, done = carry
         nx = (x + n // jnp.maximum(x, 1)) >> 1
-        newdone = done | (nx >= x)
-        return jnp.where(newdone, x, nx), newdone
+        newdone = done | (nx >= x).astype(jnp.int32)
+        return jnp.where(newdone != 0, x, nx), newdone
 
     x, _ = jax.lax.fori_loop(0, _ISQRT_ITERS, body,
-                             (x0, jnp.zeros(n.shape, dtype=bool)))
+                             (x0, jnp.zeros(n.shape, jnp.int32)))
     return jnp.where(n == 0, 0, x)
 
 

@@ -217,18 +217,18 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         # pad keys carry the never-written sentinel: masked, not attended
         kpos = jnp.pad(kpos, ((0, 0), (0, pad)),
                        constant_values=_fd.KPOS_SENTINEL)
-    act = (jnp.ones((b, 1), jnp.int32) if active is None
-           else active.astype(jnp.int32).reshape(b, 1))
+    act = (jnp.ones((b,), jnp.int32) if active is None
+           else active.astype(jnp.int32).reshape(b))
     out = _fd.flash_decode(
-        qg, k, v, kpos.astype(jnp.int32),
-        qpos.astype(jnp.int32).reshape(b, 1), act,
+        qg, k, v, kpos.astype(jnp.int32), qpos.astype(jnp.int32), act,
         window=window, bs=bs, interpret=impl == "interpret")
     return out[:, :, :g].reshape(b, h, hd)
 
 
-def _shard_map_heads(call, mesh, axis, q, arena_and_rest, arena_specs):
+def _shard_map_heads(call, mesh, axis, q, arenas, rest):
     """Dispatch a paged-decode call under shard_map with the kv-head axis
-    partitioned over `axis` and the page table / positions replicated.
+    of q and of the `arenas` leaves partitioned over `axis` and `rest`
+    (positions, page table, lane gate) replicated.
 
     `q` is (B, H, hd) with heads laid out kvh-major (ops reshape to
     (B, KVH, G, hd) below), so a contiguous H split is exactly a KV-head
@@ -237,23 +237,23 @@ def _shard_map_heads(call, mesh, axis, q, arena_and_rest, arena_specs):
     never crosses the axis, which is what keeps the sharded dispatch
     bit-identical to the unsharded one.  This is the SPMD form of the
     paper's scatter-GMI -> per-head kernels -> gather-GMI pipeline stage.
+
+    Head counts that don't divide the axis keep every operand replicated
+    (the plan replicated the arena then too): each device runs the whole
+    call.  It still goes through shard_map because the partitioner cannot
+    split a Mosaic kernel by itself.
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.pipeline import shard_map_compat
-
     n = mesh.shape[axis]
-    b, h, _ = q.shape
-    kvh = arena_and_rest[0].shape[2]
-    assert h % n == 0 and kvh % n == 0, (
-        f"head axes (H={h}, KVH={kvh}) must divide mesh axis "
-        f"'{axis}' ({n}); the caller should fall back to the unsharded "
-        "dispatch instead")
-    return shard_map_compat(
-        call, mesh,
-        in_specs=(P(None, axis),) + arena_specs,
-        out_specs=P(None, axis),
-    )(q, *arena_and_rest)
+    if q.shape[1] % n or arenas[0].shape[2] % n:
+        axis = None
+    return jax.shard_map(
+        call, mesh=mesh,
+        in_specs=((P(None, axis),) + (P(None, None, axis),) * len(arenas)
+                  + (P(),) * len(rest)),
+        out_specs=P(None, axis), check_vma=False,
+    )(q, *arenas, *rest)
 
 
 def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -278,12 +278,12 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     path.
 
     mesh/axis: dispatch under shard_map with the arena's kv-head dim (and
-    q's head dim) partitioned over `axis` and kpos/page_table/qpos/active
-    replicated — the plan-sharded serving path (`_shard_map_heads`).
+    q's head dim) partitioned over `axis` when the head counts divide it
+    and kpos/page_table/qpos/active replicated — the plan-sharded serving
+    path (`_shard_map_heads`).
     """
     impl = impl or default_impl()
     if mesh is not None and axis is not None and mesh.shape[axis] > 1:
-        from jax.sharding import PartitionSpec as P
         b = q.shape[0]
         act = (jnp.ones((b,), bool) if active is None
                else active.astype(bool))
@@ -292,9 +292,8 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             return paged_flash_decode(qb, kb, vb, kpb, ptb, qpb,
                                       active=ab, impl=impl)
 
-        return _shard_map_heads(
-            call, mesh, axis, q, (k, v, kpos, page_table, qpos, act),
-            (P(None, None, axis), P(None, None, axis), P(), P(), P(), P()))
+        return _shard_map_heads(call, mesh, axis, q, (k, v),
+                                (kpos, page_table, qpos, act))
     if impl == "ref":
         return _ref.paged_flash_decode(q, k, v, kpos, page_table, qpos,
                                        active=active)
@@ -307,12 +306,11 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     gp = _rup(g, 8)  # group dim is the sublane axis: pad to tile granularity
     if gp != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    act = (jnp.ones((b, 1), jnp.int32) if active is None
-           else active.astype(jnp.int32).reshape(b, 1))
+    act = (jnp.ones((b,), jnp.int32) if active is None
+           else active.astype(jnp.int32).reshape(b))
     out = _fd.paged_flash_decode(
         qg, k, v, kpos.astype(jnp.int32), page_table.astype(jnp.int32),
-        qpos.astype(jnp.int32).reshape(b, 1), act,
-        interpret=impl == "interpret")
+        qpos.astype(jnp.int32), act, interpret=impl == "interpret")
     return out[:, :, :g].reshape(b, h, hd)
 
 
@@ -337,7 +335,6 @@ def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,
     impl = impl or default_impl()
     assert k.dtype == jnp.int8 and v.dtype == jnp.int8, (k.dtype, v.dtype)
     if mesh is not None and axis is not None and mesh.shape[axis] > 1:
-        from jax.sharding import PartitionSpec as P
         b = q.shape[0]
         act = (jnp.ones((b,), bool) if active is None
                else active.astype(bool))
@@ -348,11 +345,9 @@ def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,
 
         # scale planes (P, ps, KVH) ride the same kv-head partition as the
         # int8 values they dequantize
-        return _shard_map_heads(
-            call, mesh, axis, q,
-            (k, v, k_scale, v_scale, kpos, page_table, qpos, act),
-            (P(None, None, axis), P(None, None, axis), P(None, None, axis),
-             P(None, None, axis), P(), P(), P(), P()))
+        return _shard_map_heads(call, mesh, axis, q,
+                                (k, v, k_scale, v_scale),
+                                (kpos, page_table, qpos, act))
     if impl == "ref":
         out = _ref.paged_flash_decode_q(q, k, v, k_scale, v_scale, kpos,
                                         page_table, qpos, active=active)
@@ -366,13 +361,12 @@ def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,
     gp = _rup(g, 8)  # group dim is the sublane axis: pad to tile granularity
     if gp != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    act = (jnp.ones((b, 1), jnp.int32) if active is None
-           else active.astype(jnp.int32).reshape(b, 1))
+    act = (jnp.ones((b,), jnp.int32) if active is None
+           else active.astype(jnp.int32).reshape(b))
     out = _fd.paged_flash_decode_q(
         qg, k, v, k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
         kpos.astype(jnp.int32), page_table.astype(jnp.int32),
-        qpos.astype(jnp.int32).reshape(b, 1), act,
-        interpret=impl == "interpret")
+        qpos.astype(jnp.int32), act, interpret=impl == "interpret")
     return out[:, :, :g].reshape(b, h, hd)
 
 

@@ -15,39 +15,27 @@ Cluster Builder requests stage parallelism.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API; absent on older jaxlibs
-    from jax.sharding import AxisType
-except (ImportError, AttributeError):  # deprecation shims raise AttributeError
-    AxisType = None
-
-
-def _axis_types_kw(n: int) -> dict:
-    return {} if AxisType is None else {"axis_types": (AxisType.Auto,) * n}
+from jax.sharding import AbstractMesh, AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """Arbitrary mesh (tests, elastic re-meshing)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_types_kw(len(shape)))
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
     """Device-free mesh for spec-only planning (tests, plan inspection)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:  # older jax: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def required_devices(multi_pod: bool = False) -> int:

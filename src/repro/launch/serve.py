@@ -38,6 +38,7 @@ import jax
 
 from repro.configs import get_config
 from repro.core.cluster_builder import build_plan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.transformer import init_params, make_model
 from repro.runtime.stragglers import StragglerMonitor
@@ -182,6 +183,9 @@ def _dryrun(cfg, plan, paged: bool, engine_kw) -> None:
 
 
 def main(argv=None):
+    """Parse argv, build the engine (or fleet), serve one request stream
+    and print its summary.  Returns (finished requests, the engine or
+    fleet router that served them)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--reduced", action="store_true")
@@ -294,6 +298,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.no_plan:
         args.plan = "none"
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -391,7 +396,7 @@ def main(argv=None):
                              "--plan serve or serve_pipeline")
         _dryrun(cfg, plan, paged,
                 dict(page_size=args.page_size, kv_dtype=args.kv_dtype))
-        return []
+        return [], None
 
     model = make_model(cfg, remat=False)
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
@@ -485,7 +490,7 @@ def main(argv=None):
                   f"wall={p['wall_s']*1e3:.0f}ms")
         for req, reason in engine.shed[:3]:
             print(f"  shed rid={req.rid}: {reason}")
-        return done
+        return done, engine
     lat = sorted((r.t_done - r.t_enqueue) * 1e3 for r in done)
     ttft = sorted((r.t_first_token - r.t_enqueue) * 1e3 for r in done)
     print(f"serve[{args.engine}]: arch={cfg.name} plan={args.plan} "
@@ -494,7 +499,7 @@ def main(argv=None):
           f"ttft_p50={ttft[len(ttft)//2]:.0f}ms "
           f"p50={lat[len(lat)//2]:.0f}ms p_max={lat[-1]:.0f}ms "
           f"stats={engine.stats}")
-    return done
+    return done, engine
 
 
 if __name__ == "__main__":

@@ -30,6 +30,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.core.cluster_builder import build_plan
 from repro.data.pipeline import TokenPipeline, shard_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import Model, init_params, make_model
 from repro.optim.optimizer import (
     clip_by_global_norm, cosine_schedule, make_optimizer,
@@ -192,6 +193,7 @@ def main(argv=None) -> Dict[str, Any]:
     ap.add_argument("--inject-failure-at", type=int, default=-1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -293,10 +293,8 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
             ck = ck.at[wr_page, wr_off].set(kf.astype(ck.dtype))
             cv = cv.at[wr_page, wr_off].set(vf.astype(cv.dtype))
         route = "pallas" if (impl == "pallas" and cfg.causal) else "ref"
-        mesh_kw = {}
         ctx = paged_shard_ctx()
-        if ctx is not None and nkv % ctx[2] == 0 and nh % ctx[2] == 0:
-            mesh_kw = {"mesh": ctx[0], "axis": ctx[1]}
+        mesh_kw = {} if ctx is None else {"mesh": ctx[0], "axis": ctx[1]}
         # fold the T contiguous queries into the batch axis: ONE kernel
         # dispatch for the whole block — (B*T) lanes sharing the arena,
         # each query attending at its own absolute position.  Per-row
@@ -402,12 +400,10 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
         # dispatch runs under shard_map — each model shard walks the
         # (replicated) page table over its own kv heads, the SPMD form of
         # the paper's per-head dotprod_softmax kernels behind the scatter
-        # GMI.  Falls back to the unsharded call when the head counts
-        # don't divide the axis (the plan replicated the arena then too).
-        mesh_kw = {}
+        # GMI.  Head counts that don't divide the axis run the whole call
+        # on every device (the plan replicated the arena then too).
         ctx = paged_shard_ctx()
-        if ctx is not None and nkv % ctx[2] == 0 and nh % ctx[2] == 0:
-            mesh_kw = {"mesh": ctx[0], "axis": ctx[1]}
+        mesh_kw = {} if ctx is None else {"mesh": ctx[0], "axis": ctx[1]}
         if quantized:
             out = kops.paged_flash_decode_q(
                 qs[:, 0], ck, cv, cks, cvs, kpos, page_table, cpos,

@@ -710,9 +710,7 @@ class Executor:
         replicated, making the pipelined stream bit-identical to the
         single-device fused loop.
         """
-        from repro.core.pipeline import (
-            gpipe_forward_perm, pipeline_steps, shard_map_compat,
-        )
+        from repro.core.pipeline import gpipe_forward_perm, pipeline_steps
         from repro.models.layers import lm_head, norm
         from repro.models.transformer import block_apply
 
@@ -814,10 +812,11 @@ class Executor:
 
         def fn(params, caches, token, active, eos, budget):
             rest_p = {k: v for k, v in params.items() if k != "scan"}
-            toks, cur, act, rem, pos, sc = shard_map_compat(
-                body, mesh,
+            toks, cur, act, rem, pos, sc = jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(P(axis), P(), P(axis), P(), P(), P(), P(), P()),
                 out_specs=(P(), P(), P(), P(), P(), P(axis)),
+                check_vma=False,
             )(params["scan"], rest_p, caches["scan"], caches["pos"],
               token, active, eos, budget)
             return toks, cur, act, rem, {"scan": sc, "tail": {}, "pos": pos}
@@ -866,7 +865,7 @@ class Executor:
         (kv_manager.kv_page_bytes(shards=)); the page table and counters
         stay replicated routing metadata.
         """
-        from repro.core.pipeline import gpipe_forward_perm, shard_map_compat
+        from repro.core.pipeline import gpipe_forward_perm
         from repro.models.layers import lm_head, norm
         from repro.models.transformer import block_apply
 
@@ -1000,11 +999,12 @@ class Executor:
         def fn(params, caches, token, active, eos, budget, forced, flen,
                fptr):
             rest_p = {k: v for k, v in params.items() if k != "scan"}
-            toks, cur, act, rem, fp, pos, sc = shard_map_compat(
-                body, mesh,
+            toks, cur, act, rem, fp, pos, sc = jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(P(axis), P(), P(axis), P(), P(), P(), P(), P(),
                           P(), P(), P(), P()),
                 out_specs=(P(), P(), P(), P(), P(), P(), P(axis)),
+                check_vma=False,
             )(params["scan"], rest_p, caches["scan"], caches["pt"],
               caches["pos"], token, active, eos, budget, forced, flen,
               fptr)

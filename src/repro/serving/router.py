@@ -38,10 +38,12 @@ import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.serving.engine import ContinuousBatchingEngine
-from repro.serving.replica import Replica
+from repro.serving.replica import (Replica, make_group_mesh,
+                                   replica_device_groups)
 from repro.serving.scheduler import Request
 
 ROUTE_POLICIES = ("affinity", "round-robin", "least-loaded")
@@ -261,8 +263,17 @@ def build_fleet(model, params, n: int, *,
                 **engine_kw) -> FleetRouter:
     """N fresh engines (shared read-only model/params, per-replica plan)
     behind one router.  `plans[i]` places replica i on its device group
-    (serving/replica.py `replica_device_groups` + `make_group_mesh`);
-    None serves every replica from the default device."""
+    (serving/replica.py `replica_device_groups` + `make_group_mesh`).
+    With no plans, a host with at least N devices gives each replica one
+    device of its own (a one-device serve plan, what `--plan serve --mesh
+    1,1` builds per replica); a smaller host serves every replica from
+    the default device."""
+    if plans is None and n > 1 and len(jax.devices()) >= n:
+        from repro.core.cluster_builder import build_plan
+        plans = [build_plan(model.cfg,
+                            make_group_mesh(g, (1, 1), ("data", "model")),
+                            mode="serve")
+                 for g in replica_device_groups(n)]
     plans = list(plans) if plans is not None else [None] * n
     if len(plans) != n:
         raise ValueError(f"fleet: {n} replicas but {len(plans)} plans")

@@ -7,6 +7,11 @@ one replica (more tree hits than the round-robin control); shedding and
 stale-affinity fallback degrade politely (reason strings and cold
 prefills, never errors).
 """
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
@@ -261,3 +266,52 @@ def test_fleet_on_disjoint_device_groups(setup):
         fleet.submit(r)
     got = {r.rid: r.tokens_out for r in fleet.run()}
     assert got == want
+
+
+def test_fleet_without_plans_spreads_replicas_over_devices():
+    """plans=None on a host with >= N devices: every replica's params and
+    arena sit on a device of their own, and the streams still match one
+    plain engine (forced 4-device host, so a subprocess)."""
+    script = """
+    import jax, numpy as np
+    from repro.configs import get_config
+    from repro.models.transformer import init_params, make_model
+    from repro.serving.engine import ContinuousBatchingEngine
+    from repro.serving.router import FleetConfig, build_fleet
+    from repro.serving.stream import clone_requests, shared_prefix_requests
+    kw = dict(max_batch=2, buckets=(16, 32, 64), num_pages=64)
+    cfg = get_config("smollm-135m").reduced()
+    model = make_model(cfg, remat=False)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    stream = shared_prefix_requests(np.random.default_rng(4), 8,
+                                    cfg.vocab_size, prefix_len=24,
+                                    suffix_range=(2, 6), budgets=4)
+    fleet = build_fleet(model, params, 4,
+                        config=FleetConfig(route="round-robin"), **kw)
+    for r in clone_requests(stream):
+        fleet.submit(r)
+    got = {r.rid: r.tokens_out for r in fleet.run()}
+    homes = []
+    for rep in fleet.replicas:
+        ex = rep.engine.executor
+        devs = {d for leaf in jax.tree.leaves((ex.params,
+                                               rep.engine._slot_caches))
+                for d in leaf.devices()}
+        assert len(devs) == 1, devs
+        homes.append(devs.pop())
+    assert len(set(homes)) == 4, homes
+    plain = ContinuousBatchingEngine(model, params, **kw)
+    for r in clone_requests(stream):
+        plain.submit(r)
+    assert got == {r.rid: r.tokens_out for r in plain.run()}
+    print("OK", sorted(d.id for d in homes))
+    """
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stdout + "\n" + out.stderr
+    assert "OK [0, 1, 2, 3]" in out.stdout

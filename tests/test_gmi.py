@@ -26,13 +26,12 @@ PRELUDE = """
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.core import gmi
-from repro.core.pipeline import shard_map_compat
 from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 4), ("pod", "data"))
 x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4) + 1.0
 def run(fn, in_spec, out_spec):
-    return shard_map_compat(fn, mesh=mesh, in_specs=(in_spec,),
-                            out_specs=out_spec)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(in_spec,),
+                         out_specs=out_spec, check_vma=False)
 """
 
 

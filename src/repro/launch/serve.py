@@ -41,7 +41,6 @@ from repro.core.cluster_builder import build_plan
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.transformer import init_params, make_model
-from repro.runtime.stragglers import StragglerMonitor
 from repro.serving.engine import ContinuousBatchingEngine, WaveEngine
 from repro.serving.kv_manager import paged_eligible
 from repro.serving.stream import (bursty_requests, poisson_requests,
@@ -400,7 +399,6 @@ def main(argv=None):
 
     model = make_model(cfg, remat=False)
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
-    monitor = StragglerMonitor()
     cls = ContinuousBatchingEngine if args.engine == "cb" else WaveEngine
     kw = {}
     if cls is ContinuousBatchingEngine:
@@ -446,12 +444,12 @@ def main(argv=None):
                                shed_depth=args.shed_depth,
                                shed_budget=args.shed_budget),
             max_batch=max_batch, buckets=(16, 32, 64, 128),
-            monitor=monitor, decode_horizon=args.decode_horizon,
+            decode_horizon=args.decode_horizon,
             quant_weights=args.quant_weights, **kw)
         engine = router
     else:
         engine = cls(model, params, max_batch=max_batch,
-                     buckets=(16, 32, 64, 128), plan=plan, monitor=monitor,
+                     buckets=(16, 32, 64, 128), plan=plan,
                      decode_horizon=args.decode_horizon,
                      quant_weights=args.quant_weights, **kw)
 

@@ -19,11 +19,11 @@ import numpy as np
 
 from repro.core.packing import bucket_len
 from repro.models.transformer import Model
-from repro.runtime.stragglers import StragglerMonitor
 from repro.serving.executor import PAD_TOKEN, Executor
 from repro.serving.kv_manager import (KVManager, kv_page_bytes,
                                       num_pages_for_hbm, paged_eligible)
 from repro.serving.scheduler import Request, Scheduler
+from repro.serving.tracing import PHASES, loop_clock, span
 
 __all__ = ["ContinuousBatchingEngine", "WaveEngine", "ServingEngine",
            "Request", "EngineBase", "PAD_TOKEN", "kv_page_bytes",
@@ -37,14 +37,12 @@ class EngineBase:
                  buckets=(32, 64, 128, 256), greedy: bool = True,
                  deadline_s: float = 0.05, plan=None,
                  max_decode_len: int = 64, decode_horizon: int = 8,
-                 monitor: Optional[StragglerMonitor] = None,
                  quant_weights: bool = False):
         self.model = model
         self.max_batch = max_batch
         self.buckets = tuple(sorted(buckets))
         self.greedy = greedy
         self.plan = plan
-        self.monitor = monitor
         self.quant_weights = bool(quant_weights)
         self.paged = False  # ContinuousBatchingEngine may flip this
         # slot rows: prompt KV + decode headroom, fixed per engine
@@ -58,8 +56,11 @@ class EngineBase:
                                  max_batch=max_batch,
                                  cache_len=self.cache_len,
                                  buckets=self.buckets)
+        # counts, then the phase counters (tracing.py): host seconds of
+        # each part of run(), added as the work happens
         self.stats = {"prefill_tokens": 0, "decode_steps": 0,
-                      "decode_dispatches": 0, "device_syncs": 0}
+                      "decode_dispatches": 0, "device_syncs": 0,
+                      **dict.fromkeys(PHASES, 0.0), "gc_collections": 0}
 
     params = property(lambda self: self.executor.params)
     policy = property(lambda self: self.sched.policy)
@@ -87,7 +88,17 @@ class EngineBase:
 
     def _greedy_next(self, logits) -> np.ndarray:
         self.stats["device_syncs"] += 1
-        return np.asarray(jnp.argmax(logits, -1), np.int32)
+        with span("first_token_sync", self.stats, "sync_s"):
+            return np.asarray(jnp.argmax(logits, -1), np.int32)
+
+    def _fetch_block(self, toks, t_step: float) -> np.ndarray:
+        """The block sync, the only per-dispatch device-to-host read; it
+        closes the dispatch's decode_s, counted from `t_step`, taken
+        just before the dispatch was enqueued."""
+        with span("block_sync", self.stats, "sync_s"):
+            block = np.asarray(toks)
+        self.stats["decode_s"] += time.perf_counter() - t_step
+        return block
 
 
 class ContinuousBatchingEngine(EngineBase):
@@ -269,14 +280,16 @@ class ContinuousBatchingEngine(EngineBase):
 
     def _admit_dense(self, r: Request, sl: int, st) -> bool:
         """Batch-1 prefill + insert into slot `sl`; TTFT paid here."""
-        logits, small = self._prefill([r.prompt], 1, bucket_cache=True)
-        st["caches"] = self.executor.insert(st["caches"], small, sl)
-        self.stats["prefills"] += 1
-        self.stats["admitted"] += 1
-        self._first_token(r, int(self._greedy_next(logits)[0]))
+        with span("cold_admission", self.stats, "prefill_s", rid=r.rid):
+            logits, small = self._prefill([r.prompt], 1, bucket_cache=True)
+            st["caches"] = self.executor.insert(st["caches"], small, sl)
+            self.stats["prefills"] += 1
+            self.stats["admitted"] += 1
+            self._first_token(r, int(self._greedy_next(logits)[0]))
         if not r.done:
-            self.executor.admit_lane(st, sl, r.tokens_out[-1], r.eos_id,
-                                     r.remaining())
+            with span("admit_lane", rid=r.rid):
+                self.executor.admit_lane(st, sl, r.tokens_out[-1], r.eos_id,
+                                         r.remaining())
         return True
 
     def _admit_paged(self, r: Request, sl: int, st) -> bool:
@@ -292,33 +305,40 @@ class ContinuousBatchingEngine(EngineBase):
         self.stats["admitted"] += 1
         if grant.hit_len:
             suffix = prompt[grant.hit_len:]
-            self.executor.admit_hit(st, sl, grant.pt_row, grant.hit_len,
-                                    grant.reset)
-            self.executor.admit_lane_paged(st, sl, int(suffix[0]), r.eos_id,
-                                           r.remaining(), suffix[1:],
-                                           len(suffix) - 1)
+            with span("admit_hit", rid=r.rid):
+                self.executor.admit_hit(st, sl, grant.pt_row, grant.hit_len,
+                                        grant.reset)
+            with span("admit_lane", rid=r.rid):
+                self.executor.admit_lane_paged(
+                    st, sl, int(suffix[0]), r.eos_id, r.remaining(),
+                    suffix[1:], len(suffix) - 1)
             self.sched.lane_forced[sl] = len(suffix) - 1
             self.stats["prefix_hits"] += 1
             self.stats["prefix_hit_tokens"] += int(grant.hit_len)
             r.t_admitted = time.perf_counter()
         else:
-            logits, small = self._prefill([prompt], 1, bucket_cache=True)
-            bucket = bucket_len(len(prompt), self.buckets, lane=8)
-            n_wp = min(self.kv.pages_for(bucket), len(grant.pages))
-            if self.disagg is not None:
-                self._ship_cold(st, sl, small, grant, prompt, bucket, n_wp)
-            else:
-                self.executor.admit_cold(
-                    st, sl, small, grant.pt_row, len(prompt), grant.reset,
-                    np.asarray(grant.pages[:n_wp], np.int32), bucket)
-            self.stats["prefills"] += 1
-            self.kv.register_prefix(prompt, grant.pages)
-            self._first_token(r, int(self._greedy_next(logits)[0]))
+            with span("cold_admission", self.stats, "prefill_s", rid=r.rid):
+                logits, small = self._prefill([prompt], 1, bucket_cache=True)
+                bucket = bucket_len(len(prompt), self.buckets, lane=8)
+                n_wp = min(self.kv.pages_for(bucket), len(grant.pages))
+                with span("admit_cold", rid=r.rid):
+                    if self.disagg is not None:
+                        self._ship_cold(st, sl, small, grant, prompt, bucket,
+                                        n_wp)
+                    else:
+                        self.executor.admit_cold(
+                            st, sl, small, grant.pt_row, len(prompt),
+                            grant.reset,
+                            np.asarray(grant.pages[:n_wp], np.int32), bucket)
+                self.stats["prefills"] += 1
+                self.kv.register_prefix(prompt, grant.pages)
+                self._first_token(r, int(self._greedy_next(logits)[0]))
             self.sched.lane_forced[sl] = 0
             if not r.done:
-                self.executor.admit_lane_paged(
-                    st, sl, r.tokens_out[-1], r.eos_id, r.remaining(),
-                    np.zeros((0,), np.int32), 0)
+                with span("admit_lane", rid=r.rid):
+                    self.executor.admit_lane_paged(
+                        st, sl, r.tokens_out[-1], r.eos_id, r.remaining(),
+                        np.zeros((0,), np.int32), 0)
         self.kv.commit(sl, grant)
         if self.spec and not r.done:
             self._admit_draft(r, sl, st, grant, prompt)
@@ -411,15 +431,13 @@ class ContinuousBatchingEngine(EngineBase):
             self._tpos[i] += min(self.sched.lane_forced[i], n) + emitted
 
     def _reconcile(self, toks, slots, done, n: int, t_step: float) -> None:
-        block = np.asarray(toks)  # the only per-dispatch device sync
-        if self.monitor is not None:
-            self.monitor.observe(self.stats["decode_steps"] + n,
-                                 (time.perf_counter() - t_step) / n)
-        if self.spec:  # spec-disabled dispatch: draft lags, catchup later
-            self._advance_mirrors(block, slots, n)
-        self.sched.reconcile(block, slots, done, n, self.stats,
-                             time.perf_counter(), self.paged,
-                             self._release if self.paged else None)
+        block = self._fetch_block(toks, t_step)
+        with span("reconcile", self.stats, "reconcile_s"):
+            if self.spec:  # spec-disabled dispatch: draft lags, catchup later
+                self._advance_mirrors(block, slots, n)
+            self.sched.reconcile(block, slots, done, n, self.stats,
+                                 time.perf_counter(), self.paged,
+                                 self._release if self.paged else None)
 
     def _reconcile_spec(self, toks, slots, done, k: int,
                         t_step: float) -> None:
@@ -427,28 +445,26 @@ class ContinuousBatchingEngine(EngineBase):
         acceptance feedback drives the per-lane depth ladder, and the
         position mirrors advance in lockstep on both caches (the device
         rewound them together)."""
-        block = np.asarray(toks)  # the only per-dispatch device sync
-        if self.monitor is not None:
-            self.monitor.observe(self.stats["decode_steps"] + k + 1,
-                                 (time.perf_counter() - t_step) / (k + 1))
-        self.stats["spec_dispatches"] += 1
-        self.stats["spec_draft_steps"] += k + 1
-        self._advance_mirrors(block, slots, k + 1)
-        for i, r in enumerate(slots):
-            if r is None:
-                continue
-            self._dpos[i] = self._tpos[i]  # verify + rewind keep them equal
-            emitted = int((block[:, i] >= 0).sum())
-            if emitted >= 1 and self.sched.lane_forced[i] == 0:
-                # emitted = 1 guaranteed + accepted drafts (+ bonus);
-                # forced-ingest dispatches say nothing about the draft
-                accepted = min(emitted - 1, k)
-                self.stats["spec_accepted"] += accepted
-                self.stats["spec_proposed"] += k
-                self.sched.observe_acceptance(i, accepted, k)
-        self.sched.reconcile(block, slots, done, k + 1, self.stats,
-                             time.perf_counter(), self.paged,
-                             self._release)
+        block = self._fetch_block(toks, t_step)
+        with span("reconcile", self.stats, "reconcile_s"):
+            self.stats["spec_dispatches"] += 1
+            self.stats["spec_draft_steps"] += k + 1
+            self._advance_mirrors(block, slots, k + 1)
+            for i, r in enumerate(slots):
+                if r is None:
+                    continue
+                self._dpos[i] = self._tpos[i]  # verify + rewind: equal
+                emitted = int((block[:, i] >= 0).sum())
+                if emitted >= 1 and self.sched.lane_forced[i] == 0:
+                    # emitted = 1 guaranteed + accepted drafts (+ bonus);
+                    # forced-ingest dispatches say nothing about the draft
+                    accepted = min(emitted - 1, k)
+                    self.stats["spec_accepted"] += accepted
+                    self.stats["spec_proposed"] += k
+                    self.sched.observe_acceptance(i, accepted, k)
+            self.sched.reconcile(block, slots, done, k + 1, self.stats,
+                                 time.perf_counter(), self.paged,
+                                 self._release)
 
     def _spec_catchup(self, slots, st) -> None:
         """Feed draft lanes the stream tokens the target consumed during
@@ -508,42 +524,49 @@ class ContinuousBatchingEngine(EngineBase):
         for r in pending:  # latency clocks start at simulated arrival
             r.t_enqueue = max(r.t_enqueue, t0 + r.t_arrival)
 
-        while pending or any(r is not None for r in slots):
-            now = time.perf_counter() - t0
-            free = self.sched.order_free(
-                [i for i, r in enumerate(slots) if r is None], slots)
-            admitted, starved = self.sched.admission_cycle(
-                pending, free, now, self.executor.warm_buckets,
-                lambda r, sl: admit(r, sl, st))
-            for r, sl in admitted:
-                pending.remove(r)
-                if r.done:  # budget of 1 / instant EOS at admission
-                    done.append(r)
-                    if self.paged:
-                        self._release(sl)
-                    free.insert(0, sl)
-                    self.stats["completed"] += 1
-                else:
-                    slots[sl] = r
-            if self.sched.should_preempt(starved, now):
-                self._preempt(slots, pending, st)
-            if not any(r is not None for r in slots):
-                self.sched.idle_wait(pending, starved,
-                                     time.perf_counter() - t0)
-                continue
+        s = self.stats
+        with loop_clock(s):
+            while pending or any(r is not None for r in slots):
+                now = time.perf_counter() - t0
+                with span("loop_control"):
+                    free = self.sched.order_free(
+                        [i for i, r in enumerate(slots) if r is None], slots)
+                with span("admission_cycle", s, "admit_s"):
+                    admitted, starved = self.sched.admission_cycle(
+                        pending, free, now, self.executor.warm_buckets,
+                        lambda r, sl: admit(r, sl, st))
+                    for r, sl in admitted:
+                        pending.remove(r)
+                        if r.done:  # budget of 1 / instant EOS at admission
+                            done.append(r)
+                            if self.paged:
+                                self._release(sl)
+                            free.insert(0, sl)
+                            s["completed"] += 1
+                        else:
+                            slots[sl] = r
+                    if self.sched.should_preempt(starved, now):
+                        self._preempt(slots, pending, st)
+                if not any(r is not None for r in slots):
+                    with span("idle_wait", s, "idle_s"):
+                        self.sched.idle_wait(pending, starved,
+                                             time.perf_counter() - t0)
+                    continue
 
-            k = (self.sched.spec_depth(slots, starved is not None)
-                 if self.spec else 0)
-            t_step = time.perf_counter()
-            if k:
-                self._spec_catchup(slots, st)
-                toks = self.executor.spec_decode(st, k)
-                self._reconcile_spec(toks, slots, done, k, t_step)
-            else:
-                n = self.sched.pick_horizon(bool(pending),
-                                            self.sched.lane_remaining(slots))
-                toks = self.executor.decode(st, n, self.paged)
-                self._reconcile(toks, slots, done, n, t_step)
+                with span("loop_control"):
+                    k = (self.sched.spec_depth(slots, starved is not None)
+                         if self.spec else 0)
+                    if not k:
+                        n = self.sched.pick_horizon(
+                            bool(pending), self.sched.lane_remaining(slots))
+                t_step = time.perf_counter()
+                if k:
+                    self._spec_catchup(slots, st)
+                    toks = self.executor.spec_decode(st, k)
+                    self._reconcile_spec(toks, slots, done, k, t_step)
+                else:
+                    toks = self.executor.decode(st, n, self.paged)
+                    self._reconcile(toks, slots, done, n, t_step)
 
         if self.paged:
             self.kv.assert_drained()

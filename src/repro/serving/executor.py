@@ -8,7 +8,10 @@ prefill, the fused decode-horizon loop (`Model.decode_steps`), the
 slot/lane admission updates, and — under a `mode="serve_pipeline"` plan —
 the stage-pipelined decode program that streams micro-steps through the
 mesh with `collective_permute` (the TPU analogue of the paper's six-FPGA
-pipelined encoder).
+pipelined encoder).  Each program is jitted from a function of its own
+name, so a profile names its module `jit_<name>` (`prefill`, `pfn` for
+the paged decode, `admit_cold`, ...); the enqueues of prefill and decode
+are the host spans `cold_prefill` and `decode_dispatch` (tracing.py).
 
 Plan-exactness contract: under a `mode="serve"` plan every program is
 traced inside a `shard_hints.hints(serve_exact=True)` context, which (a)
@@ -44,6 +47,7 @@ from repro.models import shard_hints
 from repro.models.transformer import (
     Model, greedy_token_update, layer_plan,
 )
+from repro.serving.tracing import span
 
 PAD_TOKEN = 0  # fed for finished/free slot rows; their logits are never read
 
@@ -232,7 +236,7 @@ class Executor:
         if key not in self._jit_prefill:
             model = self.model
 
-            def fn(params, tokens, positions, lengths):
+            def prefill(params, tokens, positions, lengths):
                 caches = model.init_cache(batch, cache_slots)
                 logits, caches = model.prefill(
                     params, caches, tokens=tokens, positions=positions,
@@ -243,7 +247,7 @@ class Executor:
             if self.plan is not None:
                 kw["in_shardings"] = (self._param_shardings, self._rep,
                                       self._rep, self._rep)
-            self._jit_prefill[key] = jax.jit(fn, **kw)
+            self._jit_prefill[key] = jax.jit(prefill, **kw)
         return self._jit_prefill[key]
 
     def prefill_prompts(self, prompts, batch: int,
@@ -254,26 +258,27 @@ class Executor:
         admission path pads it up to the slot row on insert); otherwise
         the cache spans cache_len and is decoded into directly (waves).
         """
-        maxlen = max(len(p) for p in prompts)
-        bucket = bucket_len(maxlen, self.buckets, lane=8)
-        cache_slots = bucket if bucket_cache else self.cache_len
-        toks = np.zeros((batch, bucket), np.int32)
-        # pad positions = 2^30 so the causal mask can never attend to them
-        # (and cache slot i == position i for decode)
-        pos = np.full((batch, bucket), 2 ** 30, np.int32)
-        lengths = np.ones((batch,), np.int32)
-        for i, p in enumerate(prompts):
-            n = len(p)
-            toks[i, :n] = p
-            pos[i, :n] = np.arange(n)
-            lengths[i] = n
-        # under disagg every prefill belongs to the prefill pool: the
-        # pool-committed param copy pins the dispatch there
-        params = (self.prefill_params if self.prefill_params is not None
-                  else self.params)
-        return self._call(self._prefill_fn(bucket, batch, cache_slots),
-                          params, jnp.asarray(toks), jnp.asarray(pos),
-                          jnp.asarray(lengths))
+        with span("cold_prefill"):
+            maxlen = max(len(p) for p in prompts)
+            bucket = bucket_len(maxlen, self.buckets, lane=8)
+            cache_slots = bucket if bucket_cache else self.cache_len
+            toks = np.zeros((batch, bucket), np.int32)
+            # pad positions = 2^30 so the causal mask can never attend to
+            # them (and cache slot i == position i for decode)
+            pos = np.full((batch, bucket), 2 ** 30, np.int32)
+            lengths = np.ones((batch,), np.int32)
+            for i, p in enumerate(prompts):
+                n = len(p)
+                toks[i, :n] = p
+                pos[i, :n] = np.arange(n)
+                lengths[i] = n
+            # under disagg every prefill belongs to the prefill pool: the
+            # pool-committed param copy pins the dispatch there
+            params = (self.prefill_params if self.prefill_params is not None
+                      else self.params)
+            return self._call(self._prefill_fn(bucket, batch, cache_slots),
+                              params, jnp.asarray(toks), jnp.asarray(pos),
+                              jnp.asarray(lengths))
 
     @property
     def warm_buckets(self):
@@ -301,7 +306,9 @@ class Executor:
                     "throughput serve_pipeline runs the paged arena path"
                 self._jit_decode[key] = self._pipeline_skew_decode_fn(n)
         elif paged:
-
+            # `pfn` (jit_pfn): benchmarks/chip finds the paged decode program
+            # in a device trace by this name, and no other program's name
+            # holds it
             def pfn(params, caches, token, active, eos, budget,
                     forced, flen, fptr):
                 return model.decode_steps(
@@ -319,7 +326,7 @@ class Executor:
             self._jit_decode[key] = jax.jit(pfn, donate_argnums=(1,), **kw)
         else:
 
-            def fn(params, caches, token, active, eos, budget):
+            def decode_dense(params, caches, token, active, eos, budget):
                 return model.decode_steps(params, caches, token, active, n,
                                           eos_id=eos, budget=budget,
                                           pad_token=PAD_TOKEN)
@@ -331,26 +338,29 @@ class Executor:
                                       + (self._rep,) * 4)
                 kw["out_shardings"] = ((self._rep,) * 4
                                        + (self._cache_shardings,))
-            self._jit_decode[key] = jax.jit(fn, donate_argnums=(1,), **kw)
+            self._jit_decode[key] = jax.jit(decode_dense, donate_argnums=(1,),
+                                            **kw)
         return self._jit_decode[key]
 
     def decode(self, st: Dict[str, Any], n: int, paged: bool):
         """Run one fused dispatch against the state dict; returns the
         (n, B) token block, st updated in place."""
-        fn = self.decode_fn(n, paged)
-        if paged:
-            toks, cur, active, budget, fptr, caches = self._call(
-                fn, self.params, st["caches"], st["cur"], st["active"],
-                st["eos"], st["budget"], st["forced"], st["flen"],
-                st["fptr"])
-            st.update(caches=caches, cur=cur, active=active, budget=budget,
-                      fptr=fptr)
-        else:
-            toks, cur, active, budget, caches = self._call(
-                fn, self.params, st["caches"], st["cur"], st["active"],
-                st["eos"], st["budget"])
-            st.update(caches=caches, cur=cur, active=active, budget=budget)
-        return toks
+        with span("decode_dispatch"):
+            fn = self.decode_fn(n, paged)
+            if paged:
+                toks, cur, active, budget, fptr, caches = self._call(
+                    fn, self.params, st["caches"], st["cur"], st["active"],
+                    st["eos"], st["budget"], st["forced"], st["flen"],
+                    st["fptr"])
+                st.update(caches=caches, cur=cur, active=active,
+                          budget=budget, fptr=fptr)
+            else:
+                toks, cur, active, budget, caches = self._call(
+                    fn, self.params, st["caches"], st["cur"], st["active"],
+                    st["eos"], st["budget"])
+                st.update(caches=caches, cur=cur, active=active,
+                          budget=budget)
+            return toks
 
     def warm_ladder(self, st: Dict[str, Any], horizons) -> None:
         """Compile the whole horizon ladder + paged lane-state programs by
@@ -381,8 +391,8 @@ class Executor:
             return self._jit_spec[k]
         model, draft = self.model, self.draft_model
 
-        def fn(params, dparams, caches, dcaches, token, active, eos,
-               budget, forced, flen, fptr):
+        def spec_decode(params, dparams, caches, dcaches, token, active, eos,
+                        budget, forced, flen, fptr):
             toks, cur, act, rem, fptr, caches, dcaches, _ = \
                 model.spec_decode_step(
                     params, caches, token, active, k, draft, dparams,
@@ -397,16 +407,18 @@ class Executor:
                                   + (self._rep,) * 7)
             kw["out_shardings"] = ((self._rep,) * 5
                                    + (self._cache_shardings, self._rep))
-        self._jit_spec[k] = jax.jit(fn, donate_argnums=(2, 3), **kw)
+        self._jit_spec[k] = jax.jit(spec_decode, donate_argnums=(2, 3), **kw)
         return self._jit_spec[k]
 
     def spec_decode(self, st: Dict[str, Any], k: int):
         """One speculative dispatch; returns the (k+1, B) token block,
         st (both cache trees included) updated in place."""
-        toks, cur, active, budget, fptr, caches, dcaches = self._call(
-            self.spec_fn(k), self.params, self.draft_params, st["caches"],
-            st["draft_caches"], st["cur"], st["active"], st["eos"],
-            st["budget"], st["forced"], st["flen"], st["fptr"])
+        with span("decode_dispatch"):
+            toks, cur, active, budget, fptr, caches, dcaches = self._call(
+                self.spec_fn(k), self.params, self.draft_params,
+                st["caches"], st["draft_caches"], st["cur"], st["active"],
+                st["eos"], st["budget"], st["forced"], st["flen"],
+                st["fptr"])
         st.update(caches=caches, draft_caches=dcaches, cur=cur,
                   active=active, budget=budget, fptr=fptr)
         return toks
@@ -429,7 +441,7 @@ class Executor:
         if key not in self._jit_draft_prefill:
             draft = self.draft_model
 
-            def fn(dparams, tokens, positions, lengths):
+            def draft_prefill(dparams, tokens, positions, lengths):
                 caches = draft.init_cache(batch, bucket)
                 return draft.prefill(dparams, caches, tokens=tokens,
                                      positions=positions,
@@ -438,7 +450,7 @@ class Executor:
             kw = {}
             if self.plan is not None:
                 kw["in_shardings"] = (self._rep,) * 4
-            self._jit_draft_prefill[key] = jax.jit(fn, **kw)
+            self._jit_draft_prefill[key] = jax.jit(draft_prefill, **kw)
         toks = np.zeros((batch, bucket), np.int32)
         pos = np.full((batch, bucket), 2 ** 30, np.int32)
         lengths = np.ones((batch,), np.int32)
@@ -461,7 +473,7 @@ class Executor:
         if key not in self._jit_admit_cold_draft:
             draft = self.draft_model
 
-            def fn(big, small, slot, pt_row, pos0, reset, wp):
+            def admit_cold_draft(big, small, slot, pt_row, pos0, reset, wp):
                 return draft.admit_lane_cache(big, slot, pt_row, pos0,
                                               reset, small=small,
                                               write_pages=wp)
@@ -470,7 +482,7 @@ class Executor:
             if self.plan is not None:
                 kw["out_shardings"] = self._rep
             self._jit_admit_cold_draft[key] = jax.jit(
-                fn, donate_argnums=(0,), **kw)
+                admit_cold_draft, donate_argnums=(0,), **kw)
         st["draft_caches"] = self._call(
             self._jit_admit_cold_draft[key], st["draft_caches"], small,
             slot, jnp.asarray(pt_row), pos0, jnp.asarray(reset),
@@ -489,7 +501,7 @@ class Executor:
         if n_pad not in self._jit_catchup:
             draft = self.draft_model
 
-            def fn(dparams, dcaches, toks, lag):
+            def draft_catchup(dparams, dcaches, toks, lag):
                 def step(caches, xs):
                     tok, j = xs
                     live = j < lag
@@ -506,8 +518,8 @@ class Executor:
             if self.plan is not None:
                 kw["in_shardings"] = (self._rep,) * 4
                 kw["out_shardings"] = self._rep
-            self._jit_catchup[n_pad] = jax.jit(fn, donate_argnums=(1,),
-                                               **kw)
+            self._jit_catchup[n_pad] = jax.jit(draft_catchup,
+                                               donate_argnums=(1,), **kw)
         padded = np.zeros((tokens.shape[0], n_pad), np.int32)
         padded[:, :tokens.shape[1]] = tokens
         st["draft_caches"] = self._call(
@@ -522,13 +534,13 @@ class Executor:
         if self._jit_insert is None:
             model = self.model
 
-            def fn(big, small, slot):
+            def insert_slot(big, small, slot):
                 return model.insert_prefill_cache(big, small, slot)
 
             kw = {}
             if self.plan is not None:
                 kw["out_shardings"] = self._cache_shardings
-            self._jit_insert = jax.jit(fn, donate_argnums=(0,), **kw)
+            self._jit_insert = jax.jit(insert_slot, donate_argnums=(0,), **kw)
         return self._call(self._jit_insert, big, small, slot)
 
     def admit_hit(self, st, slot: int, pt_row, pos0: int, reset) -> None:
@@ -537,13 +549,13 @@ class Executor:
         if self._jit_admit_hit is None:
             model = self.model
 
-            def fn(big, slot, pt_row, pos0, reset):
+            def admit_hit(big, slot, pt_row, pos0, reset):
                 return model.admit_lane_cache(big, slot, pt_row, pos0, reset)
 
             kw = {}
             if self.plan is not None:
                 kw["out_shardings"] = self._cache_shardings
-            self._jit_admit_hit = jax.jit(fn, donate_argnums=(0,), **kw)
+            self._jit_admit_hit = jax.jit(admit_hit, donate_argnums=(0,), **kw)
         st["caches"] = self._call(self._jit_admit_hit, st["caches"], slot,
                                   jnp.asarray(pt_row), pos0,
                                   jnp.asarray(reset))
@@ -555,7 +567,7 @@ class Executor:
         if key not in self._jit_admit_cold:
             model = self.model
 
-            def fn(big, small, slot, pt_row, pos0, reset, wp):
+            def admit_cold(big, small, slot, pt_row, pos0, reset, wp):
                 return model.admit_lane_cache(big, slot, pt_row, pos0,
                                               reset, small=small,
                                               write_pages=wp)
@@ -563,8 +575,8 @@ class Executor:
             kw = {}
             if self.plan is not None:
                 kw["out_shardings"] = self._cache_shardings
-            self._jit_admit_cold[key] = jax.jit(fn, donate_argnums=(0,),
-                                                **kw)
+            self._jit_admit_cold[key] = jax.jit(admit_cold,
+                                                donate_argnums=(0,), **kw)
         st["caches"] = self._call(
             self._jit_admit_cold[key], st["caches"], small, slot,
             jnp.asarray(pt_row), pos0, jnp.asarray(reset),
@@ -593,11 +605,12 @@ class Executor:
         if key not in self._jit_prefill_admit:
             model = self.model
 
-            def fn(big, small, pt_row, pos0, reset, wp):
+            def prefill_admit(big, small, pt_row, pos0, reset, wp):
                 return model.admit_lane_cache(big, 0, pt_row, pos0, reset,
                                               small=small, write_pages=wp)
 
-            self._jit_prefill_admit[key] = jax.jit(fn, donate_argnums=(0,))
+            self._jit_prefill_admit[key] = jax.jit(prefill_admit,
+                                                   donate_argnums=(0,))
         return self._call(self._jit_prefill_admit[key], arena, small,
                           jnp.asarray(pt_row), pos0, jnp.asarray(reset),
                           jnp.asarray(write_pages))
@@ -624,20 +637,21 @@ class Executor:
         if n_pad not in self._jit_ship:
             from repro.models.transformer import paged_cache_map
 
-            def gfn(scan, tail, idx):
+            def ship_gather(scan, tail, idx):
                 return paged_cache_map(
                     lambda ax, name, b: jnp.take(b, idx, axis=ax),
                     {"scan": scan, "tail": tail})
 
-            def sfn(caches, blk, idx):
+            def ship_scatter(caches, blk, idx):
                 sub = paged_cache_map(
                     lambda ax, name, b, s: (b.at[idx].set(s) if ax == 0
                                             else b.at[:, idx].set(s)),
                     {"scan": caches["scan"], "tail": caches["tail"]}, blk)
                 return dict(caches, scan=sub["scan"], tail=sub["tail"])
 
-            self._jit_ship[n_pad] = (jax.jit(gfn),
-                                     jax.jit(sfn, donate_argnums=(0,)))
+            self._jit_ship[n_pad] = (
+                jax.jit(ship_gather),
+                jax.jit(ship_scatter, donate_argnums=(0,)))
         gather, scatter = self._jit_ship[n_pad]
         blk = gather(arena["scan"], arena["tail"], jnp.asarray(src))
         blk = jax.device_put(blk, self._decode_sharding)  # the pool hop
@@ -649,11 +663,12 @@ class Executor:
         (four eager .at[].set dispatches cost ~4x this on small hosts)."""
         if self._jit_admit_lane is None:
 
-            def fn(cur, active, eos, budget, sl, tok, eos_id, bud):
+            def admit_lane(cur, active, eos, budget, sl, tok, eos_id, bud):
                 return (cur.at[sl].set(tok), active.at[sl].set(True),
                         eos.at[sl].set(eos_id), budget.at[sl].set(bud))
 
-            self._jit_admit_lane = jax.jit(fn, donate_argnums=(0, 1, 2, 3))
+            self._jit_admit_lane = jax.jit(admit_lane,
+                                           donate_argnums=(0, 1, 2, 3))
         st["cur"], st["active"], st["eos"], st["budget"] = self._call(
             self._jit_admit_lane, st["cur"], st["active"], st["eos"],
             st["budget"], sl, tok, eos_id, bud)
@@ -664,15 +679,15 @@ class Executor:
         the forced-token (suffix-ingest) queue row."""
         if self._jit_admit_lane_paged is None:
 
-            def fn(cur, active, eos, budget, forced, fl_, fptr, sl, tok,
-                   eos_id, bud, frow, fl):
+            def admit_lane_paged(cur, active, eos, budget, forced, fl_,
+                                 fptr, sl, tok, eos_id, bud, frow, fl):
                 return (cur.at[sl].set(tok), active.at[sl].set(True),
                         eos.at[sl].set(eos_id), budget.at[sl].set(bud),
                         forced.at[sl].set(frow), fl_.at[sl].set(fl),
                         fptr.at[sl].set(0))
 
             self._jit_admit_lane_paged = jax.jit(
-                fn, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
+                admit_lane_paged, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
         frow = np.zeros((self.cache_len,), np.int32)
         if len(forced_rest):
             frow[:len(forced_rest)] = forced_rest
@@ -687,10 +702,10 @@ class Executor:
         the trash page from the next step on."""
         if self._jit_park is None:
 
-            def fn(cur, active, sl):
+            def park_lane(cur, active, sl):
                 return cur.at[sl].set(PAD_TOKEN), active.at[sl].set(False)
 
-            self._jit_park = jax.jit(fn, donate_argnums=(0, 1))
+            self._jit_park = jax.jit(park_lane, donate_argnums=(0, 1))
         st["cur"], st["active"] = self._call(self._jit_park, st["cur"],
                                              st["active"], sl)
 
@@ -810,7 +825,7 @@ class Executor:
                 None, length=n)
             return toks, cur, act, rem, pos, sc
 
-        def fn(params, caches, token, active, eos, budget):
+        def pipeline_decode(params, caches, token, active, eos, budget):
             rest_p = {k: v for k, v in params.items() if k != "scan"}
             toks, cur, act, rem, pos, sc = jax.shard_map(
                 body, mesh=mesh,
@@ -828,7 +843,7 @@ class Executor:
                                   + (self._rep,) * 4)
             kw["out_shardings"] = ((self._rep,) * 4
                                    + (self._cache_shardings,))
-        return jax.jit(fn, donate_argnums=(1,), **kw)
+        return jax.jit(pipeline_decode, donate_argnums=(1,), **kw)
 
     # -- request-skewed pipelined decode (mode="serve_pipeline", exact=False) --
 
@@ -996,8 +1011,8 @@ class Executor:
                 jnp.where(sidx == 0, pos_s, jnp.zeros_like(pos_s)), axis)
             return toks, cur, act, rem, fp, pos, sc
 
-        def fn(params, caches, token, active, eos, budget, forced, flen,
-               fptr):
+        def pipeline_skew_decode(params, caches, token, active, eos, budget,
+                                 forced, flen, fptr):
             rest_p = {k: v for k, v in params.items() if k != "scan"}
             toks, cur, act, rem, fp, pos, sc = jax.shard_map(
                 body, mesh=mesh,
@@ -1019,4 +1034,4 @@ class Executor:
                                   + (self._rep,) * 7)
             kw["out_shardings"] = ((self._rep,) * 5
                                    + (self._cache_shardings,))
-        return jax.jit(fn, donate_argnums=(1,), **kw)
+        return jax.jit(pipeline_skew_decode, donate_argnums=(1,), **kw)

@@ -32,7 +32,9 @@ arena: the per-lane page table is scalar-prefetched and indexed inside the
 BlockSpec index maps, so walking a lane's pages in logical order is just
 the grid's DMA schedule — the gather costs nothing beyond the block
 fetches the dense kernel already does, and radix-shared prefix pages are
-fetched per lane that names them, never duplicated in HBM.
+fetched per lane that names them, never duplicated in HBM.  Its grid is a
+work list of blocks of several pages, and a lane walks only the pages its
+query can reach.
 """
 from __future__ import annotations
 
@@ -46,6 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 BS = 256  # default KV split length (sublane dim of the k/v blocks)
 NEG_INF = -1e30
 KPOS_SENTINEL = 2 ** 30  # never-written cache slot (models/attention.py)
+PAGED_BLOCK_BYTES = 1 << 20  # K and V bytes one paged grid step holds
 
 
 def _online_softmax_step(q, k, v, msk, m_ref, l_ref, acc_ref, h: int):
@@ -109,41 +112,103 @@ def _kernel(qpos_ref, active_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
         _write_out(o_ref, l_ref, acc_ref, q_ref.shape[1])
 
 
-def _paged_kernel(pt_ref, qpos_ref, active_ref, q_ref, k_ref, v_ref,
-                  kpos_ref, o_ref, m_ref, l_ref, acc_ref, *, n_p: int):
-    """Same online-softmax body as `_kernel`, but the KV block walked at
-    grid step j is whichever *page* the lane's page table names — the
-    gather happens in the BlockSpec index map (scalar-prefetched page
-    table), so the kernel body never sees page indirection at all."""
-    b, j = pl.program_id(0), pl.program_id(1)
+def _live_pages(qpos, active, ps: int, maxp: int):
+    """Pages lane b's query can reach: page j holds positions
+    [j*ps, (j+1)*ps), so every page past qpos // ps holds only keys with
+    kpos > qpos or sentinels; an inactive lane reaches none."""
+    return jnp.where(active != 0, jnp.minimum(maxp, qpos // ps + 1), 0)
 
-    @pl.when(j == 0)
+
+def _paged_kernel(pt_ref, qpos_ref, active_ref, lane_ref, blk_ref, col_ref,
+                  q_ref, *refs, ppb: int, maxp: int):
+    """Grid step t: block blk_ref[t] of lane lane_ref[t]'s live pages,
+    `ppb` pages folded into the online softmax at once.  Refs after q:
+    ppb k pages, ppb v pages, ppb kpos rows (the pages col_ref named),
+    then o and the partials.  A lane with no live page has one step,
+    which writes its zeros."""
+    k_refs, v_refs, kp_refs = refs[:ppb], refs[ppb:2 * ppb], \
+        refs[2 * ppb:3 * ppb]
+    o_ref, m_ref, l_ref, acc_ref = refs[3 * ppb:]
+    t = pl.program_id(0)
+    b, i = lane_ref[t], blk_ref[t]
+    ps = kp_refs[0].shape[-1]
+    n = _live_pages(qpos_ref[b], active_ref[b], ps, maxp)
+
+    @pl.when(i == 0)
     def _init():
         _init_partials(m_ref, l_ref, acc_ref)
 
-    qpos = qpos_ref[b]
-    kpos = kpos_ref[0]  # (1, ps) absolute positions; 2^30 = never written
-    # causal test rejects the sentinel, so trash/unwritten page slots and
-    # out-of-range page-table entries are unreachable by construction
-    msk = (kpos <= qpos) & (active_ref[b] != 0)
-    for h in range(q_ref.shape[1]):  # the page's kv heads, one block
-        _online_softmax_step(q_ref[0, h], k_ref[0, :, h, :],
-                             v_ref[0, :, h, :], msk, m_ref, l_ref, acc_ref,
-                             h)
+    @pl.when(n > 0)
+    def _block():
+        width = ppb * ps
+        kpos = jnp.concatenate([r[0] for r in kp_refs], axis=1)  # (1, width)
+        # slots past the last live page hold another block's page or
+        # page 0: out by position, whatever they hold
+        live = n * ps - i * width
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
+        msk = (kpos <= qpos_ref[b]) & (col < live)
+        # whole pages, loaded once: a head sliced out of a loaded page
+        # costs far less than a strided load of it from each ref
+        ks = [r[0] for r in k_refs]
+        vs = [r[0] for r in v_refs]
+        for h in range(q_ref.shape[1]):
+            k = jnp.concatenate([x[:, h, :] for x in ks], axis=0)
+            v = jnp.concatenate([x[:, h, :] for x in vs], axis=0)
+            # p is 0 there, but 0 * NaN is not: stale values stay out
+            v = jnp.where(row < live, v, jnp.zeros_like(v))
+            _online_softmax_step(q_ref[0, h], k, v, msk, m_ref, l_ref,
+                                 acc_ref, h)
 
-    @pl.when(j == n_p - 1)
+    @pl.when((i + 1) * ppb >= n)
     def _epilogue():
         _write_out(o_ref, l_ref, acc_ref, q_ref.shape[1])
+
+
+def _work_list(qpos, active, ps: int, maxp: int, ppb: int):
+    """The grid's steps: each lane's blocks of live pages in lane order,
+    one step for a lane with none.  Returns the step count, the lane and
+    the block of step t, and the page-table column of each block slot.
+    A slot past the lane's last live page names the column it named a
+    block before (-1, page 0, in a lane's first block), so the pipeline
+    copies only the pages that changed since the step before.  Every
+    array is padded past the step count to the most steps any call can
+    have."""
+    b = qpos.shape[0]
+    n = _live_pages(qpos, active, ps, maxp)
+    nblk = jnp.maximum(1, -(-n // ppb))
+    end = jnp.cumsum(nblk)
+    t = jnp.arange(b * pl.cdiv(maxp, ppb) + 1, dtype=jnp.int32)
+    lanes = jnp.minimum(jnp.sum(t[:, None] >= end[None, :], axis=1), b - 1)
+    mine = lanes[:, None] == jnp.arange(b)[None, :]
+    blks = t - jnp.sum(jnp.where(mine, end - nblk, 0), axis=1)
+    n_t = jnp.sum(jnp.where(mine, n, 0), axis=1)[:, None]
+    j = blks[:, None] * ppb + jnp.arange(ppb)[None, :]
+    j = jnp.where(j < n_t, j, j - ppb)
+    cols = jnp.where((j >= 0) & (j < n_t), j, -1)
+    as32 = lambda x: x.astype(jnp.int32)  # noqa: E731
+    return end[-1], as32(lanes), as32(blks), as32(cols.reshape(-1))
+
+
+def pages_per_block(ps: int, kvh: int, hd: int, itemsize: int,
+                    maxp: int) -> int:
+    """Pages a grid step folds in: enough keys to fill the 128 lanes of
+    the score tile, no more K and V bytes than PAGED_BLOCK_BYTES, and no
+    more than a lane's table."""
+    page = 2 * ps * kvh * hd * itemsize  # K and V of one page
+    return max(1, min(maxp, 128 // ps, PAGED_BLOCK_BYTES // page))
 
 
 def _paged_kernel_q(pt_ref, qpos_ref, active_ref, q_ref, k_ref, v_ref,
                     ks_ref, vs_ref, kpos_ref, o_ref, m_ref, l_ref, acc_ref,
                     *, n_p: int):
-    """`_paged_kernel` over an *int8* KV arena: the page named by pt[b, j]
-    arrives as int8 k/v tiles plus their per-row f32 scales, and the tiles
-    are dequantized in VMEM right before the dots — HBM moves ~half the
-    bytes of the bf16 arena (int8 values + one f32 scale per row per kv
-    head) while the online-softmax recurrence is unchanged and stays f32."""
+    """The online-softmax body over an *int8* KV arena, one page a grid
+    step over every table entry (grid (B, MAXP)): the page named by
+    pt[b, j] arrives as int8 k/v tiles plus their per-row f32 scales, and
+    the tiles are dequantized in VMEM right before the dots — HBM moves
+    ~half the bytes of the bf16 arena (int8 values + one f32 scale per row
+    per kv head) while the online-softmax recurrence is unchanged and
+    stays f32."""
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -226,10 +291,10 @@ def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,
       kpos.reshape(n_pages, 1, ps))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("ppb", "interpret"))
 def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                        kpos: jax.Array, page_table: jax.Array,
-                       qpos: jax.Array, active: jax.Array, *,
+                       qpos: jax.Array, active: jax.Array, *, ppb: int,
                        interpret: bool = False) -> jax.Array:
     """Split-KV decode over a *paged* KV arena.
 
@@ -242,39 +307,53 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     kpos are all sentinel (the allocator's trash page 0).  qpos: (B,)
     int32; active: (B,) int32 row gate.  Returns (B, KVH, G, hd).
 
-    Grid: (batch, MAXP) with pages innermost; the page table, qpos and
-    active are scalar-prefetched, and the page table is indexed in the
-    k/v/kpos BlockSpec index maps, so the per-page DMA *is* the gather.
-    One block holds a whole page — every kv head of it — so each page is
-    read once per lane and the block's last two dims are the arena's own
-    (KVH, hd), the tiling Mosaic accepts for any head count.
+    Grid: one step per block of `ppb` live pages (ops.py derives ppb from
+    the shapes, `pages_per_block`), lane after lane.  Lane b's live pages
+    are the min(MAXP, qpos // ps + 1) its query can reach, none when it
+    is inactive: the walk stops there, and a lane with none gets one step
+    that writes its zeros.  The page table, qpos, active and the work
+    list are scalar-prefetched.  Each of a block's ppb pages is one k,
+    one v and one kpos operand whose index map reads the page table, so
+    the per-page DMA *is* the gather, and the pipeline starts the next
+    block's copies before this block's compute, across lanes too.  A page
+    block spans every kv head — its last two dims are the arena's own
+    (KVH, hd), the tiling Mosaic accepts for any head count.  A lane's
+    row depends on its own qpos, active and table row alone.
     """
     b, kvh, g, hd = q.shape
     n_pages, ps = kpos.shape
     maxp = page_table.shape[1]
-    kern = functools.partial(_paged_kernel, n_p=maxp)
-    page = lambda b, j, pt, qp, act: (pt[b, j], 0, 0, 0)  # noqa: E731
+    steps, lanes, blks, cols = _work_list(qpos, active, ps, maxp, ppb)
+    lane = lambda t, *s: (s[3][t], 0, 0, 0)  # noqa: E731
+
+    def page(c):  # block slot c's arena page at grid step t
+        def index(t, pt, qp, act, ln, bk, col):
+            j = col[t * ppb + c]
+            return jnp.where(j >= 0, pt[ln[t], jnp.maximum(j, 0)], 0)
+        return index
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, maxp),
-        in_specs=[
-            pl.BlockSpec((1, kvh, g, hd), lambda b, j, pt, qp, act:
-                         (b, 0, 0, 0)),
-            pl.BlockSpec((1, ps, kvh, hd), page),
-            pl.BlockSpec((1, ps, kvh, hd), page),
-            pl.BlockSpec((1, 1, ps), lambda b, j, pt, qp, act:
-                         (pt[b, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, kvh, g, hd), lambda b, j, pt, qp, act:
-                               (b, 0, 0, 0)),
+        num_scalar_prefetch=6,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((1, kvh, g, hd), lane),
+                  *[pl.BlockSpec((1, ps, kvh, hd),
+                                 lambda *a, f=page(c): (f(*a), 0, 0, 0))
+                    for c in range(ppb)] * 2,
+                  *[pl.BlockSpec((1, 1, ps),
+                                 lambda *a, f=page(c): (f(*a), 0, 0))
+                    for c in range(ppb)]],
+        out_specs=pl.BlockSpec((1, kvh, g, hd), lane),
         scratch_shapes=_scratch(kvh, g, hd),
     )
+    kp3 = kpos.reshape(n_pages, 1, ps)
     return pl.pallas_call(
-        kern,
+        functools.partial(_paged_kernel, ppb=ppb, maxp=maxp),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
         interpret=interpret,
-    )(page_table, qpos, active, q, k, v, kpos.reshape(n_pages, 1, ps))
+        name="paged_flash_decode",
+    )(page_table, qpos, active, lanes, blks, cols, q, *[k] * ppb,
+      *[v] * ppb, *[kp3] * ppb)
 
 
 @functools.partial(

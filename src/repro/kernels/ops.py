@@ -308,9 +308,11 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
     act = (jnp.ones((b,), jnp.int32) if active is None
            else active.astype(jnp.int32).reshape(b))
+    ppb = _fd.pages_per_block(k.shape[1], kvh, hd, k.dtype.itemsize,
+                              page_table.shape[1])
     out = _fd.paged_flash_decode(
         qg, k, v, kpos.astype(jnp.int32), page_table.astype(jnp.int32),
-        qpos.astype(jnp.int32), act, interpret=impl == "interpret")
+        qpos.astype(jnp.int32), act, ppb=ppb, interpret=impl == "interpret")
     return out[:, :, :g].reshape(b, h, hd)
 
 

@@ -387,10 +387,11 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
             ck = ck.at[wr_page, wr_off].set(k[:, 0].astype(ck.dtype))
             cv = cv.at[wr_page, wr_off].set(v[:, 0].astype(cv.dtype))
         kpos = cache["kpos"].at[wr_page, wr_off].set(kpos_val)
-        # the page-gathering kernels only route compiled: their grid is
-        # (B, KVH, MAXP) and decode dispatches thousands of times, so the
-        # interpreter's per-program overhead (~8x the jnp gather at
-        # serving shapes) would dominate CPU serving — interpret CI
+        # the page-gathering kernels only route compiled: their grid walks
+        # every lane's live pages (the int8 kernel's, every table entry)
+        # and decode dispatches thousands of times, so the interpreter's
+        # per-program overhead (~8x the jnp gather at serving shapes)
+        # would dominate CPU serving — interpret CI
         # exercises the kernel bodies in tests/test_paged_kv.py and
         # tests/test_quant_kv.py; the jnp fallback is the gather oracle
         # (bitwise equal to the dense ref path on equal logical lengths)

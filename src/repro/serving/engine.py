@@ -187,7 +187,6 @@ class ContinuousBatchingEngine(EngineBase):
                                         spec_config["draft_params"])
                 self.sched.set_spec(self.spec_k)
                 self._spec_warm = False
-                self._tpos = [0] * self.max_batch   # target positions
                 self._dpos = [0] * self.max_batch   # draft positions
                 self.stats.update(spec_dispatches=0, spec_draft_steps=0,
                                   spec_accepted=0, spec_proposed=0,
@@ -201,8 +200,10 @@ class ContinuousBatchingEngine(EngineBase):
                                    if max_hit_suffix is None
                                    else max_hit_suffix)
             self._ladder_warm = False
+            self._tpos = [0] * self.max_batch  # each lane's next position
             self.stats.update(prefix_hits=0, prefix_hit_tokens=0,
-                              preemptions=0, pages_in_use=0, pages_peak=0)
+                              preemptions=0, pages_in_use=0, pages_peak=0,
+                              decode_pages_walked=0, decode_pages_grid=0)
         if self.disagg is not None:
             self._init_disagg()
 
@@ -340,6 +341,7 @@ class ContinuousBatchingEngine(EngineBase):
                         st, sl, r.tokens_out[-1], r.eos_id, r.remaining(),
                         np.zeros((0,), np.int32), 0)
         self.kv.commit(sl, grant)
+        self._tpos[sl] = grant.hit_len or len(prompt)
         if self.spec and not r.done:
             self._admit_draft(r, sl, st, grant, prompt)
         self.stats["pages_in_use"] = self.kv.pages_in_use
@@ -386,7 +388,7 @@ class ContinuousBatchingEngine(EngineBase):
         self.executor.admit_cold_draft(
             st, sl, small, grant.draft_pt_row, plen, grant.draft_reset,
             np.asarray(grant.draft_pages[:n_wp], np.int32), bucket)
-        self._tpos[sl] = self._dpos[sl] = int(plen)
+        self._dpos[sl] = int(plen)
         self.sched.reset_lane_spec(sl)
         self.stats["spec_draft_prefills"] += 1
 
@@ -399,8 +401,9 @@ class ContinuousBatchingEngine(EngineBase):
     def _release(self, sl: int) -> None:
         self.kv.release(sl)
         self.sched.lane_forced[sl] = 0
+        self._tpos[sl] = 0
         if self.spec:
-            self._tpos[sl] = self._dpos[sl] = 0
+            self._dpos[sl] = 0
         self.stats["pages_in_use"] = self.kv.pages_in_use
 
     def _preempt(self, slots, pending, st) -> None:
@@ -419,22 +422,36 @@ class ContinuousBatchingEngine(EngineBase):
         pending.append(victim)
         self.stats["preemptions"] += 1
 
-    def _advance_mirrors(self, block: np.ndarray, slots, n: int) -> None:
+    def _advance_mirrors(self, block: np.ndarray, slots, n: int,
+                         walk: bool = False) -> None:
         """Advance the host position mirrors by what the device consumed:
         per active lane, min(pending forced, n) swallowed positions plus
         one position per emitted token (every consumed step is one or the
-        other — decode_steps and the spec block share this invariant)."""
+        other — decode_steps and the spec block share this invariant).
+
+        `walk` (a plain decode dispatch) also counts the paged kernel's
+        walk from those positions: decode_pages_walked adds the live pages
+        min(MAXP, pos // ps + 1) of each step a lane ran active,
+        decode_pages_grid the max_batch x MAXP table entries a step."""
+        ps, maxp = self.page_size, self.max_pages
         for i, r in enumerate(slots):
             if r is None:
                 continue
             emitted = int((block[:, i] >= 0).sum())
-            self._tpos[i] += min(self.sched.lane_forced[i], n) + emitted
+            steps = min(self.sched.lane_forced[i], n) + emitted
+            if walk:
+                self.stats["decode_pages_walked"] += sum(
+                    min(maxp, p // ps + 1)
+                    for p in range(self._tpos[i], self._tpos[i] + steps))
+            self._tpos[i] += steps
+        if walk:
+            self.stats["decode_pages_grid"] += n * self.max_batch * maxp
 
     def _reconcile(self, toks, slots, done, n: int, t_step: float) -> None:
         block = self._fetch_block(toks, t_step)
         with span("reconcile", self.stats, "reconcile_s"):
-            if self.spec:  # spec-disabled dispatch: draft lags, catchup later
-                self._advance_mirrors(block, slots, n)
+            if self.paged:  # a spec engine's draft lags, catchup later
+                self._advance_mirrors(block, slots, n, walk=True)
             self.sched.reconcile(block, slots, done, n, self.stats,
                                  time.perf_counter(), self.paged,
                                  self._release if self.paged else None)

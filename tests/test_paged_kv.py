@@ -23,6 +23,7 @@ except ModuleNotFoundError:  # deterministic fallback draws (see detshim.py)
     import detshim as st
 
 from repro.core.packing import PagePool, RadixPrefixCache
+from repro.kernels import flash_decode as fd
 from repro.kernels import ops
 
 SENTINEL = 2 ** 30
@@ -62,23 +63,78 @@ def _mk_paged(rng, b, h, kvh, hd, n_pages, ps, maxp, share=True,
     return (q, k, v, jnp.asarray(kpos, jnp.int32), jnp.asarray(pt), qpos)
 
 
-@given(st.integers(0, 10_000), st.sampled_from([(4, 4), (8, 2), (6, 3)]),
-       st.sampled_from([(8, 3), (16, 2), (8, 5)]))
-@settings(max_examples=12, deadline=None)
-def test_paged_decode_interpret_matches_ref(seed, heads, paging):
-    """Pallas paged kernel (interpret) == gather oracle, shared pages
-    included."""
-    h, kvh = heads
-    ps, maxp = paging
+def _with_ppb(monkeypatch, ppb, ps, kvh, hd, maxp, itemsize=4):
+    """Steer the wrapper's pages-per-block to `ppb` through the block
+    byte budget it derives ppb from."""
+    monkeypatch.setattr(fd, "PAGED_BLOCK_BYTES",
+                        ppb * 2 * ps * kvh * hd * itemsize)
+    assert fd.pages_per_block(ps, kvh, hd, itemsize, maxp) == ppb
+
+
+# name: (heads, kvh, ps, maxp, ppb, qpos per lane, lane active)
+WALKS = {
+    # one block holds the whole table: the walk of the first kernel
+    "one_block": (4, 4, 8, 3, 3, [20, 9, 17], [1, 1, 1]),
+    # contexts that end just before, on and just after a block boundary
+    # (ppb * ps = 16 keys)
+    "cross_block_boundary": (8, 2, 8, 6, 2, [15, 16, 17, 31, 32, 47],
+                             [1] * 6),
+    # ppb does not divide MAXP: the last block is short of the table
+    "ppb_not_dividing_maxp": (6, 3, 8, 7, 3, [55, 40, 23, 47], [1] * 4),
+    # lanes with 0 (inactive), 1 and every page live, and a query past
+    # the table's reach
+    "zero_one_all_pages_live": (4, 2, 8, 5, 2, [30, 3, 39, 7, 500],
+                                [0, 1, 1, 1, 1]),
+    # inactive lanes between active ones
+    "inactive_between_active": (4, 4, 16, 4, 3, [40, 60, 9, 33, 63, 0],
+                                [1, 0, 0, 1, 0, 1]),
+}
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_paged_decode_interpret_matches_ref(walk, seed, monkeypatch):
+    """Pallas paged kernel (interpret) == gather oracle, radix-shared
+    pages included, over walks of several blocks of `ppb` pages."""
+    h, kvh, ps, maxp, ppb, qpos, active = WALKS[walk]
     rng = np.random.default_rng(seed)
-    b, hd = 3, 16
+    b, hd = len(qpos), 16
     n_pages = 1 + b * maxp + 1
-    q, k, v, kpos, pt, qpos = _mk_paged(rng, b, h, kvh, hd, n_pages, ps,
-                                        maxp)
-    got = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, impl="interpret")
-    want = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, impl="ref")
+    q, k, v, kpos, pt, _ = _mk_paged(rng, b, h, kvh, hd, n_pages, ps, maxp)
+    qpos = jnp.asarray(qpos, jnp.int32)
+    active = jnp.asarray(active, bool)
+    _with_ppb(monkeypatch, ppb, ps, kvh, hd, maxp)
+    got = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, active=active,
+                                 impl="interpret")
+    want = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, active=active,
+                                  impl="ref")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_paged_decode_skips_pages_past_the_bound(monkeypatch):
+    """K/V of every page past a lane's live pages are NaN (kpos sentinel),
+    the trash page's too: the walk never folds them in, so the output
+    stays finite and equals the oracle over a clean arena.  A kernel that
+    walked them would leak NaN through 0 * NaN in p @ v."""
+    rng = np.random.default_rng(3)
+    b, h, kvh, hd, ps, maxp, ppb = 4, 4, 2, 16, 8, 6, 2
+    n_pages = 1 + b * maxp
+    q, k, v, kpos, pt, _ = _mk_paged(rng, b, h, kvh, hd, n_pages, ps, maxp,
+                                     share=False)
+    qpos = np.asarray([5, 20, 47, 33], np.int32)  # 1, 3, 6 and 5 pages
+    dead = [0] + [int(pt[i, j]) for i in range(b) for j in range(maxp)
+                  if j > qpos[i] // ps]
+    kpos = kpos.at[np.asarray(dead)].set(SENTINEL)
+    k_nan = k.at[np.asarray(dead)].set(jnp.nan)
+    v_nan = v.at[np.asarray(dead)].set(jnp.nan)
+    qpos = jnp.asarray(qpos)
+    _with_ppb(monkeypatch, ppb, ps, kvh, hd, maxp)
+    got = np.asarray(ops.paged_flash_decode(q, k_nan, v_nan, kpos, pt, qpos,
+                                            impl="interpret"))
+    want = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, impl="ref")
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
 @given(st.integers(0, 10_000))

@@ -7,8 +7,9 @@ served run emits every span the benchmark reads plus the engine's own,
 that the counters only grow, nest (`prefill_s` inside `admit_s`) and add
 up to no more than `loop_s`, that they stand when `run()` ends by an
 exception, that the collector's work is counted, that no device sync was
-added, that the benchmark's three readers of the counters compute what
-they say, and that every jitted program has a name of its own.
+added, that the paged kernel's page-walk counters match a hand count,
+that the benchmark's three readers of the counters compute what they
+say, and that every jitted program has a name of its own.
 """
 import ast
 import gc
@@ -184,6 +185,30 @@ def test_device_syncs_unchanged(tiny):
     assert eng.stats["decode_dispatches"] == 10
     assert eng.stats["prefills"] == 1
     assert eng.stats["device_syncs"] == 11
+
+
+def test_page_walk_counters_by_hand(tiny):
+    """decode_pages_walked is, over every step a lane ran active, the
+    live pages min(MAXP, pos // ps + 1) of its query; decode_pages_grid
+    is steps x max_batch x MAXP.  By hand over the two-lane schedule: a
+    cold turn runs from its prompt's end L, a hit turn from its hit
+    length h through its suffix, and both to L + budget - 2."""
+    cfg = tiny[0]
+    eng = _engine(tiny)
+    reqs = _requests(cfg)
+    _serve(eng, reqs)
+    ps, maxp = eng.page_size, eng.max_pages
+    assert (ps, maxp) == (16, 5)
+    assert eng.stats["prefix_hits"] == 4  # every later turn hits 2 pages
+    walked = 0
+    for r in reqs:
+        start = len(r.prompt) if r.rid == 0 else 32
+        end = len(r.prompt) + r.max_new_tokens - 2
+        walked += sum(min(maxp, p // ps + 1) for p in range(start, end + 1))
+    # 12 + 42 + 52 + 64 + 76: 3 pages live below position 48, 4 from it
+    assert eng.stats["decode_pages_walked"] == walked == 246
+    assert eng.stats["decode_pages_grid"] == (
+        eng.stats["decode_steps"] * 2 * maxp)
 
 
 def _reader(name):

@@ -205,6 +205,46 @@ def test_spec_stream_bit_identical_mid_acceptance(ref_impl):
                             budgets=(12, 9))
 
 
+def test_verify_fold_rows_bitwise_equal_single_rows(monkeypatch):
+    """The verify pass folds B x T query rows into the paged kernel's
+    batch the way models/attention.py does: each lane's page-table row
+    repeated T times, positions p..p+T-1.  Each folded row is bitwise the
+    single-row call at its own position, whichever blocks of `ppb` pages
+    its own position makes it walk (lane 0's rows cross the 12-key block
+    boundary, lane 2 is inactive).  On the CPU the engine routes to the
+    jnp oracle, so only a direct call reaches the kernel."""
+    from repro.kernels import flash_decode as fd, ops
+
+    b, t, h, kvh, hd, ps, maxp, ppb = 3, 4, 4, 2, 16, 4, 8, 3
+    monkeypatch.setattr(fd, "PAGED_BLOCK_BYTES", ppb * 2 * ps * kvh * hd * 2)
+    assert fd.pages_per_block(ps, kvh, hd, 2, maxp) == ppb
+    rng = np.random.default_rng(0)
+    n_pages = 1 + b * maxp
+    k = jnp.asarray(rng.normal(0, 1, (n_pages, ps, kvh, hd)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(0, 1, (n_pages, ps, kvh, hd)), jnp.bfloat16)
+    pt = np.arange(1, n_pages, dtype=np.int32).reshape(b, maxp)
+    p0 = np.array([10, 25, 3])
+    kpos = np.full((n_pages, ps), 2 ** 30, np.int32)
+    for lane in range(b):  # written up to the block's last row
+        for pos in range(p0[lane] + t):
+            kpos[pt[lane, pos // ps], pos % ps] = pos
+    q = jnp.asarray(rng.normal(0, 0.25, (b, t, h, hd)), jnp.bfloat16)
+    qf = q.reshape(b * t, h, hd)
+    ptf = jnp.repeat(jnp.asarray(pt), t, axis=0)
+    qpf = jnp.asarray((p0[:, None] + np.arange(t)).reshape(-1), jnp.int32)
+    actf = jnp.repeat(jnp.asarray([True, True, False]), t)
+    kpos = jnp.asarray(kpos)
+    folded = np.asarray(ops.paged_flash_decode(
+        qf, k, v, kpos, ptf, qpf, active=actf, impl="interpret"))
+    for row in range(b * t):
+        one = ops.paged_flash_decode(
+            qf[row:row + 1], k, v, kpos, ptf[row:row + 1],
+            qpf[row:row + 1], active=actf[row:row + 1], impl="interpret")
+        np.testing.assert_array_equal(folded[row], np.asarray(one)[0])
+    assert not folded[2 * t:].any()  # the inactive lane's rows
+    assert folded[:2 * t].any()
+
+
 # ---------------------------------------------------------------------------
 # dual-arena page accounting
 # ---------------------------------------------------------------------------

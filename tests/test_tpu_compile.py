@@ -4,7 +4,8 @@ described TPU v5e chip at real widths, with no chip attached.
 Interpret mode never sees Mosaic's block-shape and lowering rules; these
 cases do.  Widths are smollm-135m's (9 query / 3 kv heads, head_dim 64,
 d_model 576, d_ff 1536, max batch 8) and ibert-base's (hidden 768, d_ff
-3072, 12 heads, sequence 128).  The topology is described inside a
+3072, 12 heads, sequence 128); the paged decode kernel also compiles at
+both benchmark cells' exact shapes.  The topology is described inside a
 fixture, never at import, so that only the pytest worker that runs this
 file loads the TPU compiler.
 """
@@ -50,6 +51,20 @@ def _paged(ps, kv_dtype):
 
     def f(*a):
         return call(*a[:-1], active=a[-1], impl="pallas")
+    return f, shapes
+
+
+def _paged_cell(b, h, kvh, hd, pages, maxp):
+    """The paged decode kernel at a benchmark cell's exact shapes (bf16
+    arena, 16-row pages)."""
+    shapes = [((b, h, hd), jnp.bfloat16),
+              ((pages, 16, kvh, hd), jnp.bfloat16),
+              ((pages, 16, kvh, hd), jnp.bfloat16),
+              ((pages, 16), jnp.int32), ((b, maxp), jnp.int32),
+              ((b,), jnp.int32), ((b,), jnp.bool_)]
+
+    def f(*a):
+        return ops.paged_flash_decode(*a[:-1], active=a[-1], impl="pallas")
     return f, shapes
 
 
@@ -102,6 +117,12 @@ CASES = {
     "paged_decode_bf16_ps128": lambda: _paged(128, "bf16"),
     "paged_decode_int8_ps16": lambda: _paged(16, "int8"),
     "paged_decode_int8_ps128": lambda: _paged(128, "int8"),
+    # smollm-135m.sessions: batch 32, 9 q / 3 kv heads, 2369 pages of 74
+    "paged_decode_smollm_sessions": lambda: _paged_cell(32, 9, 3, 64, 2369,
+                                                        74),
+    # phi3-medium-14b.batch: batch 16, 40 q / 10 kv heads, 2305 of 144
+    "paged_decode_phi3_batch": lambda: _paged_cell(16, 40, 10, 128, 2305,
+                                                   144),
     "flash_decode_dense": _dense_decode,
     "flash_attention_prefill": _prefill,
     "int8_matmul": _int8_matmul,
@@ -116,4 +137,5 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes = CASES[name]()
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 1

@@ -4,16 +4,18 @@
         --seconds <s> --trace <0|1>
 
 The cell is found by name in BENCHMARK.json: its configuration file
-(configs/), its traffic file (traffic/), its limits (limits/<cell>.json)
-and the reader of each metric (metrics/<metric>.py).  A run builds the
-serving engine with weights made from the seed, warms every program the
-cell's traffic can use, serves the requests that arrive in
-[0, seconds) and drains them, then checks a sample of what it served
-against the float32 reference.  The last line of standard output is one
-JSON object; the numbers compared, each beside its limit, are the last
-lines of standard error and the result's last key.  With --trace 1 the
-metrics are the per-layer ones, read from a profiler trace of a few
-seconds inside the window.
+(configs/), the family module of the file's model_type
+(families/<model_type>.py: the model's sizes, weights, program config,
+reference and work counts), its traffic file (traffic/), its limits
+(limits/<cell>.json) and the reader of each metric
+(metrics/<metric>.py).  A run builds the serving engine with weights
+made from the seed, warms every program the cell's traffic can use,
+serves the requests that arrive in [0, seconds) and drains them, then
+checks a sample of what it served against the float32 reference.  The
+last line of standard output is one JSON object; the numbers compared,
+each beside its limit, are the last lines of standard error and the
+result's last key.  With --trace 1 the metrics are the per-layer ones,
+read from a profiler trace of a few seconds inside the window.
 
 It exits nonzero with no result line when JAX finds no TPU, fewer chips
 than the cell asks for, or a device kind that peaks.json lacks.
@@ -43,13 +45,11 @@ import numpy as np  # noqa: E402
 
 import costs  # noqa: E402
 import traffic as T  # noqa: E402
-import weights as W  # noqa: E402
 
 TRACE_S = 3.0        # length of the traced part of a --trace 1 window
 SAMPLE_TOKENS = 320  # served tokens the correctness sample holds at least,
 # unless the cell's limits file sets its own `sample_tokens`
 NO_READING = 1e30    # the gap when nothing was served to compare
-CONTROLS = ("w8a8", "fp8")  # lower precisions calibration reads
 DRAIN_LIMIT_S = 150.0  # a request unfinished this long after the window fails
 
 
@@ -131,8 +131,9 @@ class TracedWindow:
     order the work (decode keys and FLOPs, prefill FLOPs) of what the host
     sent to the device in between."""
 
-    def __init__(self, s: W.Shapes, out_dir: Path, start_s: float):
-        self.s, self.dir, self.start_s = s, out_dir, start_s
+    def __init__(self, family, s, out_dir: Path, start_s: float):
+        self.family, self.s = family, s
+        self.dir, self.start_s = out_dir, start_s
         self.state, self.t_run = "idle", None
         self.log = []  # ("decode", keys, flops) | ("prefill", 0, flops)
         self.t_on = self.t_off = None
@@ -155,12 +156,13 @@ class TracedWindow:
             self._start()
         elif self.state == "on":
             keys = flops = 0
+            f, s = self.family, self.s
             for pos, steps, emitted in lanes:
                 k = costs.decode_keys(pos, steps)
                 keys += k
-                flops += (costs.token_flops(self.s, 0, False) * steps
-                          + costs.attn_flops_per_key(self.s) * k
-                          + costs.head_flops(self.s) * emitted)
+                flops += (f.token_flops(s, 0, False) * steps
+                          + f.attn_flops_per_key(s) * k
+                          + f.head_flops(s) * emitted)
             self.log.append(("decode", keys, flops))
             if now >= self.t_on + TRACE_S:
                 self.stop()
@@ -168,7 +170,7 @@ class TracedWindow:
     def on_prefill(self, lengths):
         if self.state == "on":
             self.log.append(("prefill", 0, sum(
-                costs.prefill_flops(self.s, n) for n in lengths)))
+                self.family.prefill_flops(self.s, n) for n in lengths)))
 
     def work(self, n_decode: int):
         """Keys and FLOPs of the last `n_decode` decode dispatches and of
@@ -187,13 +189,33 @@ def buckets_for(longest: int):
         b *= 2
 
 
+def _module(path: Path, name: str):
+    """The module at `path`, loaded once under `name`."""
+    path = Path(path).resolve()
+    mod = sys.modules.get(name)
+    if mod is not None and Path(mod.__file__) == path:
+        return mod
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # dataclasses look their module up while made
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(name: str):
     """The `read(run)` of metrics/<name>.py."""
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(HERE / "metrics" / f"{name}.py", f"metric_{name}").read
+
+
+def load_family(model_type, where: Path = HERE / "families"):
+    """The family module <where>/<model_type>.py; a model_type with none
+    fails, naming the families there."""
+    path = Path(where) / f"{model_type}.py"
+    if not isinstance(model_type, str) or not path.is_file():
+        known = sorted(p.stem for p in Path(where).glob("*.py"))
+        raise SystemExit(f"bench: no model family {model_type!r} in "
+                         f"{where}; known families: {known}")
+    return _module(path, f"family_{model_type}")
 
 
 def applies(metric: dict, workload: str) -> bool:
@@ -247,18 +269,22 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
              limits: dict | None = None, tamper=None, log=print,
              traffic_dir: Path = HERE / "traffic",
              t_start: float = T_PROCESS, drain_limit_s: float = DRAIN_LIMIT_S,
-             keep_trace: str | None = None, control: bool = False) -> dict:
+             keep_trace: str | None = None, control: bool = False,
+             families_dir: Path = HERE / "families") -> dict:
     """Set up, serve the window, drain, check.  `tamper(engine)` may break
     the timed path (the harness's own tests); `limits` overrides
     limits/<cell>.json (each number compared, by name, with its limit);
     `keep_trace` names a file for a slice of the trace and `control` also
-    reads the controls (calibration)."""
+    reads the family's controls (calibration); `families_dir` is where the
+    configuration's family module is looked for."""
     import reference
     import system as S
 
     cell, conf = find_cell(spec, workload)
-    s = W.shapes(ROOT / conf["file"], conf["name"])
-    serve = json.loads((ROOT / conf["file"]).read_text())["serve"]
+    config = json.loads((ROOT / conf["file"]).read_text())
+    family = load_family(config.get("model_type"), families_dir)
+    s = family.shapes(ROOT / conf["file"], conf["name"])
+    serve = config["serve"]
     tr = T.load(traffic_dir / f"{cell['traffic']}.json")
     if rate_scale != 1.0:
         tr = dict(tr, sessions_per_s=tr["sessions_per_s"] * rate_scale)
@@ -276,9 +302,9 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
 
     t = {"start": time.perf_counter() - t_start}
     t0 = time.perf_counter()
-    params = W.served_params(s, seed)
+    params = family.served_params(s, seed)
     jax.block_until_ready(params)
-    mcfg = S.model_config(s)
+    mcfg = family.model_config(s)
     S.check_layout(mcfg, params)
     t["weights"] = time.perf_counter() - t0
     t0 = time.perf_counter()
@@ -305,7 +331,7 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
             for x in sched]
     tw = None
     if trace:
-        tw = TracedWindow(s, CACHE / "trace", 0.4 * seconds)
+        tw = TracedWindow(family, s, CACHE / "trace", 0.4 * seconds)
         S.install_counts(engine, tw.on_dispatch, tw.on_prefill)
         S.install_spans(engine)
     if tamper is not None:
@@ -354,8 +380,8 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
     del engine
     gc.collect()
     t0 = time.perf_counter()
-    gaps, ctl = (reference.served_gaps(s, seed, served,
-                                       CONTROLS if control else ())
+    gaps, ctl = (reference.served_gaps(family, s, seed, served,
+                                       family.CONTROLS if control else ())
                  if served else ([], {}))
     t_ref = time.perf_counter() - t0
     numbers = gap_numbers(gaps)
@@ -377,7 +403,7 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
 
     run = SimpleNamespace(
         requests=reqs, seconds=seconds, t0=t_win0, setup_s=setup_s,
-        stats=stats, max_batch=serve["max_batch"], shapes=s,
+        stats=stats, max_batch=serve["max_batch"], shapes=s, family=family,
         peak=costs.peaks(dev.device_kind) if on_chip else None,
         trace=None)
     breakdown, busy = None, None

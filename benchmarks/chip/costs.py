@@ -1,17 +1,17 @@
-"""The work the algorithm needs, counted from shapes, and the chip's peaks.
+"""The chip's peaks, and the work-count arithmetic every family shares.
 
-These counts are what a share of the roofline or of the peak divides by
-time.  They follow the model, not the implementation: no padding to
-buckets, pages or tiles, no lane that is masked off, and no output of
-the head that nobody reads.  A change that removes padding therefore
-leaves them alone.
+A share of the roofline or of the peak divides the work the algorithm
+needs by time.  Each family (families/<model_type>.py) counts its own
+work from shapes (`token_flops`, `prefill_flops`, `head_flops`,
+`attn_flops_per_key`, `kv_bytes_per_key`).  The counts follow the model,
+not the implementation: no padding to buckets, pages or tiles, no lane
+that is masked off, and no output of the head that nobody reads.  A
+change that removes padding therefore leaves them alone.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
-
-from weights import Shapes
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 KV_BYTES = 2  # the arena is bfloat16
@@ -24,39 +24,6 @@ def peaks(device_kind: str) -> dict:
         raise KeyError(f"device kind {device_kind!r} is not in {PEAKS.name}; "
                        f"known: {sorted(table)}")
     return table[device_kind]
-
-
-def layer_matmul_params(s: Shapes) -> int:
-    """Weights one token multiplies through in one layer: q, k, v, o and
-    the three SwiGLU matrices."""
-    return 2 * s.d * s.q_width + 2 * s.d * s.kv_width + 3 * s.d * s.ff
-
-
-def head_flops(s: Shapes) -> int:
-    return 2 * s.d * s.vocab
-
-
-def attn_flops_per_key(s: Shapes) -> int:
-    """q.k and p.v for one query against one key, over every layer."""
-    return 4 * s.heads * s.head_dim * s.layers
-
-
-def kv_bytes_per_key(s: Shapes) -> int:
-    """K and V of one cached token, over every layer."""
-    return 2 * s.kv_width * KV_BYTES * s.layers
-
-
-def token_flops(s: Shapes, keys: int, head: bool) -> int:
-    """One token's forward pass, attending to `keys` cached tokens (itself
-    included); `head` when its logits are read."""
-    return (2 * s.layers * layer_matmul_params(s) + attn_flops_per_key(s) * keys
-            + (head_flops(s) if head else 0))
-
-
-def prefill_flops(s: Shapes, n: int) -> int:
-    """A causal prefill of n tokens whose last logits are read."""
-    return (2 * s.layers * layer_matmul_params(s) * n
-            + attn_flops_per_key(s) * n * (n + 1) // 2 + head_flops(s))
 
 
 def decode_keys(p0: int, steps: int) -> int:

@@ -17,7 +17,7 @@ def read(run):
     secs = TR.op_seconds(tr, KERNEL)
     if secs <= 0:
         return None
-    s, keys = run.shapes, tr["keys"]
-    return costs.roofline_share(keys * costs.attn_flops_per_key(s),
-                                keys * costs.kv_bytes_per_key(s), secs,
+    s, f, keys = run.shapes, run.family, tr["keys"]
+    return costs.roofline_share(keys * f.attn_flops_per_key(s),
+                                keys * f.kv_bytes_per_key(s), secs,
                                 run.peak)

@@ -1,21 +1,22 @@
-"""The plain reference: a float32 forward pass of a dense decoder with GQA,
-RoPE and SwiGLU, written from the published architecture.
+"""The plain reference's shared parts, and the comparison of served tokens.
 
-It imports nothing of the program under test.  Its weights come from
-`weights.py`, one layer at a time from the seed (the served model holds
-the same bfloat16 values), and are upcast to float32; every product runs
-at HIGHEST precision.  One layer at a time over every sampled sequence
+Each family (families/<model_type>.py) writes its own float32 forward
+pass from the published architecture, out of the pieces here.  The
+reference imports nothing of the program under test.  Its weights come
+from the seed, one layer at a time (the served model holds the same
+bfloat16 values), and are upcast to float32; every product runs at
+HIGHEST precision.  One layer at a time over every sampled sequence
 keeps the largest configuration inside one chip.
 
 The control runs the same pass with every projection in W8A8, as the
 program's own int8 serving path does it, and is read at the positions
-the program served: int8 weights per output
-channel, int8 activations with one scale per tensor (here, over the
-sequence's real tokens), integer products summed in int32, the head left
-in float.  That is the lower precision a later change would be tempted
-by, and the comparison has to fail it.  A second control rounds the same
-weights (per output channel) and activations (per tensor) to float8
-e4m3, the other step below bfloat16, and multiplies in float32.
+the program served: int8 weights per output channel, int8 activations
+with one scale per tensor (here, over the sequence's real tokens),
+integer products summed in int32, the head left in float.  That is the
+lower precision a later change would be tempted by, and the comparison
+has to fail it.  A second control rounds the same weights (per output
+channel) and activations (per tensor) to float8 e4m3, the other step
+below bfloat16, and multiplies in float32.
 """
 from __future__ import annotations
 
@@ -25,8 +26,6 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-import weights as W
 
 HI = jax.lax.Precision.HIGHEST
 PAD = 512  # sequences are padded to a multiple of this (causal: harmless)
@@ -60,8 +59,8 @@ def _mm_f8(x, w, valid):
     return _mm(jnp.where(rows, xs, 0.0), w)
 
 
-def _mm_q(x, wq, valid):
-    q, sw = wq
+def _mm_q(x, w, valid):
+    q, sw = w
     rows = (jnp.arange(x.shape[0]) < valid)[:, None]
     sx = jnp.maximum(jnp.max(jnp.where(rows, jnp.abs(x), 0.0)), 1e-12) / 127.0
     xq = jnp.clip(jnp.round(x / sx), -127, 127).astype(jnp.int8)
@@ -70,11 +69,28 @@ def _mm_q(x, wq, valid):
     return acc.astype(jnp.float32) * sx * sw
 
 
-def _rms(x, eps):
+def projection(quant: str, valid):
+    """The product of a projection: float32 at HIGHEST, or a control's
+    (`quant` "w8a8" or "fp8") over the sequence's `valid` real rows."""
+    return {"": _mm, "w8a8": functools.partial(_mm_q, valid=valid),
+            "fp8": functools.partial(_mm_f8, valid=valid)}[quant]
+
+
+def upcast(w: dict, quant: str) -> dict:
+    """A layer's bfloat16 matrices in float32, quantised as `quant` says."""
+    w = {k: v.astype(jnp.float32) for k, v in w.items()}
+    if quant == "w8a8":
+        w = {k: _quant_w(v) for k, v in w.items()}
+    elif quant == "fp8":
+        w = {k: _f8(v, True) for k, v in w.items()}
+    return w
+
+
+def rms(x, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x: (S, heads, hd); rotate-half RoPE at positions 0..S-1."""
     s, _, hd = x.shape
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
@@ -84,87 +100,51 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("s", "quant"))
-def _layer(x, w, valid, s: W.Shapes, quant: str):
-    mm = {"": _mm, "w8a8": functools.partial(_mm_q, valid=valid),
-          "fp8": functools.partial(_mm_f8, valid=valid)}[quant]
-    n = x.shape[0]
-    z = _rms(x, s.eps)
-    q = _rope(mm(z, w["wq"]).reshape(n, s.heads, s.head_dim), s.theta)
-    k = _rope(mm(z, w["wk"]).reshape(n, s.kv_heads, s.head_dim), s.theta)
-    v = mm(z, w["wv"]).reshape(n, s.kv_heads, s.head_dim)
-    g = s.heads // s.kv_heads  # query head h reads kv head h // g
-    k = jnp.repeat(k, g, axis=1)
-    v = jnp.repeat(v, g, axis=1)
-    sc = jnp.einsum("qhd,khd->hqk", q, k, precision=HI) / np.sqrt(s.head_dim)
-    causal = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
-    sc = jnp.where(causal[None], sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    o = jnp.einsum("hqk,khd->qhd", p, v, precision=HI).reshape(n, s.q_width)
-    x = x + mm(o, w["wo"])
-    z = _rms(x, s.eps)
-    return x + mm(jax.nn.silu(mm(z, w["wg"])) * mm(z, w["wi"]), w["wd"])
-
-
-@functools.partial(jax.jit, static_argnames=("s", "quant"))
-def _layer_weights(key, i, s: W.Shapes, quant: str):
-    w = {k: v.astype(jnp.float32) for k, v in W.layer(s, key, i).items()}
-    if quant == "w8a8":
-        w = {k: _quant_w(v) for k, v in w.items()}
-    elif quant == "fp8":
-        w = {k: _f8(v, True) for k, v in w.items()}
-    return w
-
-
-@functools.partial(jax.jit, static_argnames=("s",))
-def _logits(x, idx, emb, s: W.Shapes):
-    h = _rms(x[idx], s.eps)
-    head = emb["head"] if "head" in emb else emb["tok"].T
-    return _mm(h, head.astype(jnp.float32))
-
-
-def logits(s: W.Shapes, seed: int, seqs: Sequence[np.ndarray],
-           positions: Sequence[np.ndarray], quant: str = ""
-           ) -> List[np.ndarray]:
-    """Float32 logits (or, with `quant` "w8a8" or "fp8", a control's) of
-    each token sequence at the given positions, (len(positions[j]),
-    vocab) each."""
-    key = W.seed_key(seed)
-    emb = jax.jit(lambda k: W.embedding(s, k))(key)
+def embed(tok, seqs: Sequence[np.ndarray]) -> list:
+    """Each sequence's float32 embeddings, padded to a multiple of PAD."""
     xs = []
     for seq in seqs:
         n = -(-len(seq) // PAD) * PAD
         ids = np.zeros((n,), np.int32)
         ids[:len(seq)] = seq
-        xs.append(emb["tok"][jnp.asarray(ids)].astype(jnp.float32))
-    for i in range(s.layers):
-        w = _layer_weights(key, i, s, quant)
-        xs = [_layer(x, w, len(q), s, quant) for x, q in zip(xs, seqs)]
-        del w
-    out = [np.asarray(_logits(x, jnp.asarray(np.asarray(p, np.int32)), emb, s))
-           for x, p in zip(xs, positions)]
-    return out
+        xs.append(tok[jnp.asarray(ids)].astype(jnp.float32))
+    return xs
 
 
-def served_gaps(s: W.Shapes, seed: int, served: Sequence[Tuple[np.ndarray,
-                                                               np.ndarray]],
+@functools.partial(jax.jit, static_argnames=("eps",))
+def _head(x, idx, emb, eps: float):
+    h = rms(x[idx], eps)
+    head = emb["head"] if "head" in emb else emb["tok"].T
+    return _mm(h, head.astype(jnp.float32))
+
+
+def head_logits(xs, positions, emb, eps: float) -> List[np.ndarray]:
+    """Final RMSNorm and the head (untied, or the token table's
+    transpose) at the given positions of each sequence."""
+    return [np.asarray(_head(x, jnp.asarray(np.asarray(p, np.int32)), emb,
+                             eps))
+            for x, p in zip(xs, positions)]
+
+
+def served_gaps(family, s, seed: int,
+                served: Sequence[Tuple[np.ndarray, np.ndarray]],
                 controls: Sequence[str] = ()):
     """For each (prompt, served tokens): the reference's gap of each served
     token (its best logit minus the served token's); and for each control
-    named ("w8a8", "fp8"), the gap of the token it puts first at the same
-    positions, as if it had served them.  Returns a list of float arrays
-    and a dict of such lists by control."""
+    named (from the family's CONTROLS), the gap of the token it puts first
+    at the same positions, as if it had served them.  Returns a list of
+    float arrays and a dict of such lists by control."""
     seqs, pos, toks = [], [], []
     for prompt, out in served:
         out = np.asarray(out, np.int32)
         seqs.append(np.concatenate([np.asarray(prompt, np.int32), out[:-1]]))
         pos.append(np.arange(len(seqs[-1]) - len(out), len(seqs[-1])))
         toks.append(out)
-    ref = logits(s, seed, seqs, pos)
+    ref = family.logits(s, seed, seqs, pos)
     gaps = [r.max(-1) - r[np.arange(len(t)), t] for r, t in zip(ref, toks)]
     ctl = {}
     for q in controls:
-        c = logits(s, seed, seqs, pos, quant=q)
+        c = family.logits(s, seed, seqs, pos, quant=q)
         ctl[q] = [r.max(-1) - r[np.arange(len(r)), x.argmax(-1)]
                   for r, x in zip(ref, c)]
     return gaps, ctl

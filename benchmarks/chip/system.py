@@ -1,12 +1,14 @@
 """The system under test, as the benchmark drives it.
 
-Everything the benchmark knows of the program is here: the model config
-it is handed, the engine it builds (as `launch/serve.py` does for
-`--plan none`: paged bfloat16 arena, one device, the engine's own page
-size, decode horizon, page count and kernel route), the request type it
-submits, and the instance methods it wraps for host spans and for the
-per-dispatch work count of a traced run.  Wrapping sets attributes on the
-instances only; no file of the program is touched.
+Everything the benchmark knows of the program but the model (whose
+config and weights the configuration's family module makes) is here:
+the check of the weights' layout, the engine it builds (as
+`launch/serve.py` does for `--plan none`: paged bfloat16 arena, one
+device, the engine's own page size, decode horizon, page count and
+kernel route), the request type it submits, and the instance methods
+it wraps for host spans and for the per-dispatch work count of a traced
+run.  Wrapping sets attributes on the instances only; no file of the
+program is touched.
 """
 from __future__ import annotations
 
@@ -22,8 +24,6 @@ from repro.kernels import ops as kops
 from repro.models.transformer import init_params, make_model
 from repro.serving.engine import ContinuousBatchingEngine
 from repro.serving.scheduler import Request
-
-import weights as W
 
 PAGE = 16  # the engine's default page size, used only to warm its programs
 # the executor's paged decode program, as the device trace names it
@@ -42,15 +42,6 @@ class TimedRequest(Request):
         super().append_token(tok, now)
         self.t_tokens.append(time.perf_counter())
         self.blocks.append(now)
-
-
-def model_config(s: W.Shapes) -> ModelConfig:
-    return ModelConfig(name=s.name, family="dense", n_layers=s.layers,
-                       d_model=s.d, n_heads=s.heads, n_kv_heads=s.kv_heads,
-                       d_ff=s.ff, vocab_size=s.vocab, head_dim=s.head_dim,
-                       rope_theta=s.theta, local_window=0, causal=True,
-                       mlp_style="swiglu", act="silu", tie_embeddings=s.tied,
-                       norm="rmsnorm")
 
 
 def check_layout(mcfg: ModelConfig, params) -> None:
@@ -91,11 +82,14 @@ def bucket_of(n: int, buckets) -> int:
 def warm_pairs(buckets, requests):
     """Every (prefill bucket, pages written) a cold admission of these
     (prompt length, budget) requests can ask for: each as it arrives, and
-    each re-admitted after preemption with part of its answer as prompt."""
+    each re-admitted after preemption with part of its answer as prompt.
+    A re-admission longer than the largest bucket has no prefill program
+    to warm; the pool holds every lane's whole context, so none starves
+    and none is preempted."""
     seen = set()
     for n, budget in requests:
         need = pages(n + budget)
-        for m in range(n, n + budget):
+        for m in range(n, min(n + budget, max(buckets) + 1)):
             b = bucket_of(m, buckets)
             seen.add((b, min(pages(b), need)))
     return sorted(seen)

@@ -20,13 +20,15 @@ CHIP = HERE.parent
 ROOT = CHIP.parents[1]
 sys.path[:0] = [str(CHIP), str(ROOT / "src")]
 
+import bench  # noqa: E402
 import costs  # noqa: E402
 import devtrace  # noqa: E402
 import traffic as T  # noqa: E402
 import weights as W  # noqa: E402
 
 DATA = HERE / "data"
-TINY = W.shapes(DATA / "tiny.json", "tiny")
+LLAMA = bench.load_family("llama")
+TINY = LLAMA.shapes(DATA / "tiny.json", "tiny")
 
 
 # -- trace reduction ---------------------------------------------------------
@@ -69,9 +71,12 @@ def test_reduce_recorded_trace():
 
 SESSIONS = T.load(CHIP / "traffic" / "sessions.json")
 BATCH = T.load(CHIP / "traffic" / "batch.json")
+LONG_DECODE = T.load(CHIP / "traffic" / "long-decode.json")
+TRAFFIC = pytest.mark.parametrize("tr", [SESSIONS, BATCH, LONG_DECODE],
+                                  ids=["sessions", "batch", "long-decode"])
 
 
-@pytest.mark.parametrize("tr", [SESSIONS, BATCH], ids=["sessions", "batch"])
+@TRAFFIC
 def test_traffic_deterministic_and_bounded(tr):
     a = T.generate(tr, 1000, 2 ** 31 + 77, 30.0)
     b = T.generate(tr, 1000, 2 ** 31 + 77, 30.0)
@@ -88,7 +93,7 @@ def test_traffic_deterministic_and_bounded(tr):
     assert [x.t_arrival for x in a] == sorted(x.t_arrival for x in a)
 
 
-@pytest.mark.parametrize("tr", [SESSIONS, BATCH], ids=["sessions", "batch"])
+@TRAFFIC
 def test_seeds_carry_the_same_work(tr):
     """Every seed: the same requests, sizes and arrival times, in the same
     order; other tokens."""
@@ -128,14 +133,15 @@ def test_served_params_match_layer_draws_and_program_layout():
     import jax
 
     import system as S
-    params = W.served_params(TINY, 123)
-    S.check_layout(S.model_config(TINY), params)
-    lw = W.layer(TINY, W.seed_key(123), 1)
+    params = LLAMA.served_params(TINY, 123)
+    S.check_layout(LLAMA.model_config(TINY), params)
+    lw = LLAMA.layer(TINY, W.seed_key(123), 1)
     mix = params["scan"]["b0"]["mix"]
     assert (np.asarray(mix["wq"][1]) == np.asarray(lw["wq"])).all()
     assert (np.asarray(params["scan"]["b0"]["ffn"]["wo"][1])
             == np.asarray(lw["wd"])).all()
-    emb = W.embedding(TINY, W.seed_key(123))
+    emb = W.embedding(W.seed_key(123), TINY.vocab, TINY.d, TINY.std,
+                      TINY.tied)
     assert (np.asarray(params["embed"]["head"]) == np.asarray(emb["head"])).all()
     assert jax.tree_util.tree_leaves(params)[0].dtype.name in ("bfloat16",
                                                                "float32")
@@ -148,13 +154,11 @@ def test_reference_matches_the_served_model():
     import jax
     import jax.numpy as jnp
 
-    import reference
-    import system as S
     from repro.models.transformer import make_model
 
     seed = 7
-    params = W.served_params(TINY, seed)
-    model = make_model(S.model_config(TINY), remat=False)
+    params = LLAMA.served_params(TINY, seed)
+    model = make_model(LLAMA.model_config(TINY), remat=False)
     seq = T.seed_rng(seed).integers(0, TINY.vocab, 40).astype(np.int32)
     tok = jnp.asarray(seq)[None]
     p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
@@ -162,30 +166,31 @@ def test_reference_matches_the_served_model():
         prog32 = np.asarray(model.forward_logits(p32, tokens=tok))[0]
     prog = np.asarray(model.forward_logits(params, tokens=tok))[0]
     pos = np.arange(40)
-    ref = reference.logits(TINY, seed, [seq], [pos])[0]
+    ref = LLAMA.logits(TINY, seed, [seq], [pos])[0]
     scale = np.abs(ref).max()
     assert np.abs(prog32 - ref).max() < 1e-4 * scale
     assert np.abs(prog - ref).max() < 0.1 * scale
     assert (prog.argmax(-1) == ref.argmax(-1)).mean() >= 0.9
-    for q in ("w8a8", "fp8"):
-        ctl = reference.logits(TINY, seed, [seq], [pos], quant=q)[0]
+    for q in LLAMA.CONTROLS:
+        ctl = LLAMA.logits(TINY, seed, [seq], [pos], quant=q)[0]
         assert np.abs(ctl - ref).max() > 1e-3 * scale
 
 
 # -- work counts and peaks ---------------------------------------------------
 
 def test_flop_and_byte_counts_by_hand():
-    s = W.shapes(CHIP / "configs" / "smollm-135m.json", "smollm-135m")
+    f = LLAMA
+    s = f.shapes(CHIP / "configs" / "smollm-135m.json", "smollm-135m")
     # q 576x576, k and v 576x192, o 576x576, three 576x1536
-    assert costs.layer_matmul_params(s) == (
+    assert f.layer_matmul_params(s) == (
         576 * 576 * 2 + 576 * 192 * 2 + 3 * 576 * 1536)
-    assert costs.kv_bytes_per_key(s) == 2 * 3 * 64 * 2 * 30
-    assert costs.attn_flops_per_key(s) == 4 * 9 * 64 * 30
+    assert f.kv_bytes_per_key(s) == 2 * 3 * 64 * 2 * 30
+    assert f.attn_flops_per_key(s) == 4 * 9 * 64 * 30
     assert costs.decode_keys(10, 3) == 11 + 12 + 13
-    per_token = 2 * 30 * costs.layer_matmul_params(s)
-    assert costs.prefill_flops(s, 4) == (
+    per_token = 2 * 30 * f.layer_matmul_params(s)
+    assert f.prefill_flops(s, 4) == (
         4 * per_token + 4 * 9 * 64 * 30 * (1 + 2 + 3 + 4) + 2 * 576 * 49152)
-    assert costs.token_flops(s, 5, True) == (
+    assert f.token_flops(s, 5, True) == (
         per_token + 4 * 9 * 64 * 30 * 5 + 2 * 576 * 49152)
     peak = costs.peaks("TPU v5 lite")
     assert costs.roofline_share(0, 819e9, 2.0, peak) == pytest.approx(50.0)
@@ -198,7 +203,6 @@ def test_token_time_readers():
     most tokens any request got in it."""
     from types import SimpleNamespace as NS
 
-    import bench
     a = NS(t_enqueue=10.0, t_tokens=[10.5, 10.5, 10.9, 10.9, 11.0],
            blocks=[1, 1, 2, 2, 3])  # first block 2 tokens, then 2, then 1
     c = NS(t_enqueue=10.0, t_tokens=[10.6, 11.0, 11.0],
@@ -219,12 +223,14 @@ def test_peaks_refuse_an_unknown_device():
         costs.peaks("TPU v9 imaginary")
 
 
-def test_warm_pairs_cover_every_cold_admission():
+@pytest.mark.parametrize("tr,want", [
+    (BATCH, [16, 32, 64, 128, 256, 512, 1024, 2048]),
+    (LONG_DECODE, [16, 32, 64, 128])], ids=["batch", "long-decode"])
+def test_warm_pairs_cover_every_cold_admission(tr, want):
     """Every request of the schedule, cold as it arrives or re-admitted
-    after preemption with part of its answer, finds its program warm."""
-    import bench
+    after preemption with part of its answer (as far as a prefill bucket
+    holds it), finds its program warm."""
     import system as S
-    tr = BATCH
     longest, _ = T.bounds(tr)
     buckets = bench.buckets_for(longest)
     sched = T.generate(tr, 1000, 3, 120.0)
@@ -232,7 +238,9 @@ def test_warm_pairs_cover_every_cold_admission():
                                        for x in sched]))
     for x in sched:
         for done in (0, x.budget // 2, x.budget - 1):
+            if len(x.prompt) + done > buckets[-1]:
+                continue
             b = S.bucket_of(len(x.prompt) + done, buckets)
             w = min(S.pages(b), S.pages(len(x.prompt) + x.budget))
             assert (b, w) in pairs
-    assert buckets == [16, 32, 64, 128, 256, 512, 1024, 2048]
+    assert buckets == want

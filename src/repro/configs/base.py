@@ -54,6 +54,13 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     local_window: int = 0  # >0 -> sliding-window attention width
     causal: bool = True
+    # kv_lora_rank > 0: multi-head latent attention (DeepSeek-V2/V3), which
+    # caches one compressed row [RMSNorm(c_kv) | rope(k_rope)] a token,
+    # shared by every head; head_dim is then qk_nope + qk_rope dims
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # mlp
     mlp_style: str = "swiglu"  # swiglu (3 mats) | mlp (2 mats) | none
@@ -65,6 +72,20 @@ class ModelConfig:
     moe_every: int = 1  # MoE on layers where (layer % moe_every == moe_every-1)
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
+    # experts this chip holds, [expert_offset, expert_offset +
+    # experts_held) of the n_experts the router scores.  0: the softmax
+    # top-k router and the capacity path, which hold them all.  Set: the
+    # DeepSeek-V3 layer (models/moe.moe_share) — noaux_tc routing (top-k
+    # of sigmoid scores + a selection bias; weights the unbiased scores
+    # normalized over the k, times routed_scale), this chip's share
+    # computed dropless, what absent experts add left out.
+    routed_scale: float = 1.0
+    experts_held: int = 0
+    expert_offset: int = 0
+    # the first `first_k_dense` layers are dense SwiGLU of width
+    # dense_d_ff, outside the layer scan (models/transformer.py)
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
 
     # hybrid (recurrentgemma): block pattern, e.g. ("rglru","rglru","attn")
     block_pattern: Tuple[str, ...] = ()
@@ -90,7 +111,9 @@ class ModelConfig:
 
     def __post_init__(self):
         if self.head_dim == 0:
-            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+            object.__setattr__(self, "head_dim", (
+                self.qk_nope_head_dim + self.qk_rope_head_dim
+                if self.kv_lora_rank else self.d_model // self.n_heads))
         assert self.n_heads % max(self.n_kv_heads, 1) == 0, self.name
 
     # -- derived ------------------------------------------------------------
@@ -98,6 +121,21 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def latent_row(self) -> int:
+        """Width of an MLA cache row: the compressed kv plus the rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_stride(self) -> int:
+        """Elements an MLA cache row takes in the arena: latent_row padded
+        with zeros to whole 128-lane tiles (640 for 512 + 64).  The TPU
+        lays an array out row-major only where that costs no padding;
+        576-wide rows would be laid out pages-minor, and every call of
+        the paged kernel (which reads them row-major) would first copy
+        the whole arena into that layout."""
+        return -(-self.latent_row // 128) * 128
 
     def block_kind(self, layer: int) -> str:
         """Kind of sequence-mixing block at `layer`."""

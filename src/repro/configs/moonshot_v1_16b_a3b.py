@@ -1,7 +1,12 @@
-"""moonshot-v1-16b-a3b — kimi/moonlight-style MoE, 64 experts top-6.
+"""moonshot-v1-16b-a3b — a 48-layer GQA stand-in named after
+Moonlight-16B-A3B (hf:moonshotai/Moonlight-16B-A3B), for the planner and
+cluster-builder tables: 48L d_model=2048 16H (GQA kv=16) d_ff=1408 (per
+expert) vocab=163840, 64 experts top-6 routed by softmax, every layer MoE.
 
-[hf:moonshotai/Moonlight-16B-A3B; hf].  Exact assigned dims: 48L d_model=2048
-16H (GQA kv=16) d_ff=1408 (per expert) vocab=163840, MoE 64e top-6.
+It departs from the published model, which has 27 layers, latent
+attention (MLA), a dense first layer and sigmoid (noaux_tc) routing.  The
+served Moonlight is `benchmarks/chip/configs/moonlight-16b-a3b-ep8.json`
+(families/deepseek_v3.py builds its ModelConfig).
 """
 from repro.configs.base import FULL_ATTENTION_SKIP, ModelConfig, register
 

@@ -120,15 +120,22 @@ def _live_pages(qpos, active, ps: int, maxp: int):
 
 
 def _paged_kernel(pt_ref, qpos_ref, active_ref, lane_ref, blk_ref, col_ref,
-                  q_ref, *refs, ppb: int, maxp: int):
+                  q_ref, *refs, ppb: int, maxp: int, latent: int = 0,
+                  stacked: bool = False):
     """Grid step t: block blk_ref[t] of lane lane_ref[t]'s live pages,
     `ppb` pages folded into the online softmax at once.  Refs after q:
     ppb k pages, ppb v pages, ppb kpos rows (the pages col_ref named),
     then o and the partials.  A lane with no live page has one step,
-    which writes its zeros."""
-    k_refs, v_refs, kp_refs = refs[:ppb], refs[ppb:2 * ppb], \
-        refs[2 * ppb:3 * ppb]
-    o_ref, m_ref, l_ref, acc_ref = refs[3 * ppb:]
+    which writes its zeros.  Latent mode (`latent` = the value width):
+    no v pages; a key's values are the first `latent` columns of its
+    own row, sliced from the block already loaded.  `stacked`: the
+    layer index is one more scalar ref, before q."""
+    if stacked:
+        q_ref, refs = refs[0], refs[1:]
+    nv = 0 if latent else ppb
+    k_refs, v_refs, kp_refs = refs[:ppb], refs[ppb:ppb + nv], \
+        refs[ppb + nv:2 * ppb + nv]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * ppb + nv:]
     t = pl.program_id(0)
     b, i = lane_ref[t], blk_ref[t]
     ps = kp_refs[0].shape[-1]
@@ -153,8 +160,12 @@ def _paged_kernel(pt_ref, qpos_ref, active_ref, lane_ref, blk_ref, col_ref,
         ks = [r[0] for r in k_refs]
         vs = [r[0] for r in v_refs]
         for h in range(q_ref.shape[1]):
-            k = jnp.concatenate([x[:, h, :] for x in ks], axis=0)
-            v = jnp.concatenate([x[:, h, :] for x in vs], axis=0)
+            if latent:  # (ps, R) pages: one row a key, no head axis
+                k = jnp.concatenate(ks, axis=0)
+                v = k[:, :latent]
+            else:
+                k = jnp.concatenate([x[:, h, :] for x in ks], axis=0)
+                v = jnp.concatenate([x[:, h, :] for x in vs], axis=0)
             # p is 0 there, but 0 * NaN is not: stale values stay out
             v = jnp.where(row < live, v, jnp.zeros_like(v))
             _online_softmax_step(q_ref[0, h], k, v, msk, m_ref, l_ref,
@@ -191,11 +202,12 @@ def _work_list(qpos, active, ps: int, maxp: int, ppb: int):
 
 
 def pages_per_block(ps: int, kvh: int, hd: int, itemsize: int,
-                    maxp: int) -> int:
+                    maxp: int, arenas: int = 2) -> int:
     """Pages a grid step folds in: enough keys to fill the 128 lanes of
     the score tile, no more K and V bytes than PAGED_BLOCK_BYTES, and no
-    more than a lane's table."""
-    page = 2 * ps * kvh * hd * itemsize  # K and V of one page
+    more than a lane's table.  `arenas`: 2 for separate K and V, 1 for
+    a latent arena."""
+    page = arenas * ps * kvh * hd * itemsize  # one page of every arena
     return max(1, min(maxp, 128 // ps, PAGED_BLOCK_BYTES // page))
 
 
@@ -291,11 +303,12 @@ def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,
       kpos.reshape(n_pages, 1, ps))
 
 
-@functools.partial(jax.jit, static_argnames=("ppb", "interpret"))
-def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
-                       kpos: jax.Array, page_table: jax.Array,
-                       qpos: jax.Array, active: jax.Array, *, ppb: int,
-                       interpret: bool = False) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("ppb", "latent", "interpret"))
+def paged_flash_decode(q: jax.Array, k: jax.Array, v, kpos: jax.Array,
+                       page_table: jax.Array, qpos: jax.Array,
+                       active: jax.Array, layer=None, *, ppb: int,
+                       latent: int = 0, interpret: bool = False
+                       ) -> jax.Array:
     """Split-KV decode over a *paged* KV arena.
 
     q: (B, KVH, G, hd) pre-scaled grouped queries; k/v: (P, ps, KVH, hd)
@@ -319,41 +332,58 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     block spans every kv head — its last two dims are the arena's own
     (KVH, hd), the tiling Mosaic accepts for any head count.  A lane's
     row depends on its own qpos, active and table row alone.
+
+    Latent mode (`latent` > 0, v None, KVH 1): k is a (P, ps, hd) arena
+    of MLA rows, and a key's values are its row's first `latent` columns
+    — one DMA a page, not two.  Returns (B, 1, G, latent).  With `layer`
+    (an int32 scalar, latent mode) k is the (L, P, ps, hd) arena of
+    every layer a scan steps through, kpos (L, P, ps), and the kernel
+    reads layer `layer`'s pages where they lie: the caller writes the
+    step's rows into the stacked arena and never slices a layer out.
     """
     b, kvh, g, hd = q.shape
-    n_pages, ps = kpos.shape
+    stacked = layer is not None
+    n_pages, ps = kpos.shape[-2:]
     maxp = page_table.shape[1]
+    dv = latent or hd
     steps, lanes, blks, cols = _work_list(qpos, active, ps, maxp, ppb)
     lane = lambda t, *s: (s[3][t], 0, 0, 0)  # noqa: E731
+    at = (None,) if stacked else ()  # the layer axis, squeezed
 
-    def page(c):  # block slot c's arena page at grid step t
-        def index(t, pt, qp, act, ln, bk, col):
+    def page(c):  # block slot c's (layer and) arena page at grid step t
+        def index(t, pt, qp, act, ln, bk, col, *lyr):
             j = col[t * ppb + c]
-            return jnp.where(j >= 0, pt[ln[t], jnp.maximum(j, 0)], 0)
+            p = jnp.where(j >= 0, pt[ln[t], jnp.maximum(j, 0)], 0)
+            return (lyr[0][0], p) if stacked else (p,)
         return index
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7 if stacked else 6,
         grid=(steps,),
         in_specs=[pl.BlockSpec((1, kvh, g, hd), lane),
-                  *[pl.BlockSpec((1, ps, kvh, hd),
-                                 lambda *a, f=page(c): (f(*a), 0, 0, 0))
-                    for c in range(ppb)] * 2,
-                  *[pl.BlockSpec((1, 1, ps),
-                                 lambda *a, f=page(c): (f(*a), 0, 0))
+                  *([pl.BlockSpec((*at, 1, ps, hd),
+                                  lambda *a, f=page(c): (*f(*a), 0, 0))
+                     for c in range(ppb)] if latent else
+                    [pl.BlockSpec((1, ps, kvh, hd),
+                                  lambda *a, f=page(c): (*f(*a), 0, 0, 0))
+                     for c in range(ppb)] * 2),
+                  *[pl.BlockSpec((*at, 1, 1, ps),
+                                 lambda *a, f=page(c): (*f(*a), 0, 0))
                     for c in range(ppb)]],
-        out_specs=pl.BlockSpec((1, kvh, g, hd), lane),
-        scratch_shapes=_scratch(kvh, g, hd),
+        out_specs=pl.BlockSpec((1, kvh, g, dv), lane),
+        scratch_shapes=_scratch(kvh, g, dv),
     )
-    kp3 = kpos.reshape(n_pages, 1, ps)
+    kp3 = kpos.reshape(*kpos.shape[:-1], 1, ps)
+    lyr = (jnp.asarray(layer, jnp.int32).reshape(1),) if stacked else ()
     return pl.pallas_call(
-        functools.partial(_paged_kernel, ppb=ppb, maxp=maxp),
+        functools.partial(_paged_kernel, ppb=ppb, maxp=maxp, latent=latent,
+                          stacked=stacked),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
         interpret=interpret,
         name="paged_flash_decode",
-    )(page_table, qpos, active, lanes, blks, cols, q, *[k] * ppb,
-      *[v] * ppb, *[kp3] * ppb)
+    )(page_table, qpos, active, lanes, blks, cols, *lyr, q, *[k] * ppb,
+      *([] if latent else [v] * ppb), *[kp3] * ppb)
 
 
 @functools.partial(

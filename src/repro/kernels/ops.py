@@ -316,6 +316,79 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     return out[:, :, :g].reshape(b, h, hd)
 
 
+def paged_flash_decode_latent(q: jax.Array, c: jax.Array, kpos: jax.Array,
+                              page_table: jax.Array, qpos: jax.Array,
+                              dv: int, active: Optional[jax.Array] = None,
+                              layer=None,
+                              impl: Optional[str] = None) -> jax.Array:
+    """Absorbed MLA decode attention over a latent paged arena.
+
+    q: (B, H, R) *pre-scaled* latent queries ([q_nope . W_UK | rope(q_rope)]);
+    c: (P, ps, R) arena of cache rows [RMSNorm(c_kv) | rope(k_rope)],
+    one a token, shared by every head; each key's values are its row's
+    first `dv` columns (c_kv).  Otherwise `paged_flash_decode`'s contract:
+    the H heads ride one group over the single latent "kv head", and the
+    kernel loads each page once (its latent mode).  With `layer` (int32
+    scalar) c is (L, P, ps, R) and kpos (L, P, ps), every layer's
+    arena stacked, and layer `layer` is attended in place.  Returns
+    (B, H, dv).
+    """
+    impl = impl or default_impl()
+    if impl == "ref":
+        if layer is not None:
+            c, kpos = c[layer], kpos[layer]
+        return _ref.paged_flash_decode_latent(q, c, kpos, page_table, qpos,
+                                              dv, active=active)
+    from repro.kernels import flash_decode as _fd
+
+    b, h, r = q.shape
+    qg = q.reshape(b, 1, h, r)
+    gp = _rup(h, 8)  # group dim is the sublane axis: pad to tile granularity
+    if gp != h:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - h), (0, 0)))
+    act = (jnp.ones((b,), jnp.int32) if active is None
+           else active.astype(jnp.int32).reshape(b))
+    ppb = _fd.pages_per_block(c.shape[-2], 1, r, c.dtype.itemsize,
+                              page_table.shape[1], arenas=1)
+    out = _fd.paged_flash_decode(
+        qg, c, None, kpos.astype(jnp.int32), page_table.astype(jnp.int32),
+        qpos.astype(jnp.int32), act, layer, ppb=ppb, latent=dv,
+        interpret=impl == "interpret")
+    return out[:, 0, :h]
+
+
+def expert_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+               offset: int, impl: Optional[str] = None) -> jax.Array:
+    """Grouped matmul over the held experts: lhs (M, K) rows sorted by
+    expert, group_sizes (E,) rows of every expert, rhs (G, K, N) experts
+    offset..offset+G-1.  Rows of other experts, and rows past every
+    group, return 0.  The kernel is megablox's `gmm` with `group_offset`
+    (an expert's weights stream through VMEM once per row tile its group
+    touches; an expert with no rows is never read); it names its Pallas
+    kernel `kernel`, so a device trace shows its calls as `kernel.<n>`.
+    M is padded here to the row tile."""
+    impl = impl or default_impl()
+    if impl == "ref":
+        return _ref.expert_gmm(lhs, rhs, group_sizes, offset)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tm = min(128, _rup(m, 16))
+    # a k or n block is 512 wide where that divides, else the whole dim
+    tiles = (tm, 512 if k % 512 == 0 else k, 512 if n % 512 == 0 else n)
+    sizes = group_sizes.astype(jnp.int32)
+    out = gmm(_pad_to(lhs, tm, 0), rhs, sizes,
+              preferred_element_type=lhs.dtype, tiling=tiles,
+              group_offset=jnp.int32(offset),
+              interpret=impl == "interpret")[:m]
+    ends = jnp.cumsum(sizes)
+    row = jnp.arange(m)[:, None]
+    held = ((row >= ends[offset] - sizes[offset])
+            & (row < ends[offset + rhs.shape[0] - 1]))
+    return jnp.where(held, out, jnp.zeros_like(out))
+
+
 def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,
                          k_scale: jax.Array, v_scale: jax.Array,
                          kpos: jax.Array, page_table: jax.Array,

@@ -80,7 +80,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.any(msk, -1, keepdims=True), p, 0.0)
     out = jnp.einsum("bngk,bknd->bngd", p.astype(q.dtype), v)
-    return out.reshape(b, h, hd)
+    return out.reshape(b, h, v.shape[-1])
 
 
 def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -101,9 +101,39 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     b = q.shape[0]
     kvh, hd = k.shape[2], k.shape[3]
     kg = k[page_table].reshape(b, -1, kvh, hd)
-    vg = v[page_table].reshape(b, -1, kvh, hd)
+    vg = v[page_table].reshape(b, -1, kvh, v.shape[3])
     kpg = kpos[page_table].reshape(b, -1)
     return flash_decode(q, kg, vg, kpg, qpos, active=active)
+
+
+def paged_flash_decode_latent(q: jax.Array, c: jax.Array, kpos: jax.Array,
+                              page_table: jax.Array, qpos: jax.Array,
+                              dv: int,
+                              active: Optional[jax.Array] = None
+                              ) -> jax.Array:
+    """Oracle for the paged decode kernel's latent mode: keys are the
+    (P, ps, R) arena's rows, values their first `dv` columns."""
+    c = c[:, :, None, :]  # one kv head
+    return paged_flash_decode(q, c, c[..., :dv], kpos, page_table, qpos,
+                              active=active)
+
+
+def expert_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+               offset) -> jax.Array:
+    """Oracle for the grouped expert matmul: row r of the expert-sorted
+    lhs times its expert's rhs when that expert is one of the G held
+    from `offset`, else 0.  f32 products, lhs.dtype result."""
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    starts = ends - group_sizes
+    row = jnp.arange(lhs.shape[0])[:, None]
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32)
+    for g in range(rhs.shape[0]):
+        e = offset + g
+        mine = (row >= starts[e]) & (row < ends[e])
+        out += jnp.where(mine, jnp.dot(lhs, rhs[g],
+                                       preferred_element_type=jnp.float32),
+                         0.0)
+    return out.astype(lhs.dtype)
 
 
 def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -30,6 +30,8 @@ NEG_INF = -1e30
 
 
 def attn_init(key, cfg) -> Params:
+    if cfg.kv_lora_rank:
+        return mla_init(key, cfg)
     d, nh, nkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     ks = jax.random.split(key, 4)
     return {
@@ -112,7 +114,7 @@ def _chunked_attention(q, k, v, q_pos, k_pos, causal, window,
     n_chunks = k.shape[1] // chunk
 
     kc = k.reshape(b, n_chunks, chunk, kvh, hd).swapaxes(0, 1)
-    vc = v.reshape(b, n_chunks, chunk, kvh, hd).swapaxes(0, 1)
+    vc = v.reshape(b, n_chunks, chunk, kvh, v.shape[-1]).swapaxes(0, 1)
     pc = k_pos.reshape(b, n_chunks, chunk).swapaxes(0, 1)
     sc = (k_seg.reshape(b, n_chunks, chunk).swapaxes(0, 1)
           if k_seg is not None else None)
@@ -141,7 +143,7 @@ def _chunked_attention(q, k, v, q_pos, k_pos, causal, window,
     init = (
         jnp.full((b, h, sq), NEG_INF, jnp.float32),
         jnp.zeros((b, h, sq), jnp.float32),
-        jnp.zeros((b, h, sq, hd), jnp.float32),
+        jnp.zeros((b, h, sq, v.shape[-1]), jnp.float32),
     )
     xs = (kc, vc, pc) + ((sc,) if sc is not None else ())
     # checkpoint the chunk body: backward recomputes each chunk's scores
@@ -222,6 +224,9 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
     parked lane can never corrupt live pages — the paged analogue of the
     dense path's cache_map where-masking).
     """
+    if cfg.kv_lora_rank:
+        return mla_attention(x, p, cfg, positions, segment_ids, cache,
+                             page_table, active)
     from repro.kernels import ops as kops
     from repro.models.layers import dense
     from repro.models.shard_hints import (
@@ -479,6 +484,11 @@ def init_paged_attn_cache(cfg, num_pages: int, page_size: int,
     it, so every lane reading the page dequantizes identically.
     """
     assert not cfg.local_window, "paged KV does not support sliding windows"
+    if cfg.kv_lora_rank:
+        assert not quantized, "the latent arena has no int8 form"
+        return {"ckv": jnp.zeros((num_pages, page_size, cfg.latent_stride),
+                                 dtype),
+                "kpos": jnp.full((num_pages, page_size), 2**30, jnp.int32)}
     kv_shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     cache = {
         "k": jnp.zeros(kv_shape, jnp.int8 if quantized else dtype),
@@ -494,6 +504,9 @@ def init_paged_attn_cache(cfg, num_pages: int, page_size: int,
 def init_attn_cache(cfg, batch: int, seq_len: int, dtype=COMPUTE_DTYPE):
     """KV cache; ring buffer of local_window slots when windowed."""
     slots = min(seq_len, cfg.local_window) if cfg.local_window else seq_len
+    if cfg.kv_lora_rank:
+        return {"ckv": jnp.zeros((batch, slots, cfg.latent_stride), dtype),
+                "kpos": jnp.full((batch, slots), 2**30, jnp.int32)}
     return {
         "k": jnp.zeros((batch, slots, cfg.n_kv_heads, cfg.head_dim), dtype),
         "v": jnp.zeros((batch, slots, cfg.n_kv_heads, cfg.head_dim), dtype),
@@ -501,3 +514,119 @@ def init_attn_cache(cfg, batch: int, seq_len: int, dtype=COMPUTE_DTYPE):
         # causal mask excludes them (also excludes padded prompt columns)
         "kpos": jnp.full((batch, slots), 2**30, jnp.int32),
     }
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+
+
+def mla_init(key, cfg) -> Params:
+    """q straight from x (no q LoRA); keys and values from one compressed
+    row: wkv_a gives [c_kv | k_rope], c_kv is RMS-normed, and wkv_b
+    expands it to each head's [k_nope | v]."""
+    d, nh, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], d, nh * cfg.head_dim),
+        "wkv_a": dense_init(ks[1], d, cfg.latent_row),
+        "kv_norm": {"g": jnp.ones((r,), jnp.float32)},
+        "wkv_b": dense_init(ks[2], r,
+                            nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "wo": dense_init(ks[3], nh * cfg.v_head_dim, d),
+    }
+
+
+def mla_attention(x, p, cfg, positions, segment_ids=None, cache=None,
+                  page_table=None, active=None):
+    """MLA block.  Every path writes and reads the same cache row per
+    token, [RMSNorm(c_kv) | rope(k_rope) | zeros] (cfg.latent_row wide,
+    stored cfg.latent_stride wide, bf16), so prefill buckets, prefix-hit
+    pages and decode writes are one format.  Prefill (and training)
+    expands the row per head: q.k over qk_nope + qk_rope dims, v of
+    v_head_dim.  Decode is absorbed: q_nope is multiplied into W_UK to
+    give a kv_lora_rank-wide latent query, the scores run against whole
+    rows, the output is p . c_kv, and W_UV maps it to each head's v
+    space, through the paged kernel's latent mode.  RoPE is rotate-half
+    over the rope dims at the token's position (modeling_deepseek
+    de-interleaves its rope
+    columns first: with random weights the two differ by a fixed
+    permutation of those columns).  Softmax scale 1/sqrt(head_dim)."""
+    from repro.kernels import ops as kops
+    from repro.models.layers import dense, rmsnorm
+
+    b, s, _ = x.shape
+    nh, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    q = dense(x, p["wq"]).reshape(b, s, nh, cfg.head_dim)
+    q_nope = q[..., :dn]
+    q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    kva = dense(x, p["wkv_a"])
+    ckv = rmsnorm(kva[..., :r], p["kv_norm"])
+    k_rope = apply_rope(kva[..., None, r:], positions, cfg.rope_theta)
+    pad = jnp.zeros((b, s, cfg.latent_stride - cfg.latent_row), ckv.dtype)
+    row = jnp.concatenate([ckv, k_rope[:, :, 0], pad],
+                          axis=-1).astype(COMPUTE_DTYPE)  # (B, S, stride)
+    w_b = p["wkv_b"].reshape(r, nh, dn + dv)
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+
+    if cache is None or (s > 1 and page_table is None):
+        kv = jnp.einsum("bsr,rhe->bshe", row[..., :r], w_b)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(
+                row[:, :, None, r:cfg.latent_row],
+                (b, s, nh, cfg.qk_rope_head_dim))], axis=-1)
+        qs = jnp.concatenate([q_nope, q_rope], axis=-1) * scale
+        if s <= DENSE_ATTN_MAX_KV:
+            msk = _mask(s, s, positions, positions, cfg.causal, 0,
+                        segment_ids, segment_ids)
+            out = _dense_attention(qs, k, kv[..., dn:], msk)
+        else:
+            out = _chunked_attention(qs, k, kv[..., dn:], positions,
+                                     positions, cfg.causal, 0, segment_ids,
+                                     segment_ids)
+        new_cache = None
+        if cache is not None:  # prefill: rows + positions into the bucket
+            slots = cache["ckv"].shape[1]
+            take = min(s, slots)
+            idx = jnp.arange(take, dtype=jnp.int32)
+            new_cache = {
+                "ckv": cache["ckv"].at[:, idx].set(row[:, -take:]),
+                "kpos": cache["kpos"].at[:, idx].set(
+                    positions[:, -take:].astype(jnp.int32))}
+    else:
+        if s > 1 or page_table is None:
+            raise NotImplementedError(
+                "MLA decodes one token a step from the paged arena: it has "
+                "no dense slot rows and no multi-query verify")
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_b[..., :dn])
+        qa = jnp.concatenate([q_lat, q_rope[:, 0], jnp.zeros(
+            (b, nh, cfg.latent_stride - cfg.latent_row), q_lat.dtype)],
+            axis=-1) * scale  # the rows' zero pad scores 0
+        cpos = positions[:, 0].astype(jnp.int32)
+        act = (jnp.ones((b,), bool) if active is None
+               else active.astype(bool))
+        # the scatter and its trash-page redirect are the GQA paged path's;
+        # the kernel loads each latent page once.  A cache with "layer"
+        # holds every scanned layer's arena stacked (models/transformer.py
+        # carries it through the layer scan): the rows go into that
+        # layer's pages and the kernel reads them there, so no layer's
+        # arena is sliced out or written back
+        c, layer = cache["ckv"], cache.get("layer")
+        at = () if layer is None else (layer,)
+        ps = c.shape[-2]
+        page = page_table[jnp.arange(b), cpos // ps]
+        wr_page = jnp.where(act, page, 0)
+        wr_off = jnp.where(act, cpos % ps, 0)
+        c = c.at[(*at, wr_page, wr_off)].set(row[:, 0])
+        kpos = cache["kpos"].at[(*at, wr_page, wr_off)].set(
+            jnp.where(act, cpos, jnp.int32(2 ** 30)))
+        route = "pallas" if kops.default_impl() == "pallas" else "ref"
+        o_lat = kops.paged_flash_decode_latent(
+            qa, c, kpos, page_table, cpos, r, active=act, layer=layer,
+            impl=route)
+        out = jnp.einsum("bhr,rhv->bhv", o_lat.astype(COMPUTE_DTYPE),
+                         w_b[..., dn:])[:, None]
+        new_cache = {"ckv": c, "kpos": kpos}
+    out = out.reshape(b, s, nh * dv)
+    return dense(out, p["wo"]), new_cache

@@ -25,14 +25,20 @@ Params = Dict[str, Any]
 
 
 def moe_init(key, cfg) -> Params:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """Router over all n_experts; expert weights for the experts held
+    (all of them unless cfg.experts_held says otherwise)."""
+    d, f = cfg.d_model, cfg.d_ff
+    e = cfg.experts_held or cfg.n_experts
     ks = jax.random.split(key, 5)
     s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(f)
     p = {
-        "router": (jax.random.normal(ks[0], (d, e)) * 0.02).astype(jnp.float32),
+        "router": (jax.random.normal(ks[0], (d, cfg.n_experts)) * 0.02
+                   ).astype(jnp.float32),
         "wi": (jax.random.normal(ks[1], (e, d, f)) * s_in).astype(jnp.bfloat16),
         "wo": (jax.random.normal(ks[2], (e, f, d)) * s_out).astype(jnp.bfloat16),
     }
+    if cfg.experts_held:  # e_score_correction_bias: selection only
+        p["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
     if cfg.mlp_style == "swiglu":
         p["wg"] = (jax.random.normal(ks[3], (e, d, f)) * s_in).astype(jnp.bfloat16)
     if cfg.n_shared_experts:
@@ -119,14 +125,88 @@ def moe_ffn(x: jax.Array, p: Params, cfg) -> Tuple[jax.Array, jax.Array]:
     y = jnp.sum((back * w[..., None]).reshape(bsz, s, k, d), axis=2)
 
     if cfg.n_shared_experts:
-        hs = jnp.einsum("bsd,df->bsf", x, p["shared_wi"])
-        if cfg.mlp_style == "swiglu":
-            hs = act(jnp.einsum("bsd,df->bsf", x, p["shared_wg"])) * hs
-        else:
-            hs = act(hs)
-        # serve_exact gathers / serve_psum keeps f-sharded before the
-        # shared_wo reduction, mirroring the dense mlp (no-ops elsewhere)
-        hs = hint(hint(hs, "gather"), "psum")
-        y = y + jnp.einsum("bsf,fd->bsd", hs, p["shared_wo"])
+        y = y + _shared_experts(x, p, cfg)
 
     return y, aux.astype(jnp.float32)
+
+
+def _shared_experts(x, p, cfg):
+    from repro.models.shard_hints import hint
+
+    act = act_fn(cfg.act)
+    hs = jnp.einsum("bsd,df->bsf", x, p["shared_wi"])
+    if cfg.mlp_style == "swiglu":
+        hs = act(jnp.einsum("bsd,df->bsf", x, p["shared_wg"])) * hs
+    else:
+        hs = act(hs)
+    # serve_exact gathers / serve_psum keeps f-sharded before the
+    # shared_wo reduction, mirroring the dense mlp (no-ops elsewhere)
+    hs = hint(hint(hs, "gather"), "psum")
+    return jnp.einsum("bsf,fd->bsd", hs, p["shared_wo"])
+
+
+# counters the expert share returns, in this order (engine.stats keys)
+MOE_COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_experts_hit")
+
+
+def sigmoid_route(x: jax.Array, p: Params, cfg):
+    """DeepSeek-V3 `noaux_tc` routing (n_group = topk_group = 1), float32:
+    scores sigmoid(x . router); the top-k of scores + router_bias choose
+    the experts; their weights are the chosen scores without the bias,
+    normalized over the k, times cfg.routed_scale.  x: (T, D) ->
+    (weights (T, k) f32, expert ids (T, k) int32)."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + p["router_bias"], cfg.top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scale
+    return w, ids.astype(jnp.int32)
+
+
+def moe_share(x: jax.Array, p: Params, cfg, valid=None):
+    """This chip's part of a sigmoid-routed expert layer, dropless.
+
+    Routes every token over all n_experts, keeps the (token, expert)
+    pairs whose expert is held here ([expert_offset, + experts_held)),
+    sorts them by expert and runs the SwiGLU experts as three grouped
+    matmuls over the held experts only (kernels/ops.expert_gmm; every
+    held pair is computed, none dropped), then combines each token's
+    pairs with their routing weights; the shared experts are added once.
+    What absent experts would add is left out (no exchange: their chips
+    compute it).  `valid` (B, S) marks real tokens: padding and inactive
+    lanes route to no expert and are not counted.
+
+    Returns (y, counts): counts is int32 (3,) in MOE_COUNTERS order —
+    the valid tokens' pairs, those that landed on held experts, and the
+    held experts with at least one pair."""
+    from repro.kernels import ops as kops
+
+    bsz, s, d = x.shape
+    t, k, e = bsz * s, cfg.top_k, cfg.n_experts
+    off, g = cfg.expert_offset, cfg.experts_held
+    xf = x.reshape(t, d)
+    w, ids = sigmoid_route(xf, p, cfg)
+    ok = (jnp.ones((t,), bool) if valid is None else valid.reshape(t))
+    held = (ids >= off) & (ids < off + g) & ok[:, None]  # (T, k)
+    key = jnp.where(held, ids, e).reshape(-1)  # unheld pairs sort last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=e + 1)[:e].astype(jnp.int32)
+    xs = jnp.take(xf, order // k, axis=0)
+    act = act_fn(cfg.act)
+    # the kernel routes compiled only, like the paged decode kernels: its
+    # interpreted grid would dominate CPU serving (tests/test_mla_moe.py
+    # runs it in interpret mode against the jnp form)
+    impl = "pallas" if kops.default_impl() == "pallas" else "ref"
+    hi = kops.expert_gmm(xs, p["wi"], sizes, off, impl=impl)
+    hi = act(kops.expert_gmm(xs, p["wg"], sizes, off, impl=impl)) * hi
+    ys = kops.expert_gmm(hi, p["wo"], sizes, off, impl=impl)
+    back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(t, k, d)
+    wk = jnp.where(held, w, 0.0)
+    y = jnp.einsum("tkd,tk->td", back.astype(jnp.float32), wk)
+    y = y.astype(x.dtype).reshape(bsz, s, d)
+    if cfg.n_shared_experts:
+        y = y + _shared_experts(x, p, cfg)
+    counts = jnp.stack([k * ok.sum(), held.sum(),
+                        (sizes[off:off + g] > 0).sum()]).astype(jnp.int32)
+    return y, counts

@@ -6,6 +6,11 @@ and scanned with stacked parameters, so HLO size is O(period), not O(L) —
 essential to keep 512-device SPMD compiles tractable (DESIGN.md §3).  The
 remainder layers (e.g. recurrentgemma's 26 = 8*3 + 2) are unrolled as a tail.
 
+Leading dense layers (`cfg.first_k_dense`, DeepSeek-V3's
+`first_k_dense_replace`) sit before the scan, unrolled, each with its own
+params and cache entry under "lead"; the scan covers the layers after
+them.  Trees of configs without them have no "lead" key.
+
 The same block code serves train (no state), prefill (state in/out), and
 decode (single-token state update), switched by the cache pytree.
 """
@@ -24,7 +29,7 @@ from repro.models.layers import (
     COMPUTE_DTYPE, cross_entropy_chunked, embed, embed_init, lm_head,
     mlp, mlp_init, norm, norm_init,
 )
-from repro.models.moe import moe_ffn, moe_init
+from repro.models.moe import MOE_COUNTERS, moe_ffn, moe_init, moe_share
 
 Params = Dict[str, Any]
 
@@ -45,10 +50,30 @@ def period_length(cfg: ModelConfig) -> int:
 
 
 def layer_plan(cfg: ModelConfig) -> Tuple[int, int, List[str]]:
-    """(n_repeat, tail_len, kinds-of-one-period)."""
+    """(n_repeat, tail_len, kinds-of-one-period) of the layers after the
+    cfg.first_k_dense leading dense ones."""
     p = period_length(cfg)
     kinds = [cfg.block_kind(i) for i in range(p)]
-    return cfg.n_layers // p, cfg.n_layers % p, kinds
+    n = cfg.n_layers - cfg.first_k_dense
+    return n // p, n % p, kinds
+
+
+LAYER_KEYS = ("lead", "scan", "tail")  # the subtrees that hold layers
+
+
+def _layers(tree) -> Dict[str, Any]:
+    return {k: tree[k] for k in LAYER_KEYS if k in tree}
+
+
+def _counts0(cfg: ModelConfig):
+    """The expert share's routing counters (models/moe.MOE_COUNTERS)
+    before any layer adds to them; None for a model without a share."""
+    return (jnp.zeros((len(MOE_COUNTERS),), jnp.int32) if cfg.experts_held
+            else None)
+
+
+def _add(acc, c):
+    return acc if c is None else acc + c
 
 
 # ---------------------------------------------------------------------------
@@ -56,7 +81,9 @@ def layer_plan(cfg: ModelConfig) -> Tuple[int, int, List[str]]:
 # ---------------------------------------------------------------------------
 
 
-def block_init(key, cfg: ModelConfig, pos_in_period: int) -> Params:
+def block_init(key, cfg: ModelConfig, pos_in_period: int,
+               lead: bool = False) -> Params:
+    """lead: a leading dense layer (SwiGLU of width cfg.dense_d_ff)."""
     kind = cfg.block_kind(pos_in_period)
     ks = jax.random.split(key, 3)
     p: Params = {"norm1": norm_init(cfg)}
@@ -72,7 +99,9 @@ def block_init(key, cfg: ModelConfig, pos_in_period: int) -> Params:
         raise ValueError(kind)
     if cfg.family != "ssm":
         p["norm2"] = norm_init(cfg)
-        if cfg.is_moe_layer(pos_in_period):
+        if lead:
+            p["ffn"] = mlp_init(ks[1], cfg, d_ff=cfg.dense_d_ff)
+        elif cfg.is_moe_layer(pos_in_period):
             p["ffn"] = moe_init(ks[1], cfg)
         else:
             p["ffn"] = mlp_init(ks[1], cfg)
@@ -81,8 +110,11 @@ def block_init(key, cfg: ModelConfig, pos_in_period: int) -> Params:
 
 def block_apply(cfg: ModelConfig, pos_in_period: int, p: Params, h: jax.Array,
                 positions: jax.Array, segment_ids, state,
-                pos_contiguous: bool = False, page_table=None, active=None):
-    """Returns (h, new_state, aux_loss)."""
+                pos_contiguous: bool = False, page_table=None, active=None,
+                lead: bool = False):
+    """Returns (h, new_state, aux_loss, counts): counts are an expert
+    share's int32 routing counters (models/moe.MOE_COUNTERS), None for
+    every other block."""
     kind = cfg.block_kind(pos_in_period)
     z = norm(h, p["norm1"], cfg)
     if kind == "attn":
@@ -103,15 +135,22 @@ def block_apply(cfg: ModelConfig, pos_in_period: int, p: Params, h: jax.Array,
         else:  # slstm
             y, new_state = rec_mod.slstm_block(z, p["mix"], cfg, state, valid)
     h = h + y
-    aux = jnp.float32(0.0)
+    aux, counts = jnp.float32(0.0), None
     if "ffn" in p:
         z = norm(h, p["norm2"], cfg)
-        if cfg.is_moe_layer(pos_in_period):
+        if lead:
+            y = mlp(z, p["ffn"], cfg)
+        elif cfg.is_moe_layer(pos_in_period) and cfg.experts_held:
+            valid = positions < 2**29  # not a pad
+            if active is not None:
+                valid &= active.astype(bool)[:, None]
+            y, counts = moe_share(z, p["ffn"], cfg, valid)
+        elif cfg.is_moe_layer(pos_in_period):
             y, aux = moe_ffn(z, p["ffn"], cfg)
         else:
             y = mlp(z, p["ffn"], cfg)
         h = h + y
-    return h, new_state, aux
+    return h, new_state, aux, counts
 
 
 def block_init_state(cfg: ModelConfig, pos_in_period: int, batch: int,
@@ -192,12 +231,13 @@ def quantize_kv_tree(tree):
 
 
 def paged_cache_map(fn, *trees):
-    """Map ``fn(page_axis, leaf_name, *leaves)`` over the scan/tail arena
-    leaves of paged cache pytrees.
+    """Map ``fn(page_axis, leaf_name, *leaves)`` over the layer arena
+    leaves of paged cache pytrees ("lead", "scan", "tail").
 
-    Arena leaves ((P, ps, ...) under "tail", (n_rep, P, ps, ...) under
-    "scan") have a *page* axis where dense slot caches have a batch axis;
-    top-level "pos"/"pt" are per-lane and handled by the caller.
+    Arena leaves ((P, ps, ...) under "lead" and "tail", (n_rep, P, ps,
+    ...) under "scan") have a *page* axis where dense slot caches have a
+    batch axis; top-level "pos"/"pt" are per-lane and handled by the
+    caller.
     """
 
     def go(axis, name, *subs):
@@ -206,7 +246,7 @@ def paged_cache_map(fn, *trees):
         return fn(axis, name, *subs)
 
     return {k: (go(1 if k == "scan" else 0, k, *[t[k] for t in trees])
-                if k in ("scan", "tail") else trees[0][k])
+                if k in LAYER_KEYS else trees[0][k])
             for k in trees[0]}
 
 
@@ -302,12 +342,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     tail_params = {
         str(t): block_init(keys[n_rep + t], cfg, t) for t in range(tail)
     }
-    return {
+    out = {
         "embed": embed_init(keys[-2], cfg),
         "scan": scan_params,
         "tail": tail_params,
         "final_norm": norm_init(cfg),
     }
+    if cfg.first_k_dense:
+        lk = jax.random.split(jax.random.fold_in(key, 1 << 20),
+                              cfg.first_k_dense)
+        out["lead"] = {str(i): block_init(lk[i], cfg, 0, lead=True)
+                       for i in range(cfg.first_k_dense)}
+    return out
 
 
 class Model:
@@ -322,7 +368,9 @@ class Model:
     def backbone(self, params: Params, h: jax.Array, positions: jax.Array,
                  segment_ids=None, caches=None, pos_contiguous: bool = False,
                  page_table=None, active=None):
-        """h: (B,S,D) embeddings -> (h_final, new_caches, aux).
+        """h: (B,S,D) embeddings -> (h_final, new_caches, aux, counts):
+        counts sums the expert share's routing counters over the layers
+        (None without a share).
 
         pos_contiguous: positions are a plain broadcast arange (no pad
         sentinels) — lets long-prefill attention take the Pallas fused path.
@@ -334,19 +382,30 @@ class Model:
         n_rep, tail, kinds = layer_plan(cfg)
         np_ = len(kinds)
 
-        def period_fn(h, period_params, period_caches):
+        new_lead = {}
+        for i in range(cfg.first_k_dense):  # dense MLPs: no aux, no counts
+            st = None if caches is None else caches["lead"][str(i)]
+            h, new_lead[str(i)], _, _ = block_apply(
+                cfg, 0, params["lead"][str(i)], h, positions, segment_ids,
+                st, pos_contiguous=pos_contiguous, page_table=page_table,
+                active=active, lead=True)
+
+        def period_fn(h, period_params, period_caches, layer=None):
             new_caches = {}
-            aux = jnp.float32(0.0)
+            aux, counts = jnp.float32(0.0), _counts0(cfg)
             for i in range(np_):
                 st = None if period_caches is None else period_caches[f"b{i}"]
-                h, ns, a = block_apply(cfg, i, period_params[f"b{i}"], h,
-                                       positions, segment_ids, st,
-                                       pos_contiguous=pos_contiguous,
-                                       page_table=page_table, active=active)
+                if layer is not None:  # stacked arenas, this layer's index
+                    st = dict(st, layer=layer)
+                h, ns, a, c = block_apply(cfg, i, period_params[f"b{i}"], h,
+                                          positions, segment_ids, st,
+                                          pos_contiguous=pos_contiguous,
+                                          page_table=page_table,
+                                          active=active)
                 if period_caches is not None:
                     new_caches[f"b{i}"] = ns
-                aux = aux + a
-            return h, new_caches, aux
+                aux, counts = aux + a, _add(counts, c)
+            return h, new_caches, aux, counts
 
         pf = period_fn
         if self.remat and caches is None:
@@ -355,38 +414,56 @@ class Model:
                 policy=jax.checkpoint_policies.nothing_saveable,
             )
 
-        if n_rep:
+        if n_rep and caches is not None and page_table is not None \
+                and cfg.kv_lora_rank:
+            # latent decode: the stacked arenas ride the carry and each
+            # layer writes its rows into, and attends over, its own pages
+            # in place (models/attention.mla_attention); as scanned xs/ys
+            # they would be sliced out and written back a layer at a time,
+            # and the ys would be a second whole arena
+            def carry_body(carry, pp):
+                h, aux_acc, cnt_acc, arenas, li = carry
+                h, arenas, aux, cnt = pf(h, pp, arenas, li)
+                return (h, aux_acc + aux, _add(cnt_acc, cnt), arenas,
+                        li + 1), None
+
+            (h, aux, counts, new_scan_caches, _), _ = jax.lax.scan(
+                carry_body, (h, jnp.float32(0.0), _counts0(cfg),
+                             caches["scan"], jnp.int32(0)), params["scan"])
+        elif n_rep:
             def scan_body(carry, xs):
-                h, aux_acc = carry
+                h, aux_acc, cnt_acc = carry
                 pp = xs[0] if caches is not None else xs
                 pc = xs[1] if caches is not None else None
-                h, ncache, aux = pf(h, pp, pc)
-                return (h, aux_acc + aux), (ncache if caches is not None
-                                            else None)
+                h, ncache, aux, cnt = pf(h, pp, pc)
+                return (h, aux_acc + aux, _add(cnt_acc, cnt)), (
+                    ncache if caches is not None else None)
 
             xs = (params["scan"], caches["scan"]) if caches is not None \
                 else params["scan"]
-            (h, aux), new_scan_caches = jax.lax.scan(
-                scan_body, (h, jnp.float32(0.0)), xs)
+            (h, aux, counts), new_scan_caches = jax.lax.scan(
+                scan_body, (h, jnp.float32(0.0), _counts0(cfg)), xs)
         else:
-            aux = jnp.float32(0.0)
+            aux, counts = jnp.float32(0.0), _counts0(cfg)
             new_scan_caches = None
 
         new_tail = {}
         for t in range(tail):
             st = None if caches is None else caches["tail"][str(t)]
-            h, ns, a = block_apply(cfg, t, params["tail"][str(t)], h,
-                                   positions, segment_ids, st,
-                                   pos_contiguous=pos_contiguous,
-                                   page_table=page_table, active=active)
+            h, ns, a, c = block_apply(cfg, t, params["tail"][str(t)], h,
+                                      positions, segment_ids, st,
+                                      pos_contiguous=pos_contiguous,
+                                      page_table=page_table, active=active)
             if caches is not None:
                 new_tail[str(t)] = ns
-            aux = aux + a
+            aux, counts = aux + a, _add(counts, c)
 
         h = norm(h, params["final_norm"], cfg)
         new_caches = (None if caches is None else
                       {"scan": new_scan_caches, "tail": new_tail})
-        return h, new_caches, aux
+        if caches is not None and cfg.first_k_dense:
+            new_caches["lead"] = new_lead
+        return h, new_caches, aux, counts
 
     def embed_inputs(self, params, tokens=None, embeds=None):
         if embeds is not None:
@@ -405,9 +482,9 @@ class Model:
         contiguous = positions is None
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-        h, _, aux = self.backbone(params, x, positions,
-                                  batch.get("segment_ids"),
-                                  pos_contiguous=contiguous)
+        h, _, aux, _ = self.backbone(params, x, positions,
+                                     batch.get("segment_ids"),
+                                     pos_contiguous=contiguous)
         ce = cross_entropy_chunked(h, batch["labels"], params["embed"])
         return ce + 0.01 * aux
 
@@ -417,8 +494,8 @@ class Model:
         contiguous = positions is None
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-        h, _, _ = self.backbone(params, x, positions,
-                                pos_contiguous=contiguous)
+        h, _, _, _ = self.backbone(params, x, positions,
+                                   pos_contiguous=contiguous)
         return lm_head(h, params["embed"])
 
     def init_cache(self, batch: int, seq_len: int):
@@ -434,8 +511,12 @@ class Model:
         ) if n_rep else {}
         tail_caches = {str(t): block_init_state(cfg, t, batch, seq_len)
                        for t in range(tail)}
-        return {"scan": scan_caches, "tail": tail_caches,
-                "pos": jnp.zeros((batch,), jnp.int32)}
+        out = {"scan": scan_caches, "tail": tail_caches,
+               "pos": jnp.zeros((batch,), jnp.int32)}
+        if cfg.first_k_dense:
+            out["lead"] = {str(i): block_init_state(cfg, 0, batch, seq_len)
+                           for i in range(cfg.first_k_dense)}
+        return out
 
     def init_paged_cache(self, batch: int, num_pages: int, page_size: int,
                          max_pages: int, kv_dtype: str = "bf16"):
@@ -470,9 +551,16 @@ class Model:
         tail_caches = {str(t): attn_mod.init_paged_attn_cache(
             cfg, num_pages, page_size, quantized=quant)
             for t in range(tail)}
-        return {"scan": scan_caches, "tail": tail_caches,
-                "pos": jnp.zeros((batch,), jnp.int32),
-                "pt": jnp.zeros((batch, max_pages), jnp.int32)}
+        out = {"scan": scan_caches, "tail": tail_caches,
+               "pos": jnp.zeros((batch,), jnp.int32),
+               "pt": jnp.zeros((batch, max_pages), jnp.int32)}
+        if cfg.first_k_dense:
+            out["lead"] = {str(i): attn_mod.init_paged_attn_cache(
+                cfg, num_pages, page_size, quantized=quant)
+                for i in range(cfg.first_k_dense)}
+        if cfg.experts_held:  # the decode program's routing counters
+            out["moe"] = jnp.zeros((3,), jnp.int32)
+        return out
 
     def prefill(self, params, caches, tokens=None, embeds=None,
                 positions=None, last_idx=None):
@@ -489,9 +577,9 @@ class Model:
             positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         if last_idx is None:
             last_idx = jnp.full((b,), s - 1, jnp.int32)
-        sub = {"scan": caches["scan"], "tail": caches["tail"]}
-        h, sub, _ = self.backbone(params, x, positions, caches=sub,
-                                  pos_contiguous=contiguous)
+        h, sub, _, _ = self.backbone(params, x, positions,
+                                     caches=_layers(caches),
+                                     pos_contiguous=contiguous)
         bidx = jnp.arange(b)
         last_pos = positions[bidx, last_idx].astype(jnp.int32)
         caches = dict(sub, pos=last_pos + 1)
@@ -513,18 +601,22 @@ class Model:
         else:
             x = token[:, None, :].astype(COMPUTE_DTYPE)
         positions = caches["pos"][:, None]
-        sub = {"scan": caches["scan"], "tail": caches["tail"]}
+        sub = _layers(caches)
         if "pt" in caches:
             # paged: writes are already active-gated inside attention (an
             # arena has no batch axis for cache_map's where-masking), so
             # only the per-lane position counters need the mask here
-            h, sub, _ = self.backbone(params, x, positions, caches=sub,
-                                      page_table=caches["pt"], active=active)
+            h, sub, _, counts = self.backbone(params, x, positions,
+                                              caches=sub,
+                                              page_table=caches["pt"],
+                                              active=active)
             pos = caches["pos"] + 1 if active is None else jnp.where(
                 active, caches["pos"] + 1, caches["pos"])
-            return (lm_head(h[:, -1:], params["embed"])[:, 0],
-                    dict(sub, pos=pos, pt=caches["pt"]))
-        h, sub, _ = self.backbone(params, x, positions, caches=sub)
+            out = dict(sub, pos=pos, pt=caches["pt"])
+            if "moe" in caches:  # the expert share's routing counters
+                out["moe"] = caches["moe"] + counts
+            return lm_head(h[:, -1:], params["embed"])[:, 0], out
+        h, sub, _, _ = self.backbone(params, x, positions, caches=sub)
         new_caches = dict(sub, pos=caches["pos"] + 1)
         if active is not None:
             new_caches = cache_map(
@@ -577,6 +669,8 @@ class Model:
             eos_id = jnp.full((b,), -1, jnp.int32)
         if budget is None:
             budget = jnp.full((b,), 2 ** 30, jnp.int32)
+        if "moe" in caches:  # counters count this dispatch's steps
+            caches = dict(caches, moe=jnp.zeros_like(caches["moe"]))
 
         if forced is None:
 
@@ -669,11 +763,11 @@ class Model:
         x = self.embed_inputs(params, tokens=tokens)
         positions = (caches["pos"][:, None]
                      + jnp.arange(t, dtype=jnp.int32)[None, :])
-        sub = {"scan": caches["scan"], "tail": caches["tail"]}
-        h, sub, _ = self.backbone(params, x, positions, caches=sub,
-                                  page_table=caches["pt"], active=active)
+        h, sub, _, _ = self.backbone(params, x, positions,
+                                     caches=_layers(caches),
+                                     page_table=caches["pt"], active=active)
         logits = lm_head(h, params["embed"])
-        return logits, dict(sub, pos=caches["pos"], pt=caches["pt"])
+        return logits, dict(caches, **sub)
 
     def spec_decode_step(self, params, caches, token: jax.Array,
                          active: jax.Array, n_draft: int,
@@ -791,14 +885,11 @@ class Model:
             return (b.at[write_pages].set(s) if page_axis == 0
                     else b.at[:, write_pages].set(s))
 
-        sub = {"scan": small["scan"], "tail": small["tail"]} \
-            if small is not None else None
         out = paged_cache_map(
             lambda ax, name, bb: leaf(ax, name, bb, None), big) \
-            if sub is None else paged_cache_map(
+            if small is None else paged_cache_map(
                 lambda ax, name, bb, ss: leaf(ax, name, bb, ss), big,
-                {"scan": sub["scan"], "tail": sub["tail"],
-                 "pos": big["pos"], "pt": big["pt"]})
+                _layers(small))
         out["pos"] = big["pos"].at[slot].set(jnp.asarray(pos0, jnp.int32))
         out["pt"] = big["pt"].at[slot].set(
             jnp.asarray(pt_row, jnp.int32))

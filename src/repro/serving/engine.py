@@ -14,10 +14,12 @@ from __future__ import annotations
 import time
 from typing import List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.packing import bucket_len
+from repro.models.moe import MOE_COUNTERS
 from repro.models.transformer import Model
 from repro.serving.executor import PAD_TOKEN, Executor
 from repro.serving.kv_manager import (KVManager, kv_page_bytes,
@@ -91,14 +93,53 @@ class EngineBase:
         with span("first_token_sync", self.stats, "sync_s"):
             return np.asarray(jnp.argmax(logits, -1), np.int32)
 
-    def _fetch_block(self, toks, t_step: float) -> np.ndarray:
+    def _fetch_block(self, toks, t_step: float, counts=None) -> np.ndarray:
         """The block sync, the only per-dispatch device-to-host read; it
         closes the dispatch's decode_s, counted from `t_step`, taken
-        just before the dispatch was enqueued."""
+        just before the dispatch was enqueued.  `counts`, the expert
+        share's routing counters of the same dispatch, come back in the
+        same read and are added to stats (models/moe.MOE_COUNTERS)."""
         with span("block_sync", self.stats, "sync_s"):
-            block = np.asarray(toks)
+            if counts is None:
+                block = np.asarray(toks)
+            else:
+                block, counts = jax.device_get((toks, counts))
+                for k, v in zip(MOE_COUNTERS, counts.tolist()):
+                    self.stats[k] += v
         self.stats["decode_s"] += time.perf_counter() - t_step
         return block
+
+
+def refuse_unbuilt(cfg, plan, kv_dtype: str, spec_config, disagg,
+                   paged) -> None:
+    """Latent attention, leading dense layers and the expert share run on
+    the paged bf16 arena of one device only; each path they lack is
+    refused here, naming it, instead of falling back."""
+    if cfg.kv_lora_rank:  # MLA
+        if paged is False:
+            raise ValueError(
+                "MLA with paged=False: the latent path decodes from the "
+                "paged arena only (no dense slot rows)")
+        if kv_dtype != "bf16":
+            raise ValueError(
+                "MLA with kv_dtype='int8': the latent arena has no quantized "
+                "row format and the paged kernel's latent mode no int8 "
+                "variant")
+        if spec_config:
+            raise ValueError(
+                "MLA with spec_config: the latent path has no multi-query "
+                "paged verify (Model.verify_block)")
+    if plan is not None and (cfg.kv_lora_rank or cfg.first_k_dense
+                             or cfg.experts_held):
+        raise ValueError(
+            "MLA, leading dense layers or an expert share under a "
+            "ClusterPlan: the plan has no latent-arena or expert-axis specs, "
+            "and its shard_map heads split a kv-head dim the latent arena "
+            "does not have")
+    if disagg is not None and cfg.first_k_dense:
+        raise ValueError(
+            "disagg with leading dense layers: page shipping carries the "
+            "scan and tail arenas only")
 
 
 class ContinuousBatchingEngine(EngineBase):
@@ -116,8 +157,12 @@ class ContinuousBatchingEngine(EngineBase):
                  kv_dtype: str = "bf16",
                  spec_config: Optional[dict] = None,
                  disagg: Optional[Tuple[int, int]] = None, **kw):
+        refuse_unbuilt((args[0] if args else kw["model"]).cfg,
+                       kw.get("plan"), kv_dtype, spec_config, disagg, paged)
         super().__init__(*args, **kw)
         self.disagg = tuple(disagg) if disagg is not None else None
+        if self.model.cfg.experts_held:
+            self.stats.update(dict.fromkeys(MOE_COUNTERS, 0))
         self.stats.update(admitted=0, completed=0, prefills=0,
                           active_lane_steps=0)
         self._slot_caches = None
@@ -447,8 +492,9 @@ class ContinuousBatchingEngine(EngineBase):
         if walk:
             self.stats["decode_pages_grid"] += n * self.max_batch * maxp
 
-    def _reconcile(self, toks, slots, done, n: int, t_step: float) -> None:
-        block = self._fetch_block(toks, t_step)
+    def _reconcile(self, toks, slots, done, n: int, t_step: float,
+                   counts=None) -> None:
+        block = self._fetch_block(toks, t_step, counts)
         with span("reconcile", self.stats, "reconcile_s"):
             if self.paged:  # a spec engine's draft lags, catchup later
                 self._advance_mirrors(block, slots, n, walk=True)
@@ -583,7 +629,8 @@ class ContinuousBatchingEngine(EngineBase):
                     self._reconcile_spec(toks, slots, done, k, t_step)
                 else:
                     toks = self.executor.decode(st, n, self.paged)
-                    self._reconcile(toks, slots, done, n, t_step)
+                    self._reconcile(toks, slots, done, n, t_step,
+                                    st["caches"].get("moe"))
 
         if self.paged:
             self.kv.assert_drained()

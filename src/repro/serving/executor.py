@@ -774,9 +774,9 @@ class Executor:
                                 a, row0, mb, 0), pc)
                         new_sl = {}
                         for i in range(np_):
-                            h, ns, _ = block_apply(cfg, i, pp[f"b{i}"], h,
-                                                   pos_sl, None,
-                                                   pc_sl[f"b{i}"])
+                            h, ns, _, _ = block_apply(cfg, i, pp[f"b{i}"],
+                                                      h, pos_sl, None,
+                                                      pc_sl[f"b{i}"])
                             new_sl[f"b{i}"] = ns
 
                         def upd(full, nsl):
@@ -940,7 +940,7 @@ class Executor:
                     pp, pc = xs
                     new_pc = {}
                     for i in range(np_):
-                        h, ns, _ = block_apply(
+                        h, ns, _, _ = block_apply(
                             cfg, i, pp[f"b{i}"], h, pos_sl[:, None], None,
                             pc[f"b{i}"], page_table=pt_sl, active=wr)
                         new_pc[f"b{i}"] = ns

@@ -30,7 +30,10 @@ def kv_page_bytes(cfg, page_size: int, kv_dtype: str,
 
     bf16: 2 (k+v) * KVH * hd elements at 2 B per cache row; int8: the same
     elements at 1 B plus 2 * KVH f32 scales per row, i.e. (hd+4)/(2*hd) of
-    the bf16 bytes — a fixed budget holds ~2x the pages at hd=64.
+    the bf16 bytes — a fixed budget holds ~2x the pages at hd=64.  MLA:
+    one latent row a token, stored cfg.latent_stride elements wide (the
+    kv_lora_rank + rope dims zero-padded to whole 128-lane tiles) at
+    2 B — 1280 B a layer at Moonlight's 512 + 64.
 
     shards > 1: the arena is stage-sharded (exact=False serve_pipeline —
     each stage holds only its own layers' slice of every page), so a page
@@ -42,7 +45,11 @@ def kv_page_bytes(cfg, page_size: int, kv_dtype: str,
     (`KVManager(shards=)`) guards against.
     """
     per_row = 2 * cfg.n_kv_heads * cfg.head_dim  # k+v elements
-    if kv_dtype == "int8":
+    if cfg.kv_lora_rank:  # MLA: one latent row a token, bf16 only
+        if kv_dtype != "bf16":
+            raise ValueError("the MLA latent arena is bf16 only")
+        row_bytes = cfg.latent_stride * 2
+    elif kv_dtype == "int8":
         row_bytes = per_row + 2 * cfg.n_kv_heads * 4  # values + f32 scales
     else:
         row_bytes = per_row * 2

@@ -137,6 +137,104 @@ def test_paged_decode_skips_pages_past_the_bound(monkeypatch):
     np.testing.assert_allclose(got, np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
+LATENT_R, LATENT_DV = 24, 16  # a latent row (kv rank + rope) and its values
+
+
+def _with_latent_ppb(monkeypatch, ppb, ps, maxp, itemsize=4):
+    """As _with_ppb, for the latent arena's one row a key."""
+    monkeypatch.setattr(fd, "PAGED_BLOCK_BYTES",
+                        ppb * ps * LATENT_R * itemsize)
+    assert fd.pages_per_block(ps, 1, LATENT_R, itemsize, maxp,
+                              arenas=1) == ppb
+
+
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["one_layer", "stacked_layers"])
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_paged_decode_latent_interpret_matches_ref(walk, seed, stacked,
+                                                   monkeypatch):
+    """The kernel's latent mode (MLA absorbed decode): one (P, ps, R)
+    arena, every query head in one group, each key's values the first dv
+    columns of its own row; interpret == oracle over the same walks.
+    Stacked: three layers' arenas (L, P, ps, R) and kpos (L, P, ps),
+    the kernel told layer 1, against the oracle over layer 1 alone."""
+    h, _, ps, maxp, ppb, qpos, active = WALKS[walk]
+    rng = np.random.default_rng(seed)
+    b = len(qpos)
+    n_pages = 1 + b * maxp + 1
+    q, c, _, kpos, pt, _ = _mk_paged(rng, b, h, 1, LATENT_R, n_pages, ps,
+                                     maxp)
+    c = c[:, :, 0]  # (P, ps, R): one row a key
+    qpos = jnp.asarray(qpos, jnp.int32)
+    active = jnp.asarray(active, bool)
+    _with_latent_ppb(monkeypatch, ppb, ps, maxp)
+    want = ops.paged_flash_decode_latent(q, c, kpos, pt, qpos, LATENT_DV,
+                                         active=active, impl="ref")
+    layer = None
+    if stacked:  # layers 0 and 2 hold other rows and other positions
+        other = jnp.asarray(rng.normal(0, 1, c.shape), c.dtype)
+        c = jnp.stack([other, c, -other])
+        kpos = jnp.stack([kpos[::-1], kpos, kpos + 1])
+        layer = jnp.int32(1)
+    got = ops.paged_flash_decode_latent(q, c, kpos, pt, qpos, LATENT_DV,
+                                        active=active, layer=layer,
+                                        impl="interpret")
+    assert got.shape == (b, h, LATENT_DV)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def _pallas_operands(fn, *args):
+    """Operand avals of the pallas_call(s) `fn` traces to."""
+    import jax
+    out = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.append([v.aval for v in eqn.invars])
+            for p in eqn.params.values():
+                if hasattr(p, "jaxpr") and hasattr(p.jaxpr, "eqns"):
+                    walk(p.jaxpr)
+                elif hasattr(p, "eqns"):
+                    walk(p)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return out
+
+
+def test_paged_decode_latent_one_dma_a_page_and_nan_past_the_bound(
+        monkeypatch):
+    """Rows of every page past a lane's live pages are NaN (kpos
+    sentinel): the latent walk never folds them in, so the output stays
+    finite and equals the oracle over a clean arena.  And the arena is
+    one operand a block slot (ppb in all), not two as for K and V: each
+    page is loaded once and its values sliced from it."""
+    rng = np.random.default_rng(3)
+    b, h, ps, maxp, ppb = 4, 16, 8, 6, 2
+    q, c, _, kpos, pt, _ = _mk_paged(rng, b, h, 1, LATENT_R, 1 + b * maxp,
+                                     ps, maxp, share=False)
+    c = c[:, :, 0]  # (P, ps, R): one row a key
+    qpos = np.asarray([5, 20, 47, 33], np.int32)
+    dead = [0] + [int(pt[i, j]) for i in range(b) for j in range(maxp)
+                  if j > qpos[i] // ps]
+    kpos = kpos.at[np.asarray(dead)].set(SENTINEL)
+    c_nan = c.at[np.asarray(dead)].set(jnp.nan)
+    qpos = jnp.asarray(qpos)
+    _with_latent_ppb(monkeypatch, ppb, ps, maxp)
+
+    def call(cc):
+        return ops.paged_flash_decode_latent(q, cc, kpos, pt, qpos,
+                                             LATENT_DV, impl="interpret")
+    got = np.asarray(call(c_nan))
+    want = ops.paged_flash_decode_latent(q, c, kpos, pt, qpos, LATENT_DV,
+                                         impl="ref")
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), atol=2e-5, rtol=2e-5)
+    (avals,) = _pallas_operands(call, c_nan)
+    assert sum(a.shape == c.shape for a in avals) == ppb
+
+
 @given(st.integers(0, 10_000))
 @settings(max_examples=8, deadline=None)
 def test_paged_ref_bitwise_equals_dense_ref(seed):

@@ -5,7 +5,12 @@ Interpret mode never sees Mosaic's block-shape and lowering rules; these
 cases do.  Widths are smollm-135m's (9 query / 3 kv heads, head_dim 64,
 d_model 576, d_ff 1536, max batch 8) and ibert-base's (hidden 768, d_ff
 3072, 12 heads, sequence 128); the paged decode kernel also compiles at
-both benchmark cells' exact shapes.  The topology is described inside a
+both benchmark cells' exact shapes, and its latent mode (one layer, and
+26 layers stacked) and the grouped expert matmul at
+moonlight-16b-a3b.long-decode's (batch 64, 16 heads over one latent row
+of 512 + 64 stored 640 wide, 12289 pages of 192; 8 held of 64 experts,
+top 6, hidden 2048, expert width 1408); a latent decode program compiles
+with no second arena among its buffers.  The topology is described inside a
 fixture, never at import, so that only the pytest worker that runs this
 file loads the TPU compiler.
 """
@@ -68,6 +73,28 @@ def _paged_cell(b, h, kvh, hd, pages, maxp):
     return f, shapes
 
 
+def _paged_latent(b, h, r, dv, pages, maxp, layers=0):
+    """The latent mode at a cell's exact shapes (bf16 arena, 16-row
+    pages); `layers` > 0: that many layers' arenas stacked, one read."""
+    at = (layers,) if layers else ()
+    shapes = [((b, h, r), jnp.bfloat16), ((*at, pages, 16, r), jnp.bfloat16),
+              ((*at, pages, 16), jnp.int32), ((b, maxp), jnp.int32),
+              ((b,), jnp.int32), ((b,), jnp.bool_)]
+
+    def f(q, c, kpos, pt, qpos, act):
+        return ops.paged_flash_decode_latent(
+            q, c, kpos, pt, qpos, dv, active=act,
+            layer=jnp.int32(layers // 2) if layers else None, impl="pallas")
+    return f, shapes
+
+
+def _expert_gmm(m, k, n, held, experts):
+    def f(x, w, sizes):
+        return ops.expert_gmm(x, w, sizes, 0, impl="pallas")
+    return f, [((m, k), jnp.bfloat16), ((held, k, n), jnp.bfloat16),
+               ((experts,), jnp.int32)]
+
+
 def _dense_decode():
     def f(q, k, v, kpos, qpos, act):
         return ops.flash_decode(q, k, v, kpos, qpos, active=act,
@@ -123,6 +150,14 @@ CASES = {
     # phi3-medium-14b.batch: batch 16, 40 q / 10 kv heads, 2305 of 144
     "paged_decode_phi3_batch": lambda: _paged_cell(16, 40, 10, 128, 2305,
                                                    144),
+    # moonlight-16b-a3b.long-decode: 64 lanes x top 6 expert rows
+    "paged_decode_moonlight_latent": lambda: _paged_latent(64, 16, 640, 512,
+                                                           12289, 192),
+    "paged_decode_moonlight_latent_stacked": lambda: _paged_latent(
+        64, 16, 640, 512, 12289, 192, layers=26),
+    "expert_gmm_moonlight_gate_up": lambda: _expert_gmm(384, 2048, 1408, 8,
+                                                        64),
+    "expert_gmm_moonlight_down": lambda: _expert_gmm(384, 1408, 2048, 8, 64),
     "flash_decode_dense": _dense_decode,
     "flash_attention_prefill": _prefill,
     "int8_matmul": _int8_matmul,
@@ -139,3 +174,46 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
         == 1
+
+
+def test_latent_decode_program_holds_one_arena(one_chip):
+    """The paged decode program of a latent-attention model carries its
+    stacked arena through the layer scan and attends in place: among its
+    buffers there is no second arena (as scanned xs/ys there was one, and
+    576-wide rows laid out pages-minor cost a whole-arena copy a call).
+    Small widths, many pages (2049: laid out pages-minor, 576-wide rows
+    would pad less than their own 64 lanes), so the arena dominates every
+    other buffer."""
+    from repro.configs.base import ModelConfig
+    from repro.models.transformer import Model, init_params
+    cfg = ModelConfig(name="tiny-mla", family="moe", n_layers=8, d_model=256,
+                      n_heads=4, n_kv_heads=1, d_ff=128, vocab_size=512,
+                      kv_lora_rank=512, qk_nope_head_dim=32,
+                      qk_rope_head_dim=64, v_head_dim=32, mlp_style="swiglu",
+                      act="silu", n_experts=16, top_k=2, moe_every=1,
+                      n_shared_experts=1, routed_scale=2.0, experts_held=4,
+                      first_k_dense=1, dense_d_ff=256, tie_embeddings=False,
+                      norm="rmsnorm")
+    model = Model(cfg, remat=False)
+    b, ps, maxp = 32, 16, 64
+    pages = b * maxp + 1
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    caches = jax.eval_shape(lambda: model.init_paged_cache(b, pages, ps,
+                                                           maxp))
+
+    def pfn(params, caches, token, active, eos, budget):
+        return model.decode_steps(params, caches, token, active, 4,
+                                  eos_id=eos, budget=budget)
+    i32 = jax.ShapeDtypeStruct((b,), jnp.int32)
+    args = (params, caches, i32, jax.ShapeDtypeStruct((b,), jnp.bool_),
+            i32, i32)
+    with ops.pinned_impl("pallas"):
+        compiled = jax.jit(
+            pfn, donate_argnums=(1,),
+            in_shardings=jax.tree.map(lambda _: one_chip, args)
+        ).lower(*args).compile()
+    arena = caches["scan"]["b0"]["ckv"]
+    arena_bytes = arena.size * arena.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < arena_bytes / 2
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        >= 2  # the latent kernel (lead layer and scan) and the expert gmm

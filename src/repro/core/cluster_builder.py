@@ -163,12 +163,14 @@ class ClusterPlan:
         must stay unsharded or every insert crosses data shards.
 
         paged=True: the cache tree is a *paged arena*
-        (`Model.init_paged_cache`) — per-layer `k`/`v` arenas
-        (P, ps, KVH, hd) and `k_scale`/`v_scale` planes (P, ps, KVH) shard
-        the kv-head dim over `model` (decode reads and the per-step scatter
-        writes stay shard-local: the scatter addresses pages/offsets, never
-        the head dim), while `kpos`, the per-lane page tables `pt` and the
-        position counters `pos` replicate — the page table is the *shared*
+        (`Model.init_paged_cache`) — the bf16 arena's rows `kv`
+        (P, ps, 2*KVH*hd), whole kv heads side by side, and the int8
+        path's `k`/`v` arenas (P, ps, KVH, hd) and `k_scale`/`v_scale`
+        planes (P, ps, KVH) shard their kv heads over `model` (decode
+        reads and the per-step scatter writes stay shard-local: the
+        scatter addresses pages/offsets, never the head dim), while
+        `kpos`, the per-lane page tables `pt` and the position counters
+        `pos` replicate — the page table is the *shared*
         routing metadata every model shard walks identically, the TPU
         analogue of the paper's gateway routing tables."""
         if self.mode == "serve_pipeline":
@@ -178,8 +180,9 @@ class ClusterPlan:
                 lambda p, s: _stage_spec(p, s, self.axes.stage, n))
         r = Rules(self.mesh, self.axes, fsdp=self.fsdp)
         if paged:
+            kvh = self.cfg.n_kv_heads
             return _tree_specs(
-                caches_shape, lambda p, s: _paged_cache_spec(p, s, r))
+                caches_shape, lambda p, s: _paged_cache_spec(p, s, r, kvh))
         return _tree_specs(
             caches_shape,
             lambda p, s: _cache_spec(p, s, r, batch, mode=self.mode,
@@ -379,12 +382,15 @@ def _cache_spec(path: Tuple[str, ...], shape: Tuple[int, ...], r: Rules,
 
 
 def _paged_cache_spec(path: Tuple[str, ...], shape: Tuple[int, ...],
-                      r: Rules) -> P:
-    """Leaf rules for a paged KV arena (serve mode).
+                      r: Rules, kvh: int) -> P:
+    """Leaf rules for a paged KV arena (serve mode) of `kvh` kv heads.
 
     Arena leaves have a *page* axis where dense slot caches have batch:
-    `k`/`v` (P, ps, KVH, hd) and the int8 path's `k_scale`/`v_scale`
-    (P, ps, KVH) put the kv-head dim on `model` when divisible — the reads
+    the bf16 rows `kv` (P, ps, 2*KVH*hd), each head's K and V side by
+    side, put their row axis on `model` when the kv heads divide it (a
+    shard then holds whole heads); the int8 path's `k`/`v` (P, ps, KVH,
+    hd) and `k_scale`/`v_scale` (P, ps, KVH) put the kv-head dim there
+    when divisible — the reads
     (paged_flash_decode under shard_map) and the decode scatter (addressed
     by page/offset) then never cross shards.  `kpos` (P, ps), the per-lane
     page tables `pt` (B, MAXP) and position counters `pos` (B,) replicate:
@@ -395,7 +401,9 @@ def _paged_cache_spec(path: Tuple[str, ...], shape: Tuple[int, ...],
     off = 1 if in_scan else 0
     s = shape[off:]
     parts: List[Any] = [None] * len(shape)
-    if name in ("k", "v") and len(s) == 4 and s[2] % r.tp_n == 0:
+    if name == "kv" and len(s) == 3 and kvh % r.tp_n == 0:
+        parts[off + 2] = r.axes.tp
+    elif name in ("k", "v") and len(s) == 4 and s[2] % r.tp_n == 0:
         parts[off + 2] = r.axes.tp
     elif name in ("k_scale", "v_scale") and len(s) == 3 \
             and s[2] % r.tp_n == 0:

@@ -34,7 +34,11 @@ the grid's DMA schedule — the gather costs nothing beyond the block
 fetches the dense kernel already does, and radix-shared prefix pages are
 fetched per lane that names them, never duplicated in HBM.  Its grid is a
 work list of blocks of several pages, and a lane walks only the pages its
-query can reach.
+query can reach.  Its bf16 arena holds one row a token, each kv head's K
+then its V, so a page is one DMA of whole rows; given a layer index it
+reads one layer's pages of the stacked arena a layer scan carries.  The
+int8 arena keeps separate (P, ps, KVH, hd) K and V and its own kernel,
+`paged_flash_decode_q`.
 """
 from __future__ import annotations
 
@@ -48,7 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 BS = 256  # default KV split length (sublane dim of the k/v blocks)
 NEG_INF = -1e30
 KPOS_SENTINEL = 2 ** 30  # never-written cache slot (models/attention.py)
-PAGED_BLOCK_BYTES = 1 << 20  # K and V bytes one paged grid step holds
+PAGED_BLOCK_BYTES = 1 << 20  # arena bytes one paged grid step holds
 
 
 def _online_softmax_step(q, k, v, msk, m_ref, l_ref, acc_ref, h: int):
@@ -120,25 +124,27 @@ def _live_pages(qpos, active, ps: int, maxp: int):
 
 
 def _paged_kernel(pt_ref, qpos_ref, active_ref, lane_ref, blk_ref, col_ref,
-                  q_ref, *refs, ppb: int, maxp: int, latent: int = 0,
+                  *refs, ppb: int, maxp: int, latent: int = 0,
                   stacked: bool = False):
     """Grid step t: block blk_ref[t] of lane lane_ref[t]'s live pages,
-    `ppb` pages folded into the online softmax at once.  Refs after q:
-    ppb k pages, ppb v pages, ppb kpos rows (the pages col_ref named),
+    `ppb` pages folded into the online softmax at once.  Refs after the
+    scalars: q, ppb arena pages, ppb kpos rows (the pages col_ref named),
     then o and the partials.  A lane with no live page has one step,
-    which writes its zeros.  Latent mode (`latent` = the value width):
-    no v pages; a key's values are the first `latent` columns of its
-    own row, sliced from the block already loaded.  `stacked`: the
-    layer index is one more scalar ref, before q."""
+    which writes its zeros.  Each page is one DMA of whole rows.  Dense
+    mode: a row holds each kv head's K then its V, head after head, and
+    head h's keys and values are sliced from the block already loaded.
+    Latent mode (`latent` = the value width): one row a key, its values
+    the row's first `latent` columns.  `stacked`: the layer index is one
+    more scalar ref, before q."""
     if stacked:
-        q_ref, refs = refs[0], refs[1:]
-    nv = 0 if latent else ppb
-    k_refs, v_refs, kp_refs = refs[:ppb], refs[ppb:ppb + nv], \
-        refs[ppb + nv:2 * ppb + nv]
-    o_ref, m_ref, l_ref, acc_ref = refs[2 * ppb + nv:]
+        refs = refs[1:]
+    q_ref, kv_refs = refs[0], refs[1:ppb + 1]
+    kp_refs = refs[ppb + 1:2 * ppb + 1]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * ppb + 1:]
     t = pl.program_id(0)
     b, i = lane_ref[t], blk_ref[t]
     ps = kp_refs[0].shape[-1]
+    hd = q_ref.shape[-1]
     n = _live_pages(qpos_ref[b], active_ref[b], ps, maxp)
 
     @pl.when(i == 0)
@@ -155,17 +161,15 @@ def _paged_kernel(pt_ref, qpos_ref, active_ref, lane_ref, blk_ref, col_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
         row = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
         msk = (kpos <= qpos_ref[b]) & (col < live)
-        # whole pages, loaded once: a head sliced out of a loaded page
+        # whole pages, loaded once: a head sliced out of a loaded block
         # costs far less than a strided load of it from each ref
-        ks = [r[0] for r in k_refs]
-        vs = [r[0] for r in v_refs]
+        x = jnp.concatenate([r[0] for r in kv_refs], axis=0)  # (width, R)
         for h in range(q_ref.shape[1]):
-            if latent:  # (ps, R) pages: one row a key, no head axis
-                k = jnp.concatenate(ks, axis=0)
-                v = k[:, :latent]
+            if latent:
+                k, v = x, x[:, :latent]
             else:
-                k = jnp.concatenate([x[:, h, :] for x in ks], axis=0)
-                v = jnp.concatenate([x[:, h, :] for x in vs], axis=0)
+                k = x[:, 2 * h * hd:(2 * h + 1) * hd]
+                v = x[:, (2 * h + 1) * hd:(2 * h + 2) * hd]
             # p is 0 there, but 0 * NaN is not: stale values stay out
             v = jnp.where(row < live, v, jnp.zeros_like(v))
             _online_softmax_step(q_ref[0, h], k, v, msk, m_ref, l_ref,
@@ -201,14 +205,12 @@ def _work_list(qpos, active, ps: int, maxp: int, ppb: int):
     return end[-1], as32(lanes), as32(blks), as32(cols.reshape(-1))
 
 
-def pages_per_block(ps: int, kvh: int, hd: int, itemsize: int,
-                    maxp: int, arenas: int = 2) -> int:
+def pages_per_block(ps: int, row_bytes: int, maxp: int) -> int:
     """Pages a grid step folds in: enough keys to fill the 128 lanes of
-    the score tile, no more K and V bytes than PAGED_BLOCK_BYTES, and no
-    more than a lane's table.  `arenas`: 2 for separate K and V, 1 for
-    a latent arena."""
-    page = arenas * ps * kvh * hd * itemsize  # one page of every arena
-    return max(1, min(maxp, 128 // ps, PAGED_BLOCK_BYTES // page))
+    the score tile, no more arena bytes than PAGED_BLOCK_BYTES (a page
+    is ps rows of `row_bytes`: every kv head's K and V, or one latent
+    row), and no more than a lane's table."""
+    return max(1, min(maxp, 128 // ps, PAGED_BLOCK_BYTES // (ps * row_bytes)))
 
 
 def _paged_kernel_q(pt_ref, qpos_ref, active_ref, q_ref, k_ref, v_ref,
@@ -304,72 +306,76 @@ def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("ppb", "latent", "interpret"))
-def paged_flash_decode(q: jax.Array, k: jax.Array, v, kpos: jax.Array,
+def paged_flash_decode(q: jax.Array, kv: jax.Array, kpos: jax.Array,
                        page_table: jax.Array, qpos: jax.Array,
                        active: jax.Array, layer=None, *, ppb: int,
                        latent: int = 0, interpret: bool = False
                        ) -> jax.Array:
     """Split-KV decode over a *paged* KV arena.
 
-    q: (B, KVH, G, hd) pre-scaled grouped queries; k/v: (P, ps, KVH, hd)
-    global page arenas shared by every lane; kpos: (P, ps) int32 absolute
-    positions per arena slot (2^30 = never written); page_table:
-    (B, MAXP) int32 — lane b's logical KV positions [j*ps, (j+1)*ps) live
-    in arena page page_table[b, j].  Entries may repeat across lanes
-    (radix-shared prefixes) and unused entries may point anywhere whose
-    kpos are all sentinel (the allocator's trash page 0).  qpos: (B,)
-    int32; active: (B,) int32 row gate.  Returns (B, KVH, G, hd).
+    q: (B, KVH, G, hd) pre-scaled grouped queries; kv: (P, ps,
+    2*KVH*hd) global page arena shared by every lane, one row a token
+    holding each kv head's K then its V, head after head ([k_0 | v_0 |
+    k_1 | v_1 | ...]); kpos: (P, ps) int32 absolute positions per arena
+    slot (2^30 = never written); page_table: (B, MAXP) int32 — lane b's
+    logical KV positions [j*ps, (j+1)*ps) live in arena page
+    page_table[b, j].  Entries may repeat across lanes (radix-shared
+    prefixes) and unused entries may point anywhere whose kpos are all
+    sentinel (the allocator's trash page 0).  qpos: (B,) int32; active:
+    (B,) int32 row gate.  Returns (B, KVH, G, hd).
 
     Grid: one step per block of `ppb` live pages (ops.py derives ppb from
     the shapes, `pages_per_block`), lane after lane.  Lane b's live pages
     are the min(MAXP, qpos // ps + 1) its query can reach, none when it
     is inactive: the walk stops there, and a lane with none gets one step
     that writes its zeros.  The page table, qpos, active and the work
-    list are scalar-prefetched.  Each of a block's ppb pages is one k,
-    one v and one kpos operand whose index map reads the page table, so
-    the per-page DMA *is* the gather, and the pipeline starts the next
-    block's copies before this block's compute, across lanes too.  A page
-    block spans every kv head — its last two dims are the arena's own
-    (KVH, hd), the tiling Mosaic accepts for any head count.  A lane's
-    row depends on its own qpos, active and table row alone.
+    list are scalar-prefetched.  Each of a block's ppb pages is one arena
+    and one kpos operand whose index map reads the page table, so the
+    per-page DMA *is* the gather — one DMA of whole rows a page, every kv
+    head's K and V in it — and the pipeline starts the next block's
+    copies before this block's compute, across lanes too.  A lane's row
+    depends on its own qpos, active and table row alone.
 
-    Latent mode (`latent` > 0, v None, KVH 1): k is a (P, ps, hd) arena
-    of MLA rows, and a key's values are its row's first `latent` columns
-    — one DMA a page, not two.  Returns (B, 1, G, latent).  With `layer`
-    (an int32 scalar, latent mode) k is the (L, P, ps, hd) arena of
-    every layer a scan steps through, kpos (L, P, ps), and the kernel
-    reads layer `layer`'s pages where they lie: the caller writes the
-    step's rows into the stacked arena and never slices a layer out.
+    Latent mode (`latent` > 0, KVH 1): kv is a (P, ps, hd) arena of MLA
+    rows, and a key's values are its row's first `latent` columns.
+    Returns (B, 1, G, latent).
+
+    With `layer` (an int32 scalar) kv is the (L, P, ps, R) arena of every
+    layer a scan steps through, and the kernel reads layer `layer`'s
+    pages where they lie: the caller writes the step's rows into the
+    stacked arena and never slices a layer out.  kpos is then either
+    stacked too, (L, P, ps) (latent mode), or that layer's own (P, ps)
+    plane.
     """
     b, kvh, g, hd = q.shape
     stacked = layer is not None
+    kpos_stacked = kpos.ndim == 3
+    assert stacked or not kpos_stacked, kpos.shape
     n_pages, ps = kpos.shape[-2:]
     maxp = page_table.shape[1]
     dv = latent or hd
     steps, lanes, blks, cols = _work_list(qpos, active, ps, maxp, ppb)
     lane = lambda t, *s: (s[3][t], 0, 0, 0)  # noqa: E731
-    at = (None,) if stacked else ()  # the layer axis, squeezed
 
-    def page(c):  # block slot c's (layer and) arena page at grid step t
+    def page(c, layered):  # block slot c's (layer and) page at grid step t
         def index(t, pt, qp, act, ln, bk, col, *lyr):
             j = col[t * ppb + c]
             p = jnp.where(j >= 0, pt[ln[t], jnp.maximum(j, 0)], 0)
-            return (lyr[0][0], p) if stacked else (p,)
+            return (lyr[0][0], p) if layered else (p,)
         return index
+
+    def pages(shape, layered):  # one operand a block slot
+        at = (None,) if layered else ()  # the layer axis, squeezed
+        return [pl.BlockSpec((*at, 1, *shape),
+                             lambda *a, f=page(c, layered): (*f(*a), 0, 0))
+                for c in range(ppb)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7 if stacked else 6,
         grid=(steps,),
         in_specs=[pl.BlockSpec((1, kvh, g, hd), lane),
-                  *([pl.BlockSpec((*at, 1, ps, hd),
-                                  lambda *a, f=page(c): (*f(*a), 0, 0))
-                     for c in range(ppb)] if latent else
-                    [pl.BlockSpec((1, ps, kvh, hd),
-                                  lambda *a, f=page(c): (*f(*a), 0, 0, 0))
-                     for c in range(ppb)] * 2),
-                  *[pl.BlockSpec((*at, 1, 1, ps),
-                                 lambda *a, f=page(c): (*f(*a), 0, 0))
-                    for c in range(ppb)]],
+                  *pages((ps, kv.shape[-1]), stacked),
+                  *pages((1, ps), kpos_stacked)],
         out_specs=pl.BlockSpec((1, kvh, g, dv), lane),
         scratch_shapes=_scratch(kvh, g, dv),
     )
@@ -382,8 +388,8 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v, kpos: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
         interpret=interpret,
         name="paged_flash_decode",
-    )(page_table, qpos, active, lanes, blks, cols, *lyr, q, *[k] * ppb,
-      *([] if latent else [v] * ppb), *[kp3] * ppb)
+    )(page_table, qpos, active, lanes, blks, cols, *lyr, q, *[kv] * ppb,
+      *[kp3] * ppb)
 
 
 @functools.partial(

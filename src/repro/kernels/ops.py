@@ -225,11 +225,15 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     return out[:, :, :g].reshape(b, h, hd)
 
 
-def _shard_map_heads(call, mesh, axis, q, arenas, rest):
+def _shard_map_heads(call, mesh, axis, kvh, q, arenas, rest):
     """Dispatch a paged-decode call under shard_map with the kv-head axis
-    of q and of the `arenas` leaves partitioned over `axis` and `rest`
-    (positions, page table, lane gate) replicated.
+    of q and of the `arenas` partitioned over `axis` and `rest`
+    (positions, page table, lane gate, layer index) replicated.
 
+    `arenas` are (array, dim) pairs: `dim` holds whole kv heads — the
+    kv-head axis of an int8 arena and of its scale planes, or the row
+    axis of the bf16 arena, whose [k_h | v_h] column runs are laid out
+    head after head, so a contiguous split of it is a split of heads.
     `q` is (B, H, hd) with heads laid out kvh-major (ops reshape to
     (B, KVH, G, hd) below), so a contiguous H split is exactly a KV-head
     split — each shard holds whole GQA groups and computes its heads'
@@ -246,61 +250,68 @@ def _shard_map_heads(call, mesh, axis, q, arenas, rest):
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
-    if q.shape[1] % n or arenas[0].shape[2] % n:
+    if q.shape[1] % n or kvh % n:
         axis = None
     return jax.shard_map(
         call, mesh=mesh,
-        in_specs=((P(None, axis),) + (P(None, None, axis),) * len(arenas)
+        in_specs=((P(None, axis),)
+                  + tuple(P(*[None] * d, axis) for _, d in arenas)
                   + (P(),) * len(rest)),
         out_specs=P(None, axis), check_vma=False,
-    )(q, *arenas, *rest)
+    )(q, *[a for a, _ in arenas], *rest)
 
 
-def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
-                       kpos: jax.Array, page_table: jax.Array,
-                       qpos: jax.Array,
-                       active: Optional[jax.Array] = None,
+def paged_flash_decode(q: jax.Array, kv: jax.Array, kpos: jax.Array,
+                       page_table: jax.Array, qpos: jax.Array,
+                       active: Optional[jax.Array] = None, layer=None,
                        impl: Optional[str] = None,
                        mesh=None, axis: Optional[str] = None) -> jax.Array:
     """Single-query decode attention over a paged KV arena.
 
-    q: (B, H, hd) *pre-scaled* by 1/sqrt(hd); k/v: (P, ps, KVH, hd) global
-    page arenas; kpos: (P, ps) int32 absolute positions (2^30 =
-    never-written sentinel); page_table: (B, MAXP) int32 mapping lane b's
-    logical page j to an arena page (entries may repeat across lanes —
-    radix-shared prefixes; unused entries must name pages whose kpos are
-    all sentinel, e.g. the allocator's trash page 0); qpos: (B,) int32;
-    active: optional (B,) bool lane gate.  Returns (B, H, hd) in q.dtype.
+    q: (B, H, hd) *pre-scaled* by 1/sqrt(hd); kv: (P, ps, 2*KVH*hd) global
+    page arena, one row a token holding each kv head's K then its V, head
+    after head (`models/attention.fuse_kv`); kpos: (P, ps) int32 absolute
+    positions (2^30 = never-written sentinel); page_table: (B, MAXP) int32
+    mapping lane b's logical page j to an arena page (entries may repeat
+    across lanes — radix-shared prefixes; unused entries must name pages
+    whose kpos are all sentinel, e.g. the allocator's trash page 0); qpos:
+    (B,) int32; active: optional (B,) bool lane gate.  With `layer` (int32
+    scalar) kv is (L, P, ps, 2*KVH*hd), every scanned layer's arena
+    stacked, and layer `layer` is attended where it lies; kpos stays that
+    layer's own plane.  Returns (B, H, hd) in q.dtype.
 
     Unlike the dense wrapper there is no KV padding to do: pages are the
     tile granularity already.  Sliding windows aren't supported here — the
     serving engine keeps windowed (ring-buffer) caches on the dense slot
     path.
 
-    mesh/axis: dispatch under shard_map with the arena's kv-head dim (and
-    q's head dim) partitioned over `axis` when the head counts divide it
-    and kpos/page_table/qpos/active replicated — the plan-sharded serving
-    path (`_shard_map_heads`).
+    mesh/axis: dispatch under shard_map with the arena's rows (whole kv
+    heads, and q's head dim) partitioned over `axis` when the head counts
+    divide it and kpos/page_table/qpos/active replicated — the
+    plan-sharded serving path (`_shard_map_heads`).
     """
     impl = impl or default_impl()
+    b, h, hd = q.shape
+    kvh = kv.shape[-1] // (2 * hd)
+    lyr = () if layer is None else (jnp.asarray(layer, jnp.int32),)
     if mesh is not None and axis is not None and mesh.shape[axis] > 1:
-        b = q.shape[0]
         act = (jnp.ones((b,), bool) if active is None
                else active.astype(bool))
 
-        def call(qb, kb, vb, kpb, ptb, qpb, ab):
-            return paged_flash_decode(qb, kb, vb, kpb, ptb, qpb,
-                                      active=ab, impl=impl)
+        def call(qb, kvb, kpb, ptb, qpb, ab, *lb):
+            return paged_flash_decode(qb, kvb, kpb, ptb, qpb, active=ab,
+                                      layer=lb[0] if lb else None,
+                                      impl=impl)
 
-        return _shard_map_heads(call, mesh, axis, q, (k, v),
-                                (kpos, page_table, qpos, act))
+        return _shard_map_heads(call, mesh, axis, kvh, q,
+                                ((kv, kv.ndim - 1),),
+                                (kpos, page_table, qpos, act, *lyr))
     if impl == "ref":
-        return _ref.paged_flash_decode(q, k, v, kpos, page_table, qpos,
+        return _ref.paged_flash_decode(q, kv if layer is None else kv[layer],
+                                       kpos, page_table, qpos,
                                        active=active)
     from repro.kernels import flash_decode as _fd
 
-    b, h, hd = q.shape
-    kvh = k.shape[2]
     g = h // kvh
     qg = q.reshape(b, kvh, g, hd)
     gp = _rup(g, 8)  # group dim is the sublane axis: pad to tile granularity
@@ -308,11 +319,12 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
     act = (jnp.ones((b,), jnp.int32) if active is None
            else active.astype(jnp.int32).reshape(b))
-    ppb = _fd.pages_per_block(k.shape[1], kvh, hd, k.dtype.itemsize,
+    ppb = _fd.pages_per_block(kv.shape[-2], kv.shape[-1] * kv.dtype.itemsize,
                               page_table.shape[1])
     out = _fd.paged_flash_decode(
-        qg, k, v, kpos.astype(jnp.int32), page_table.astype(jnp.int32),
-        qpos.astype(jnp.int32), act, ppb=ppb, interpret=impl == "interpret")
+        qg, kv, kpos.astype(jnp.int32), page_table.astype(jnp.int32),
+        qpos.astype(jnp.int32), act, layer, ppb=ppb,
+        interpret=impl == "interpret")
     return out[:, :, :g].reshape(b, h, hd)
 
 
@@ -348,10 +360,10 @@ def paged_flash_decode_latent(q: jax.Array, c: jax.Array, kpos: jax.Array,
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - h), (0, 0)))
     act = (jnp.ones((b,), jnp.int32) if active is None
            else active.astype(jnp.int32).reshape(b))
-    ppb = _fd.pages_per_block(c.shape[-2], 1, r, c.dtype.itemsize,
-                              page_table.shape[1], arenas=1)
+    ppb = _fd.pages_per_block(c.shape[-2], r * c.dtype.itemsize,
+                              page_table.shape[1])
     out = _fd.paged_flash_decode(
-        qg, c, None, kpos.astype(jnp.int32), page_table.astype(jnp.int32),
+        qg, c, kpos.astype(jnp.int32), page_table.astype(jnp.int32),
         qpos.astype(jnp.int32), act, layer, ppb=ppb, latent=dv,
         interpret=impl == "interpret")
     return out[:, 0, :h]
@@ -420,8 +432,9 @@ def paged_flash_decode_q(q: jax.Array, k: jax.Array, v: jax.Array,
 
         # scale planes (P, ps, KVH) ride the same kv-head partition as the
         # int8 values they dequantize
-        return _shard_map_heads(call, mesh, axis, q,
-                                (k, v, k_scale, v_scale),
+        return _shard_map_heads(call, mesh, axis, k.shape[2], q,
+                                ((k, 2), (v, 2), (k_scale, 2),
+                                 (v_scale, 2)),
                                 (kpos, page_table, qpos, act))
     if impl == "ref":
         out = _ref.paged_flash_decode_q(q, k, v, k_scale, v_scale, kpos,

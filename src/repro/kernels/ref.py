@@ -83,13 +83,13 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(b, h, v.shape[-1])
 
 
-def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
-                       kpos: jax.Array, page_table: jax.Array,
-                       qpos: jax.Array,
+def paged_flash_decode(q: jax.Array, kv: jax.Array, kpos: jax.Array,
+                       page_table: jax.Array, qpos: jax.Array,
                        active: Optional[jax.Array] = None) -> jax.Array:
     """Oracle for the paged split-KV decode kernel: gather, then decode.
 
-    q: (B, H, hd) pre-scaled; k/v: (P, ps, KVH, hd) global page arenas;
+    q: (B, H, hd) pre-scaled; kv: (P, ps, 2*KVH*hd) global page arena, one
+    row a token holding each kv head's K then its V, head after head;
     kpos: (P, ps) absolute positions (2^30 = never written); page_table:
     (B, MAXP) int32 (entries may repeat across lanes — shared prefix
     pages).  The gathered per-lane cache is laid out exactly like the
@@ -98,12 +98,11 @@ def paged_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     equivalent dense cache — the property the engine equality tests lean
     on.
     """
-    b = q.shape[0]
-    kvh, hd = k.shape[2], k.shape[3]
-    kg = k[page_table].reshape(b, -1, kvh, hd)
-    vg = v[page_table].reshape(b, -1, kvh, v.shape[3])
+    b, hd = q.shape[0], q.shape[-1]
+    g = kv[page_table].reshape(b, -1, kv.shape[-1] // (2 * hd), 2, hd)
     kpg = kpos[page_table].reshape(b, -1)
-    return flash_decode(q, kg, vg, kpg, qpos, active=active)
+    return flash_decode(q, g[..., 0, :], g[..., 1, :], kpg, qpos,
+                        active=active)
 
 
 def paged_flash_decode_latent(q: jax.Array, c: jax.Array, kpos: jax.Array,
@@ -113,9 +112,10 @@ def paged_flash_decode_latent(q: jax.Array, c: jax.Array, kpos: jax.Array,
                               ) -> jax.Array:
     """Oracle for the paged decode kernel's latent mode: keys are the
     (P, ps, R) arena's rows, values their first `dv` columns."""
-    c = c[:, :, None, :]  # one kv head
-    return paged_flash_decode(q, c, c[..., :dv], kpos, page_table, qpos,
-                              active=active)
+    b = q.shape[0]
+    cg = c[page_table].reshape(b, -1, 1, c.shape[-1])  # one kv head
+    kpg = kpos[page_table].reshape(b, -1)
+    return flash_decode(q, cg, cg[..., :dv], kpg, qpos, active=active)
 
 
 def expert_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,

@@ -217,12 +217,14 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
     pad sentinels), so the purely positional mask of the Pallas
     flash-attention kernel is exact and long prefill may route through it.
     page_table: (B, MAXP) int32 — marks the cache as a *paged* KV arena
-    (`k`/`v`: (P, ps, KVH, hd), `kpos`: (P, ps)): lane b's logical position
-    q lives at arena page page_table[b, q // ps], offset q % ps.  Decode
-    writes are scattered through the table; `active` gates them (inactive
-    lanes write to the allocator's trash page 0 with sentinel kpos, so a
-    parked lane can never corrupt live pages — the paged analogue of the
-    dense path's cache_map where-masking).
+    (`kv`: (P, ps, 2*KVH*hd) rows [k_h | v_h] — or (L, P, ps, 2*KVH*hd),
+    every scanned layer's stacked, with the index `layer`; int8: `k`/`v`
+    (P, ps, KVH, hd) and scale planes; `kpos`: (P, ps)): lane b's logical
+    position q lives at arena page page_table[b, q // ps], offset q % ps.
+    Decode writes are scattered through the table; `active` gates them
+    (inactive lanes write to the allocator's trash page 0 with sentinel
+    kpos, so a parked lane can never corrupt live pages — the paged
+    analogue of the dense path's cache_map where-masking).
     """
     if cfg.kv_lora_rank:
         return mla_attention(x, p, cfg, positions, segment_ids, cache,
@@ -268,9 +270,12 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
         # unreachable).
         assert not window, "paged KV does not support sliding windows"
         b, t = x.shape[0], x.shape[1]
-        ck, cv = cache["k"], cache["v"]  # (P, ps, KVH, hd)
-        ps = ck.shape[1]
         quantized = "k_scale" in cache
+        # bf16: "kv" rows [k_h | v_h], with "layer" every scanned layer's
+        # arena stacked (see the single-query branch below)
+        layer = cache.get("layer")
+        at = () if layer is None else (layer,)
+        ps = cache["kpos"].shape[-1]
         act = (jnp.ones((b,), bool) if active is None
                else active.astype(bool))
         # one batched (B*T)-row scatter: within a lane the T positions are
@@ -290,13 +295,13 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
             from repro.core.quant import kv_quantize
             kq, ksc = kv_quantize(kf)
             vq, vsc = kv_quantize(vf)
-            ck = ck.at[wr_page, wr_off].set(kq)
-            cv = cv.at[wr_page, wr_off].set(vq)
+            ck = cache["k"].at[wr_page, wr_off].set(kq)
+            cv = cache["v"].at[wr_page, wr_off].set(vq)
             cks = cache["k_scale"].at[wr_page, wr_off].set(ksc)
             cvs = cache["v_scale"].at[wr_page, wr_off].set(vsc)
         else:
-            ck = ck.at[wr_page, wr_off].set(kf.astype(ck.dtype))
-            cv = cv.at[wr_page, wr_off].set(vf.astype(cv.dtype))
+            ckv = cache["kv"].at[(*at, wr_page, wr_off)].set(
+                fuse_kv(kf, vf).astype(cache["kv"].dtype))
         route = "pallas" if (impl == "pallas" and cfg.causal) else "ref"
         ctx = paged_shard_ctx()
         mesh_kw = {} if ctx is None else {"mesh": ctx[0], "axis": ctx[1]}
@@ -314,14 +319,14 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
             of = kops.paged_flash_decode_q(qf, ck, cv, cks, cvs, kpos, ptf,
                                            qpf, active=actf, impl=route,
                                            **mesh_kw)
+            new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs,
+                         "kpos": kpos}
         else:
-            of = kops.paged_flash_decode(qf, ck.astype(q.dtype),
-                                         cv.astype(q.dtype), kpos, ptf, qpf,
-                                         active=actf, impl=route, **mesh_kw)
+            of = kops.paged_flash_decode(qf, ckv, kpos, ptf, qpf,
+                                         active=actf, layer=layer,
+                                         impl=route, **mesh_kw)
+            new_cache = {"kv": ckv, "kpos": kpos}
         out = of.reshape(b, t, nh, hd)
-        new_cache = {"k": ck, "v": cv, "kpos": kpos}
-        if quantized:
-            new_cache.update({"k_scale": cks, "v_scale": cvs})
     elif cache is None or x.shape[1] > 1:
         if x.shape[1] <= DENSE_ATTN_MAX_KV:
             msk = _mask(x.shape[1], x.shape[1], positions, positions,
@@ -366,9 +371,16 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
         # afterwards — an arena has no batch axis to mask over.
         assert not window, "paged KV does not support sliding windows"
         b = x.shape[0]
-        ck, cv = cache["k"], cache["v"]  # (P, ps, KVH, hd)
-        ps = ck.shape[1]
         quantized = "k_scale" in cache  # int8 arena + per-row scales
+        # the bf16 arena "kv" holds one row a token, each kv head's K then
+        # its V; a cache with "layer" holds every scanned layer's arena
+        # stacked (models/transformer.py carries it through the layer
+        # scan): the rows go into that layer's pages and the kernel reads
+        # them there, so no layer's arena is sliced out or written back.
+        # kpos stays the layer's own (P, ps) plane
+        layer = cache.get("layer")
+        at = () if layer is None else (layer,)
+        ps = cache["kpos"].shape[-1]
         cpos = positions[:, 0].astype(jnp.int32)
         act = (jnp.ones((b,), bool) if active is None
                else active.astype(bool))
@@ -384,13 +396,13 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
             from repro.core.quant import kv_quantize
             kq, ksc = kv_quantize(k[:, 0])  # (B, KVH, hd) int8, (B, KVH)
             vq, vsc = kv_quantize(v[:, 0])
-            ck = ck.at[wr_page, wr_off].set(kq)
-            cv = cv.at[wr_page, wr_off].set(vq)
+            ck = cache["k"].at[wr_page, wr_off].set(kq)
+            cv = cache["v"].at[wr_page, wr_off].set(vq)
             cks = cache["k_scale"].at[wr_page, wr_off].set(ksc)
             cvs = cache["v_scale"].at[wr_page, wr_off].set(vsc)
         else:
-            ck = ck.at[wr_page, wr_off].set(k[:, 0].astype(ck.dtype))
-            cv = cv.at[wr_page, wr_off].set(v[:, 0].astype(cv.dtype))
+            ckv = cache["kv"].at[(*at, wr_page, wr_off)].set(
+                fuse_kv(k[:, 0], v[:, 0]).astype(cache["kv"].dtype))
         kpos = cache["kpos"].at[wr_page, wr_off].set(kpos_val)
         # the page-gathering kernels only route compiled: their grid walks
         # every lane's live pages (the int8 kernel's, every table entry)
@@ -402,7 +414,7 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
         # (bitwise equal to the dense ref path on equal logical lengths)
         route = "pallas" if (impl == "pallas" and cfg.causal) else "ref"
         # plan-sharded serving (serve_exact hints context): the arena's
-        # kv-head dim is partitioned over `model`, so the decode kernel
+        # kv heads are partitioned over `model`, so the decode kernel
         # dispatch runs under shard_map — each model shard walks the
         # (replicated) page table over its own kv heads, the SPMD form of
         # the paper's per-head dotprod_softmax kernels behind the scatter
@@ -418,10 +430,9 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
                          "kpos": kpos}
         else:
             out = kops.paged_flash_decode(
-                qs[:, 0], ck.astype(q.dtype), cv.astype(q.dtype), kpos,
-                page_table, cpos, active=act, impl=route,
-                **mesh_kw)[:, None]
-            new_cache = {"k": ck, "v": cv, "kpos": kpos}
+                qs[:, 0], ckv, kpos, page_table, cpos, active=act,
+                layer=layer, impl=route, **mesh_kw)[:, None]
+            new_cache = {"kv": ckv, "kpos": kpos}
     else:
         # decode: Sq == 1; the token's absolute position comes from the
         # model-level counter (positions[:, 0]) — the cache itself is
@@ -466,6 +477,12 @@ def attention(x: jax.Array, p: Params, cfg, positions: jax.Array,
     return dense(out, wo), new_cache
 
 
+def fuse_kv(k: jax.Array, v: jax.Array) -> jax.Array:
+    """(..., KVH, hd) keys and values -> (..., 2*KVH*hd) rows of the bf16
+    paged arena: each kv head's K then its V, head after head."""
+    return jnp.stack([k, v], axis=-2).reshape(*k.shape[:-2], -1)
+
+
 def init_paged_attn_cache(cfg, num_pages: int, page_size: int,
                           dtype=COMPUTE_DTYPE, quantized: bool = False):
     """Paged KV arena: a global page pool instead of per-lane slot rows.
@@ -477,28 +494,39 @@ def init_paged_attn_cache(cfg, num_pages: int, page_size: int,
     serving engine re-sentinels a page's kpos on reallocation, so a page's
     previous occupant is unreachable by construction.
 
-    quantized=True stores int8 k/v plus per-row per-kv-head f32 scale
-    planes (`k_scale`/`v_scale`, core/quant.kv_quantize): ~half the bytes
-    per cache row, so a fixed HBM budget holds ~2x the pages.  The scales
-    live in the arena — a radix-shared prefix page carries its scales with
-    it, so every lane reading the page dequantizes identically.
+    bf16: one arena "kv" (P, ps, 2*KVH*hd), one row a token holding each
+    kv head's K then its V ([k_0 | v_0 | k_1 | v_1 | ...], `fuse_kv`).
+    The decode kernel loads a page's rows in one DMA and slices each head
+    in VMEM; the rows are whole 128-lane tiles at the benchmark's widths
+    (384 for 3 x 64, 2560 for 10 x 128) with nothing padded, so the chip
+    keeps the arena in the row-major layout the kernel reads; and a
+    contiguous split of a row is a split of whole heads (plan sharding).
+
+    quantized=True stores int8 k/v (P, ps, KVH, hd) plus per-row
+    per-kv-head f32 scale planes (`k_scale`/`v_scale`,
+    core/quant.kv_quantize): ~half the bytes per cache row, so a fixed HBM
+    budget holds ~2x the pages.  The scales live in the arena — a
+    radix-shared prefix page carries its scales with it, so every lane
+    reading the page dequantizes identically.  It keeps its own kernel
+    (`paged_flash_decode_q`) and is scanned layer by layer.
     """
     assert not cfg.local_window, "paged KV does not support sliding windows"
+    kpos = jnp.full((num_pages, page_size), 2**30, jnp.int32)
     if cfg.kv_lora_rank:
         assert not quantized, "the latent arena has no int8 form"
         return {"ckv": jnp.zeros((num_pages, page_size, cfg.latent_stride),
                                  dtype),
-                "kpos": jnp.full((num_pages, page_size), 2**30, jnp.int32)}
+                "kpos": kpos}
+    if not quantized:
+        return {"kv": jnp.zeros((num_pages, page_size,
+                                 2 * cfg.n_kv_heads * cfg.head_dim), dtype),
+                "kpos": kpos}
     kv_shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    cache = {
-        "k": jnp.zeros(kv_shape, jnp.int8 if quantized else dtype),
-        "v": jnp.zeros(kv_shape, jnp.int8 if quantized else dtype),
-        "kpos": jnp.full((num_pages, page_size), 2**30, jnp.int32),
-    }
-    if quantized:
-        cache["k_scale"] = jnp.zeros(kv_shape[:3], jnp.float32)
-        cache["v_scale"] = jnp.zeros(kv_shape[:3], jnp.float32)
-    return cache
+    return {"k": jnp.zeros(kv_shape, jnp.int8),
+            "v": jnp.zeros(kv_shape, jnp.int8),
+            "k_scale": jnp.zeros(kv_shape[:3], jnp.float32),
+            "v_scale": jnp.zeros(kv_shape[:3], jnp.float32),
+            "kpos": kpos}
 
 
 def init_attn_cache(cfg, batch: int, seq_len: int, dtype=COMPUTE_DTYPE):

@@ -230,6 +230,22 @@ def quantize_kv_tree(tree):
     return go(tree)
 
 
+def fuse_kv_tree(tree):
+    """Convert a dense bf16 cache pytree's attention leaves
+    ({"k","v","kpos"}) to the bf16 paged arena's leaf layout
+    ({"kv","kpos"}: each kv head's K then its V in one row,
+    models/attention.fuse_kv)."""
+    def go(t):
+        if isinstance(t, dict):
+            if "k" in t and "kpos" in t:
+                return {"kv": attn_mod.fuse_kv(t["k"], t["v"]),
+                        "kpos": t["kpos"]}
+            return {k: go(v) for k, v in t.items()}
+        return t
+
+    return go(tree)
+
+
 def paged_cache_map(fn, *trees):
     """Map ``fn(page_axis, leaf_name, *leaves)`` over the layer arena
     leaves of paged cache pytrees ("lead", "scan", "tail").
@@ -248,6 +264,20 @@ def paged_cache_map(fn, *trees):
     return {k: (go(1 if k == "scan" else 0, k, *[t[k] for t in trees])
                 if k in LAYER_KEYS else trees[0][k])
             for k in trees[0]}
+
+
+def _carried_leaves(scan_caches) -> Tuple[str, ...]:
+    """The leaves of a paged cache's stacked layers that ride the layer
+    scan's carry in decode, read in place at a layer index: the latent
+    arena and its kpos (their kernel mode takes both stacked), the bf16
+    arena "kv" (its kpos planes stay scanned: the kernel reads a (P, ps)
+    plane row-major, and a stacked plane laid out pages-minor would be
+    re-laid whole for every layer).  None for the int8 arena, which is
+    scanned layer by layer."""
+    leaves = {k for t in scan_caches.values() for k in t}
+    if "ckv" in leaves:
+        return ("ckv", "kpos")
+    return ("kv",) if "kv" in leaves else ()
 
 
 def spec_acceptance(ins, tgt, active, remaining, eos_id, pad_token,
@@ -414,22 +444,37 @@ class Model:
                 policy=jax.checkpoint_policies.nothing_saveable,
             )
 
-        if n_rep and caches is not None and page_table is not None \
-                and cfg.kv_lora_rank:
-            # latent decode: the stacked arenas ride the carry and each
+        carried = (_carried_leaves(caches["scan"]) if n_rep and caches
+                   is not None and page_table is not None else ())
+        if carried:
+            # paged decode: the stacked arenas ride the carry and each
             # layer writes its rows into, and attends over, its own pages
-            # in place (models/attention.mla_attention); as scanned xs/ys
-            # they would be sliced out and written back a layer at a time,
-            # and the ys would be a second whole arena
-            def carry_body(carry, pp):
-                h, aux_acc, cnt_acc, arenas, li = carry
-                h, arenas, aux, cnt = pf(h, pp, arenas, li)
-                return (h, aux_acc + aux, _add(cnt_acc, cnt), arenas,
-                        li + 1), None
+            # in place (models/attention.py); as scanned xs/ys they would
+            # be sliced out and written back a layer at a time, and the
+            # ys would be a second whole arena.  The other leaves (the
+            # dense arena's kpos planes) stay scanned
+            def split(tree):  # per-block leaves -> (carried, scanned)
+                return tuple({blk: {k: x for k, x in t.items()
+                                    if (k in carried) == keep}
+                              for blk, t in tree.items()}
+                             for keep in (True, False))
 
-            (h, aux, counts, new_scan_caches, _), _ = jax.lax.scan(
-                carry_body, (h, jnp.float32(0.0), _counts0(cfg),
-                             caches["scan"], jnp.int32(0)), params["scan"])
+            def carry_body(carry, xs):
+                h, aux_acc, cnt_acc, arenas, li = carry
+                pp, planes = xs
+                h, new, aux, cnt = pf(h, pp, {blk: {**arenas[blk],
+                                                    **planes[blk]}
+                                              for blk in arenas}, li)
+                arenas, planes = split(new)
+                return (h, aux_acc + aux, _add(cnt_acc, cnt), arenas,
+                        li + 1), planes
+
+            arenas, planes = split(caches["scan"])
+            (h, aux, counts, arenas, _), planes = jax.lax.scan(
+                carry_body, (h, jnp.float32(0.0), _counts0(cfg), arenas,
+                             jnp.int32(0)), (params["scan"], planes))
+            new_scan_caches = {blk: {**arenas[blk], **planes[blk]}
+                               for blk in arenas}
         elif n_rep:
             def scan_body(carry, xs):
                 h, aux_acc, cnt_acc = carry
@@ -522,16 +567,22 @@ class Model:
                          max_pages: int, kv_dtype: str = "bf16"):
         """Paged serving cache: global KV page arenas + per-lane tables.
 
-        Tree: {"scan"/"tail": per-layer {"k","v","kpos"} arenas with no
-        batch axis (models/attention.init_paged_attn_cache), "pos": (B,)
-        position counters, "pt": (B, max_pages) int32 page tables (0 = the
-        allocator's reserved trash page)}.  Only all-attention configs
-        qualify — recurrent state has no paged analogue, and ring-buffer
-        (windowed) caches stay on the dense slot path.
+        Tree: {"scan"/"tail": per-layer arenas with no batch axis
+        (models/attention.init_paged_attn_cache) — bf16: {"kv", "kpos"},
+        "kv" (P, ps, 2*KVH*hd) one row a token holding each kv head's K
+        then its V; stacked under "scan", (n_rep, P, ps, 2*KVH*hd), and
+        carried whole through the decode program's layer scan, each layer
+        writing and reading its own pages in place (`backbone`) — "pos":
+        (B,) position counters, "pt": (B, max_pages) int32 page tables
+        (0 = the allocator's reserved trash page)}.  Only all-attention
+        configs qualify — recurrent state has no paged analogue, and
+        ring-buffer (windowed) caches stay on the dense slot path.
 
-        kv_dtype="int8" stores quantized arenas (int8 k/v + f32
-        `k_scale`/`v_scale` planes): ~half the HBM per cache row, so an
-        equal byte budget holds ~2x the pages (docs/perf.md §int8 pages).
+        kv_dtype="int8" stores quantized arenas (int8 k/v (P, ps, KVH, hd)
+        + f32 `k_scale`/`v_scale` planes): ~half the HBM per cache row, so
+        an equal byte budget holds ~2x the pages (docs/perf.md §int8
+        pages).  They keep their own kernel and are scanned layer by
+        layer, sliced out and written back.
         """
         cfg = self.cfg
         n_rep, tail, kinds = layer_plan(cfg)
@@ -852,13 +903,15 @@ class Model:
         page-table row becomes `pt_row` and its position counter `pos0`
         (the prompt length; a prefix-cache hit passes hit_len and no
         `small` — the suffix arrives through the decode loop's forced
-        queue instead).  A quantized arena (`kv_dtype="int8"`) quantizes
-        the bucket cache on the way in, per cache row, so its leaves match
-        the arena's int8 + scale layout.
+        queue instead).  The bucket cache's k/v leaves become the bf16
+        arena's rows (each kv head's K then its V), or, for a quantized
+        arena (`kv_dtype="int8"`), are quantized on the way in, per cache
+        row, so its leaves match the arena's int8 + scale layout.
         """
         slot = jnp.asarray(slot, jnp.int32)
-        if small is not None and cache_is_quantized(big):
-            small = quantize_kv_tree(small)
+        if small is not None:
+            small = (quantize_kv_tree(small) if cache_is_quantized(big)
+                     else fuse_kv_tree(small))
 
         def leaf(page_axis, name, b, s):
             ps = b.shape[page_axis + 1]

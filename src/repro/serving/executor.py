@@ -875,7 +875,7 @@ class Executor:
 
         The paged arenas ride the stage axis for free: `_stage_spec`
         shards every scan-stacked leaf's leading period dim, which for an
-        arena leaf (n_rep, P, ps, KVH, hd) leaves stage s holding only its
+        arena leaf (n_rep, P, ps, ...) leaves stage s holding only its
         own layers' pages — pipeline depth multiplies usable KV HBM
         (kv_manager.kv_page_bytes(shards=)); the page table and counters
         stay replicated routing metadata.

@@ -25,6 +25,7 @@ except ModuleNotFoundError:  # deterministic fallback draws (see detshim.py)
 from repro.core.packing import PagePool, RadixPrefixCache
 from repro.kernels import flash_decode as fd
 from repro.kernels import ops
+from repro.models.attention import fuse_kv
 
 SENTINEL = 2 ** 30
 
@@ -68,7 +69,7 @@ def _with_ppb(monkeypatch, ppb, ps, kvh, hd, maxp, itemsize=4):
     byte budget it derives ppb from."""
     monkeypatch.setattr(fd, "PAGED_BLOCK_BYTES",
                         ppb * 2 * ps * kvh * hd * itemsize)
-    assert fd.pages_per_block(ps, kvh, hd, itemsize, maxp) == ppb
+    assert fd.pages_per_block(ps, 2 * kvh * hd * itemsize, maxp) == ppb
 
 
 # name: (heads, kvh, ps, maxp, ppb, qpos per lane, lane active)
@@ -91,23 +92,41 @@ WALKS = {
 }
 
 
+def _stack_layers(rng, kv):
+    """`kv` as layer 1 of three stacked arenas; layers 0 and 2 hold
+    other rows."""
+    other = jnp.asarray(rng.normal(0, 1, kv.shape), kv.dtype)
+    return jnp.stack([other, kv, -other])
+
+
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["one_layer", "stacked_layers"])
 @pytest.mark.parametrize("seed", [0, 1])
 @pytest.mark.parametrize("walk", sorted(WALKS))
-def test_paged_decode_interpret_matches_ref(walk, seed, monkeypatch):
+def test_paged_decode_interpret_matches_ref(walk, seed, stacked,
+                                            monkeypatch):
     """Pallas paged kernel (interpret) == gather oracle, radix-shared
-    pages included, over walks of several blocks of `ppb` pages."""
+    pages included, over walks of several blocks of `ppb` pages.  Each
+    page is one (ps, 2*KVH*hd) block of [k_h | v_h] rows.  Stacked: three
+    layers' arenas (L, P, ps, 2*KVH*hd), the kernel told layer 1 and
+    given that layer's (P, ps) kpos plane, against the oracle over layer
+    1 alone."""
     h, kvh, ps, maxp, ppb, qpos, active = WALKS[walk]
     rng = np.random.default_rng(seed)
     b, hd = len(qpos), 16
     n_pages = 1 + b * maxp + 1
     q, k, v, kpos, pt, _ = _mk_paged(rng, b, h, kvh, hd, n_pages, ps, maxp)
+    kv = fuse_kv(k, v)
     qpos = jnp.asarray(qpos, jnp.int32)
     active = jnp.asarray(active, bool)
     _with_ppb(monkeypatch, ppb, ps, kvh, hd, maxp)
-    got = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, active=active,
-                                 impl="interpret")
-    want = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, active=active,
+    want = ops.paged_flash_decode(q, kv, kpos, pt, qpos, active=active,
                                   impl="ref")
+    layer = None
+    if stacked:
+        kv, layer = _stack_layers(rng, kv), jnp.int32(1)
+    got = ops.paged_flash_decode(q, kv, kpos, pt, qpos, active=active,
+                                 layer=layer, impl="interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
@@ -130,11 +149,52 @@ def test_paged_decode_skips_pages_past_the_bound(monkeypatch):
     v_nan = v.at[np.asarray(dead)].set(jnp.nan)
     qpos = jnp.asarray(qpos)
     _with_ppb(monkeypatch, ppb, ps, kvh, hd, maxp)
-    got = np.asarray(ops.paged_flash_decode(q, k_nan, v_nan, kpos, pt, qpos,
-                                            impl="interpret"))
-    want = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, impl="ref")
+    got = np.asarray(ops.paged_flash_decode(
+        q, fuse_kv(k_nan, v_nan), kpos, pt, qpos, impl="interpret"))
+    want = ops.paged_flash_decode(q, fuse_kv(k, v), kpos, pt, qpos,
+                                  impl="ref")
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+def test_paged_decode_stacked_edges_one_dma_a_page(monkeypatch):
+    """The stacked dense mode at its edges, against the oracle over the
+    same layer: radix-shared pages; an inactive lane; a lane whose whole
+    table names the trash page (no live key: exact zeros); garbage in the
+    trash page; and NaN rows in every page past a lane's live pages, in
+    blocks the walk must not fold in.  The arena is one operand a block
+    slot (ppb in all): each page is one DMA of [k_h | v_h] rows."""
+    rng = np.random.default_rng(5)
+    b, h, kvh, hd, ps, maxp, ppb = 5, 6, 3, 16, 8, 6, 2
+    q, k, v, kpos, pt, _ = _mk_paged(rng, b, h, kvh, hd, 1 + b * maxp, ps,
+                                     maxp)
+    pt = pt.at[3].set(0)  # lane 3: every entry the trash page
+    qpos = np.asarray([5, 20, 47, 30, 33], np.int32)
+    active = jnp.asarray([True, True, False, True, True])
+    live = {int(pt[i, j]) for i in range(b) for j in range(maxp)
+            if j <= qpos[i] // ps and i != 3}
+    dead = sorted({int(p) for p in np.asarray(pt).ravel()} - live - {0})
+    kpos = kpos.at[np.asarray(dead)].set(SENTINEL)
+    kv = fuse_kv(k, v)
+    want = np.asarray(ops.paged_flash_decode(q, kv, kpos, pt,
+                                             jnp.asarray(qpos),
+                                             active=active, impl="ref"))
+    dirty = kv.at[np.asarray(dead)].set(jnp.nan).at[0].set(1e9)
+    stacked = _stack_layers(rng, dirty)
+    qpos = jnp.asarray(qpos)
+    _with_ppb(monkeypatch, ppb, ps, kvh, hd, maxp)
+
+    def call(arena):
+        return ops.paged_flash_decode(q, arena, kpos, pt, qpos,
+                                      active=active, layer=jnp.int32(1),
+                                      impl="interpret")
+    got = np.asarray(call(stacked))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got[2], 0.0)  # inactive
+    np.testing.assert_array_equal(got[3], 0.0)  # trash-page table
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    (avals,) = _pallas_operands(call, stacked)
+    assert sum(a.shape == stacked.shape for a in avals) == ppb
 
 
 LATENT_R, LATENT_DV = 24, 16  # a latent row (kv rank + rope) and its values
@@ -144,8 +204,7 @@ def _with_latent_ppb(monkeypatch, ppb, ps, maxp, itemsize=4):
     """As _with_ppb, for the latent arena's one row a key."""
     monkeypatch.setattr(fd, "PAGED_BLOCK_BYTES",
                         ppb * ps * LATENT_R * itemsize)
-    assert fd.pages_per_block(ps, 1, LATENT_R, itemsize, maxp,
-                              arenas=1) == ppb
+    assert fd.pages_per_block(ps, LATENT_R * itemsize, maxp) == ppb
 
 
 @pytest.mark.parametrize("stacked", [False, True],
@@ -246,7 +305,8 @@ def test_paged_ref_bitwise_equals_dense_ref(seed):
     n_pages = 1 + b * maxp
     q, k, v, kpos, pt, qpos = _mk_paged(rng, b, h, kvh, hd, n_pages, ps,
                                         maxp)
-    paged = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, impl="ref")
+    paged = ops.paged_flash_decode(q, fuse_kv(k, v), kpos, pt, qpos,
+                                   impl="ref")
     kg = jnp.asarray(np.asarray(k)[np.asarray(pt)].reshape(b, -1, kvh, hd))
     vg = jnp.asarray(np.asarray(v)[np.asarray(pt)].reshape(b, -1, kvh, hd))
     kpg = jnp.asarray(np.asarray(kpos)[np.asarray(pt)].reshape(b, -1))
@@ -264,8 +324,8 @@ def test_paged_decode_inactive_and_sentinel_rows():
     pt = pt.at[2].set(0)  # lane 2's whole table points at the trash page
     active = jnp.asarray([True, False, True])
     for impl in ("ref", "interpret"):
-        out = np.asarray(ops.paged_flash_decode(q, k, v, kpos, pt, qpos,
-                                                active=active, impl=impl))
+        out = np.asarray(ops.paged_flash_decode(
+            q, fuse_kv(k, v), kpos, pt, qpos, active=active, impl=impl))
         assert not np.isnan(out).any(), impl
         np.testing.assert_array_equal(out[1], 0.0)  # inactive
         np.testing.assert_array_equal(out[2], 0.0)  # all-sentinel pages
@@ -278,11 +338,53 @@ def test_paged_decode_trash_page_garbage_is_unreachable():
     b, h, kvh, hd, ps, maxp = 2, 4, 2, 16, 8, 3
     q, k, v, kpos, pt, qpos = _mk_paged(rng, b, h, kvh, hd, 1 + b * maxp,
                                         ps, maxp)
-    clean = ops.paged_flash_decode(q, k, v, kpos, pt, qpos, impl="ref")
+    clean = ops.paged_flash_decode(q, fuse_kv(k, v), kpos, pt, qpos,
+                                   impl="ref")
     k2 = k.at[0].set(1e9)
     v2 = v.at[0].set(-1e9)
-    dirty = ops.paged_flash_decode(q, k2, v2, kpos, pt, qpos, impl="ref")
+    dirty = ops.paged_flash_decode(q, fuse_kv(k2, v2), kpos, pt, qpos,
+                                   impl="ref")
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+
+
+def test_paged_greedy_decode_matches_dense_slots_at_smollm_heads():
+    """Three layers at smollm-135m's head widths (9 query / 3 kv heads of
+    64): the paged engine — its arena carried through the layer scan,
+    rows scattered at (layer, page, offset), read in place — emits the
+    dense-slot engine's tokens, under one pinned impl, across page
+    boundaries and with more requests than lanes."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_config
+    from repro.models.transformer import init_params, make_model
+    from repro.serving.engine import ContinuousBatchingEngine, Request
+
+    cfg = dataclasses.replace(get_config("smollm-135m").reduced(),
+                              n_layers=3, n_heads=9, n_kv_heads=3,
+                              head_dim=64)
+    model = make_model(cfg, remat=False)
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 14, 9, 3, 11)]
+    budgets = [12, 20, 3, 17, 9]
+
+    def run(paged):
+        eng = ContinuousBatchingEngine(model, params, max_batch=3,
+                                       buckets=(16,), paged=paged)
+        for i, p in enumerate(prompts):
+            eng.submit(Request(rid=i, prompt=p, max_new_tokens=budgets[i]))
+        return {r.rid: r.tokens_out for r in eng.run()}, eng
+
+    with ops.pinned_impl("ref"):
+        dense, _ = run(False)
+        paged, eng = run(True)
+    arena = eng._slot_caches["scan"]["b0"]["kv"]
+    assert arena.shape[0] == 3 and arena.shape[-1] == 2 * 3 * 64
+    assert paged == dense
+    assert all(len(paged[i]) == budgets[i] for i in range(len(prompts)))
 
 
 # ---------------------------------------------------------------------------

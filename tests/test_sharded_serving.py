@@ -85,9 +85,10 @@ def test_kv_manager_grant_and_release_standalone():
 
 
 def test_paged_arena_specs_kv_head_sharded():
-    """Cluster-Builder paged leaf rules: arena k/v + scale planes shard
-    the kv-head dim over `model`; kpos/pt/pos replicate (spec-only,
-    abstract mesh — no devices needed)."""
+    """Cluster-Builder paged leaf rules: the bf16 arena's [k_h | v_h]
+    rows, the int8 arena's k/v and its scale planes shard the kv heads
+    over `model`; kpos/pt/pos replicate (spec-only, abstract mesh — no
+    devices needed)."""
     import dataclasses
 
     import jax
@@ -111,6 +112,10 @@ def test_paged_arena_specs_kv_head_sharded():
     assert all(p is None for p in b0["kpos"])
     assert all(p is None for p in specs["pt"])
     assert all(p is None for p in specs["pos"])
+    bf16 = plan.specs_for_caches(jax.eval_shape(
+        lambda: model.init_paged_cache(4, 32, 8, 8)), batch=4, paged=True)
+    assert bf16["scan"]["b0"]["kv"][3] == "model"
+    assert all(p is None for p in bf16["scan"]["b0"]["kpos"])
     # indivisible kv heads fall back to replication, never uneven shards
     cfg3 = dataclasses.replace(cfg, n_heads=9, n_kv_heads=3)
     model3 = make_model(cfg3, remat=False)
@@ -118,7 +123,7 @@ def test_paged_arena_specs_kv_head_sharded():
         lambda: model3.init_paged_cache(4, 32, 8, 8))
     specs3 = build_plan(cfg3, mesh, mode="serve").specs_for_caches(
         shape3, batch=4, paged=True)
-    assert all(p is None for p in specs3["scan"]["b0"]["k"])
+    assert all(p is None for p in specs3["scan"]["b0"]["kv"])
 
 
 # -- tentpole: sharded-vs-unsharded bit identity (8 host devices) ----------
@@ -181,8 +186,9 @@ def test_sharded_serve_bit_identical_bf16_int8_and_prefix_hits():
             s1, s2 = streams(single), streams(shard)
             assert s1 == s2, (kv_dtype, s1, s2)
             assert shard.stats["prefix_hits"] >= 4, shard.stats
-            # the arena is REALLY distributed: kv-head dim on `model`
-            k = shard._slot_caches["scan"]["b0"]["k"]
+            # the arena is REALLY distributed: kv heads on `model`
+            k = shard._slot_caches["scan"]["b0"][
+                "k" if kv_dtype == "int8" else "kv"]
             assert k.sharding.spec[3] == "model", k.sharding.spec
             if kv_dtype == "int8":
                 ks = shard._slot_caches["scan"]["b0"]["k_scale"]

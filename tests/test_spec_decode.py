@@ -214,10 +214,11 @@ def test_verify_fold_rows_bitwise_equal_single_rows(monkeypatch):
     boundary, lane 2 is inactive).  On the CPU the engine routes to the
     jnp oracle, so only a direct call reaches the kernel."""
     from repro.kernels import flash_decode as fd, ops
+    from repro.models.attention import fuse_kv
 
     b, t, h, kvh, hd, ps, maxp, ppb = 3, 4, 4, 2, 16, 4, 8, 3
     monkeypatch.setattr(fd, "PAGED_BLOCK_BYTES", ppb * 2 * ps * kvh * hd * 2)
-    assert fd.pages_per_block(ps, kvh, hd, 2, maxp) == ppb
+    assert fd.pages_per_block(ps, 2 * kvh * hd * 2, maxp) == ppb
     rng = np.random.default_rng(0)
     n_pages = 1 + b * maxp
     k = jnp.asarray(rng.normal(0, 1, (n_pages, ps, kvh, hd)), jnp.bfloat16)
@@ -234,11 +235,12 @@ def test_verify_fold_rows_bitwise_equal_single_rows(monkeypatch):
     qpf = jnp.asarray((p0[:, None] + np.arange(t)).reshape(-1), jnp.int32)
     actf = jnp.repeat(jnp.asarray([True, True, False]), t)
     kpos = jnp.asarray(kpos)
+    kv = fuse_kv(k, v)
     folded = np.asarray(ops.paged_flash_decode(
-        qf, k, v, kpos, ptf, qpf, active=actf, impl="interpret"))
+        qf, kv, kpos, ptf, qpf, active=actf, impl="interpret"))
     for row in range(b * t):
         one = ops.paged_flash_decode(
-            qf[row:row + 1], k, v, kpos, ptf[row:row + 1],
+            qf[row:row + 1], kv, kpos, ptf[row:row + 1],
             qpf[row:row + 1], active=actf[row:row + 1], impl="interpret")
         np.testing.assert_array_equal(folded[row], np.asarray(one)[0])
     assert not folded[2 * t:].any()  # the inactive lane's rows

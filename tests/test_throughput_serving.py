@@ -299,8 +299,8 @@ def test_serve_param_specs_psum_form_when_not_exact():
     shape = jax.eval_shape(
         lambda: model.init_paged_cache(4, 32, 8, 8))
     specs = skew.specs_for_caches(shape, batch=4, paged=True)
-    assert specs["scan"]["b0"]["k"][0] == "stage"
-    assert specs["scan"]["b0"]["v"][0] == "stage"
+    assert specs["scan"]["b0"]["kv"][0] == "stage"
+    assert specs["scan"]["b0"]["kpos"][0] == "stage"
     assert all(p is None for p in specs["pt"])  # shared routing metadata
     assert all(p is None for p in specs["pos"])
 

@@ -44,11 +44,12 @@ def one_chip():
 def _paged(ps, kv_dtype):
     maxp = -(-CACHE_LEN // ps)
     pages = B * maxp + 1
-    kv = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
-    shapes = [((B, H, HD), jnp.bfloat16), ((pages, ps, KVH, HD), kv),
-              ((pages, ps, KVH, HD), kv)]
+    shapes = [((B, H, HD), jnp.bfloat16)]
     if kv_dtype == "int8":
+        shapes += [((pages, ps, KVH, HD), jnp.int8)] * 2
         shapes += [((pages, ps, KVH), jnp.float32)] * 2
+    else:  # one row a token: each kv head's K then its V
+        shapes += [((pages, ps, 2 * KVH * HD), jnp.bfloat16)]
     shapes += [((pages, ps), jnp.int32), ((B, maxp), jnp.int32),
                ((B,), jnp.int32), ((B,), jnp.bool_)]
     call = (ops.paged_flash_decode_q if kv_dtype == "int8"
@@ -59,17 +60,20 @@ def _paged(ps, kv_dtype):
     return f, shapes
 
 
-def _paged_cell(b, h, kvh, hd, pages, maxp):
+def _paged_cell(b, h, kvh, hd, pages, maxp, layers=0):
     """The paged decode kernel at a benchmark cell's exact shapes (bf16
-    arena, 16-row pages)."""
+    arena of [k_h | v_h] rows, 16-row pages); `layers` > 0: that many
+    layers' arenas stacked, one read, and the layer's own kpos plane."""
+    at = (layers,) if layers else ()
     shapes = [((b, h, hd), jnp.bfloat16),
-              ((pages, 16, kvh, hd), jnp.bfloat16),
-              ((pages, 16, kvh, hd), jnp.bfloat16),
+              ((*at, pages, 16, 2 * kvh * hd), jnp.bfloat16),
               ((pages, 16), jnp.int32), ((b, maxp), jnp.int32),
               ((b,), jnp.int32), ((b,), jnp.bool_)]
 
-    def f(*a):
-        return ops.paged_flash_decode(*a[:-1], active=a[-1], impl="pallas")
+    def f(q, kv, kpos, pt, qpos, act):
+        return ops.paged_flash_decode(
+            q, kv, kpos, pt, qpos, active=act,
+            layer=jnp.int32(layers // 2) if layers else None, impl="pallas")
     return f, shapes
 
 
@@ -147,9 +151,13 @@ CASES = {
     # smollm-135m.sessions: batch 32, 9 q / 3 kv heads, 2369 pages of 74
     "paged_decode_smollm_sessions": lambda: _paged_cell(32, 9, 3, 64, 2369,
                                                         74),
+    "paged_decode_smollm_sessions_stacked": lambda: _paged_cell(
+        32, 9, 3, 64, 2369, 74, layers=30),
     # phi3-medium-14b.batch: batch 16, 40 q / 10 kv heads, 2305 of 144
     "paged_decode_phi3_batch": lambda: _paged_cell(16, 40, 10, 128, 2305,
                                                    144),
+    "paged_decode_phi3_batch_stacked": lambda: _paged_cell(
+        16, 40, 10, 128, 2305, 144, layers=10),
     # moonlight-16b-a3b.long-decode: 64 lanes x top 6 expert rows
     "paged_decode_moonlight_latent": lambda: _paged_latent(64, 16, 640, 512,
                                                            12289, 192),
@@ -217,3 +225,73 @@ def test_latent_decode_program_holds_one_arena(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < arena_bytes / 2
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
         >= 2  # the latent kernel (lead layer and scan) and the expert gmm
+
+
+def _decode_program(cfg, b, pages, ps, maxp, one_chip):
+    """The paged bf16 decode program (4 fused steps) compiled for one
+    described v5e, the cache donated; returns it and the cache shapes."""
+    from repro.models.transformer import Model, init_params
+    model = Model(cfg, remat=False)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    caches = jax.eval_shape(lambda: model.init_paged_cache(b, pages, ps,
+                                                           maxp))
+
+    def pfn(params, caches, token, active, eos, budget):
+        return model.decode_steps(params, caches, token, active, 4,
+                                  eos_id=eos, budget=budget)
+    i32 = jax.ShapeDtypeStruct((b,), jnp.int32)
+    args = (params, caches, i32, jax.ShapeDtypeStruct((b,), jnp.bool_),
+            i32, i32)
+    with ops.pinned_impl("pallas"):
+        compiled = jax.jit(
+            pfn, donate_argnums=(1,),
+            in_shardings=jax.tree.map(lambda _: one_chip, args)
+        ).lower(*args).compile()
+    return compiled, caches
+
+
+DENSE_WIDTHS = {
+    # smollm-135m's heads: rows of 2 x 3 x 64 = 384 lanes
+    "smollm_heads": dict(n_layers=6, d_model=576, n_heads=9, n_kv_heads=3,
+                         head_dim=64, d_ff=1536, b=32, maxp=64),
+    # phi3-medium-14b's heads (2 x 10 x 128 = 2560 lanes), narrow d_model
+    # and few layers, so that the arena dominates every other buffer
+    "phi3_heads": dict(n_layers=3, d_model=1024, n_heads=40, n_kv_heads=10,
+                       head_dim=128, d_ff=1024, b=16, maxp=64),
+}
+
+
+@pytest.mark.parametrize("widths", sorted(DENSE_WIDTHS))
+def test_dense_decode_program_holds_one_arena(widths, one_chip):
+    """The paged bf16 decode program of a dense model carries its stacked
+    [k_h | v_h] arena through the layer scan and attends in place: among
+    its buffers there is no second arena, and its optimized HLO holds no
+    copy, slice or write-back of the stacked arena or of one layer's.
+    As scanned xs/ys each layer's arena was sliced out and written back,
+    and separate (P, ps, KVH, hd) arenas, whose last dims are not whole
+    tiles, were laid out pages-minor and copied whole into the kernel's
+    row-major layout."""
+    import re
+
+    from repro.configs.base import ModelConfig
+    w = dict(DENSE_WIDTHS[widths])
+    b, maxp, ps = w.pop("b"), w.pop("maxp"), 16
+    cfg = ModelConfig(name=f"tiny-{widths}", family="dense",
+                      vocab_size=512, mlp_style="swiglu", act="silu",
+                      norm="rmsnorm", **w)
+    pages = b * maxp + 1
+    compiled, caches = _decode_program(cfg, b, pages, ps, maxp, one_chip)
+    arena = caches["scan"]["b0"]["kv"]
+    assert arena.shape == (cfg.n_layers, pages, ps,
+                           2 * cfg.n_kv_heads * cfg.head_dim)
+    arena_bytes = arena.size * arena.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < arena_bytes / 2
+    hlo = compiled.as_text()
+    dims = [",".join(map(str, arena.shape)),
+            ",".join(map(str, arena.shape[1:]))]
+    moves = [ln for ln in hlo.splitlines()
+             if re.search(r"\b(copy|dynamic-slice|dynamic-update-slice)\(",
+                          ln)
+             and any(f"bf16[{d}]" in ln for d in dims)]
+    assert not moves, moves[:3]
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 1
